@@ -110,8 +110,15 @@ class ComputeModel:
 
     @classmethod
     def assumed(cls, platform: str) -> "ComputeModel":
-        f = ASSUMED_FLOPS_PER_S.get(platform, ASSUMED_FLOPS_PER_S["cpu"])
-        return cls(f, f"assumed-{platform}")
+        if platform not in ASSUMED_FLOPS_PER_S:
+            # pricing an unknown accelerator at the CPU's rate would rank
+            # every plan on a number nobody chose
+            raise ValueError(
+                f"no assumed FLOP/s for platform {platform!r} "
+                f"(known: {sorted(ASSUMED_FLOPS_PER_S)}); pass a measured "
+                f"ComputeModel or add the platform to ASSUMED_FLOPS_PER_S"
+            )
+        return cls(ASSUMED_FLOPS_PER_S[platform], f"assumed-{platform}")
 
     @classmethod
     def from_measured_step(cls, step_seconds: float, flops_per_step: float,

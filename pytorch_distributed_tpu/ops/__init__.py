@@ -2,8 +2,8 @@
 
 The hot ops of the transformer recipes live here, written MXU-first:
 batched einsums in bf16, f32 softmax accumulation, no data-dependent
-shapes. The Pallas flash-attention kernel (ops/pallas/) is selected
-automatically for long sequences on TPU.
+shapes. The Pallas kernels (flash_attention.py, opt-in; paged_attention.py,
+the serving engine's TPU default) sit beside the XLA paths they must match.
 """
 
 from pytorch_distributed_tpu.ops.attention import (

@@ -37,11 +37,27 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30  # large-but-finite: avoids NaN from (-inf) - (-inf)
 # lse/delta carry a small replicated trailing dim: 8 == sublane tile floor,
 # the minimum that satisfies TPU block tiling without 128x HBM blow-up
 _LANES = 8
+
+
+def _mxu_dot(a, b, a_dim: int, b_dim: int):
+    """``a`` x ``b`` contracting ``a_dim`` with ``b_dim``, accumulated in
+    f32. Operands narrower than f32 pin the DEFAULT precision: under a
+    caller's ``jax.default_matmul_precision("highest")`` the dot would
+    otherwise ask Mosaic for an fp32-contract matmul on bf16 operands,
+    which it refuses ("Bad lhs type"; v5e, libtpu 0.0.34). f32 operands
+    keep following the caller's precision."""
+    f32 = a.dtype == jnp.float32 and b.dtype == jnp.float32
+    return jax.lax.dot_general(
+        a, b, (((a_dim,), (b_dim,)), ((), ())),
+        precision=None if f32 else jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _causal_live(q_start: int, k_start: int, block_q: int):
@@ -60,11 +76,27 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _pick_block(seq: int, want: int) -> int:
-    b = min(want, seq)
-    while seq % b:
-        b -= 1
-    return b
+def _pick_block(seq: int, want: int, align: int) -> int:
+    """Largest divisor of ``seq`` that is <= ``want`` and a multiple of
+    ``align``; the whole of ``seq`` when there is none. The TPU lowering
+    takes a block dim only if it is tile-aligned (``align``: 8 rows on
+    sublanes, 128 on lanes) or spans the array."""
+    if seq <= want:
+        return seq
+    for b in range(want - want % align, 0, -align):
+        if seq % b == 0:
+            return b
+    return seq
+
+
+def _blocks(S, T, block_q, block_k, side_inputs: bool):
+    # k positions sit on LANES of the [B, 1, T] mask/segment rows, so
+    # their block must be 128-aligned there; on sublanes 8 rows is the
+    # floor for 4-byte types and 16 covers the packed 2-byte ones too
+    return (
+        _pick_block(S, block_q, 16),
+        _pick_block(T, block_k, 128 if side_inputs else 16),
+    )
 
 
 # --------------------------------------------------------------------------
@@ -72,9 +104,11 @@ def _pick_block(seq: int, want: int) -> int:
 # --------------------------------------------------------------------------
 
 
-def _seg_mask(s, qseg, kseg):
-    """Mask scores across segment boundaries (packed sequences)."""
-    return jnp.where(qseg[:, None] == kseg[None, :], s, _NEG_INF)
+def _seg_mask(s, qseg_ref, kseg_ref):
+    """Mask scores across segment boundaries (packed sequences): query
+    ids ride as a lane-replicated column [bq, _LANES], key ids as a row
+    [1, bk], so the compare broadcasts with no in-kernel transpose."""
+    return jnp.where(qseg_ref[0][:, :1] == kseg_ref[0], s, _NEG_INF)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
@@ -108,14 +142,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         q = q_ref[0]  # [bq, d]
         k = k_ref[0]  # [bk, d]
         v = v_ref[0]  # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale  # [bq, bk]
-        if bias_ref is not None:  # kv padding: additive [bk] bias row
-            s = s + bias_ref[0][None, :]
+        s = _mxu_dot(q, k, 1, 1) * sm_scale  # [bq, bk]
+        if bias_ref is not None:  # kv padding: additive [1, bk] bias row
+            s = s + bias_ref[0]
         if qseg_ref is not None:  # packed sequences: block-diagonal mask
-            s = _seg_mask(s, qseg_ref[0], kseg_ref[0])
+            s = _seg_mask(s, qseg_ref, kseg_ref)
 
         if causal:
             s = _causal_mask(s, q_start, k_start, block_q, block_k)
@@ -127,9 +158,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         alpha = jnp.exp(m_prev - m_new)  # rescale of old accumulator
         p = jnp.exp(s - m_new)  # [bq, bk]
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        acc_ref[:] = acc_ref[:] * alpha + _mxu_dot(
+            p.astype(v.dtype), v, 1, 0
         )
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -153,17 +183,18 @@ def _kv_head_map(bh, hq: int, hkv: int):
 
 def _flash_forward(q, k, v, bias, segments, *, hq, hkv, sm_scale, causal,
                    block_q, block_k):
-    """q: [B*Hq, S, D]; k, v: [B*Hkv, T, D]; bias: [B, T] f32 additive
-    or None; segments: [B, S] i32 or None (self-attention packing)
+    """q: [B*Hq, S, D]; k, v: [B*Hkv, T, D]; bias: [B, 1, T] f32
+    additive or None; segments: (query ids [B, S, _LANES], key ids
+    [B, 1, S]) i32 or None (self-attention packing)
     -> (out [B*Hq, S, D], lse)."""
     BH, S, D = q.shape
     _, T, _ = k.shape
-    bq = _pick_block(S, block_q)
-    bk = _pick_block(T, block_k)
+    bq, bk = _blocks(
+        S, T, block_q, block_k, bias is not None or segments is not None
+    )
     grid = (BH, S // bq, T // bk)
 
     kv_map = lambda bh, qi, ki: (_kv_head_map(bh, hq, hkv), ki, 0)
-    bias_map = lambda bh, qi, ki: (bh // hq, ki)
     kernel = functools.partial(
         _fwd_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
         block_k=bk, has_bias=bias is not None,
@@ -175,17 +206,13 @@ def _flash_forward(q, k, v, bias, segments, *, hq, hkv, sm_scale, causal,
         pl.BlockSpec((1, bk, D), kv_map),
     ]
     inputs = [q, k, v]
-    if bias is not None:
-        in_specs.append(pl.BlockSpec((1, bk), bias_map))
-        inputs.append(bias)
-    if segments is not None:
-        in_specs.append(
-            pl.BlockSpec((1, bq), lambda bh, qi, ki: (bh // hq, qi))
-        )
-        in_specs.append(
-            pl.BlockSpec((1, bk), lambda bh, qi, ki: (bh // hq, ki))
-        )
-        inputs.extend([segments, segments])
+    side_specs, side_inputs = _side_inputs(
+        bias, segments, bq, bk,
+        q_map=lambda bh, qi, ki: (bh // hq, qi, 0),
+        k_map=lambda bh, qi, ki: (bh // hq, 0, ki),
+    )
+    in_specs += side_specs
+    inputs += side_inputs
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -198,32 +225,42 @@ def _flash_forward(q, k, v, bias, segments, *, hq, hkv, sm_scale, causal,
             jax.ShapeDtypeStruct((BH, S, D), q.dtype),
             jax.ShapeDtypeStruct((BH, S, _LANES), jnp.float32),
         ],
-        scratch_shapes=_fwd_scratch(bq, bk, D),
-        compiler_params=_compiler_params(),
+        scratch_shapes=[
+            pltpu.VMEM((bq, D), jnp.float32),  # acc
+            pltpu.VMEM((bq, 128), jnp.float32),  # running max (lanes
+            # replicated)
+            pltpu.VMEM((bq, 128), jnp.float32),  # running sum
+        ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(),
+        name="flash_attention_fwd",
     )(*inputs)
     return out, lse
 
 
-def _fwd_scratch(bq, bk, d):
-    from jax.experimental.pallas import tpu as pltpu
+def _side_inputs(bias, segments, bq, bk, *, q_map, k_map):
+    """(specs, inputs) for the optional mask-bias row and segment ids,
+    in the order the kernels pop them. Both are per-BATCH side inputs;
+    a (1, bk) block over a [B, T] array would put the batch on sublanes
+    in a 1-row block the TPU lowering refuses for B > 1, hence the
+    [B, 1, T] rows (block (1, 1, bk): whole on sublanes, 128-aligned on
+    lanes) and the lane-replicated [B, S, _LANES] query-id column."""
+    specs, inputs = [], []
+    if bias is not None:
+        specs.append(pl.BlockSpec((1, 1, bk), k_map))
+        inputs.append(bias)
+    if segments is not None:
+        specs.append(pl.BlockSpec((1, bq, _LANES), q_map))
+        specs.append(pl.BlockSpec((1, 1, bk), k_map))
+        inputs.extend(segments)
+    return specs, inputs
 
-    return [
-        pltpu.VMEM((bq, d), jnp.float32),  # acc
-        pltpu.VMEM((bq, 128), jnp.float32),  # running max (lane-replicated)
-        pltpu.VMEM((bq, 128), jnp.float32),  # running sum
-    ]
 
-
-def _compiler_params():
-    from jax.experimental.pallas import tpu as pltpu
-
-    # renamed TPUCompilerParams -> CompilerParams across jax releases;
-    # accept whichever this container's jax ships (cf. runtime/compat.py)
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    )
+# the innermost grid dimension carries the online-softmax (or gradient)
+# accumulator in VMEM scratch, so it is sequential ("arbitrary")
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"),
+)
 
 
 # --------------------------------------------------------------------------
@@ -259,26 +296,17 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0][:, :1]  # [bq, 1] (lanes replicated)
         delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
+        s = _mxu_dot(q, k, 1, 1) * sm_scale
         if bias_ref is not None:
-            s = s + bias_ref[0][None, :]
+            s = s + bias_ref[0]
         if qseg_ref is not None:
-            s = _seg_mask(s, qseg_ref[0], kseg_ref[0])
+            s = _seg_mask(s, qseg_ref, kseg_ref)
         if causal:
             s = _causal_mask(s, q_start, k_start, block_q, block_k)
         p = jnp.exp(s - lse)  # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dp = _mxu_dot(do, v.astype(jnp.float32), 1, 1)
         ds = p * (dp - delta) * sm_scale
-        acc_ref[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        acc_ref[:] += _mxu_dot(ds.astype(k.dtype), k, 1, 0)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -314,30 +342,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         do = do_ref[0].astype(jnp.float32)
         lse = lse_ref[0][:, :1]  # [bq, 1] (lanes replicated)
         delta = delta_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale
+        s = _mxu_dot(q, k, 1, 1) * sm_scale
         if bias_ref is not None:
-            s = s + bias_ref[0][None, :]
+            s = s + bias_ref[0]
         if qseg_ref is not None:
-            s = _seg_mask(s, qseg_ref[0], kseg_ref[0])
+            s = _seg_mask(s, qseg_ref, kseg_ref)
         if causal:
             s = _causal_mask(s, q_start, k_start, block_q, block_k)
         p = jnp.exp(s - lse)  # [bq, bk]
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [bk, d]
-        dp = jax.lax.dot_general(
-            do, v.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dv_acc[:] += _mxu_dot(p.astype(do.dtype), do, 0, 0)  # [bk, d]
+        dp = _mxu_dot(do, v.astype(jnp.float32), 1, 1)
         ds = p * (dp - delta) * sm_scale  # [bq, bk]
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dk_acc[:] += _mxu_dot(ds.astype(q.dtype), q, 0, 0)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -401,13 +417,13 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, dout):
     )
 
     BH = B * Hq
-    bq = _pick_block(S, block_q)
-    bk = _pick_block(T, block_k)
+    has_bias = bias is not None
+    has_segments = segments is not None
+    bq, bk = _blocks(S, T, block_q, block_k, has_bias or has_segments)
     kv_map = lambda bh, qi, ki: (_kv_head_map(bh, Hq, Hkv), ki, 0)
     q_map = lambda bh, qi, ki: (bh, qi, 0)
     lse_map = lambda bh, qi, ki: (bh, qi, 0)
 
-    has_bias = bias is not None
     dq_specs = [
         pl.BlockSpec((1, bq, D), q_map),
         pl.BlockSpec((1, bk, D), kv_map),
@@ -416,15 +432,12 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, dout):
         pl.BlockSpec((1, bq, _LANES), lse_map),
         pl.BlockSpec((1, bq, _LANES), lse_map),
     ]
-    dq_inputs = [qf, kf, vf, dof, lse, delta]
-    if has_bias:
-        dq_specs.append(pl.BlockSpec((1, bk), lambda bh, qi, ki: (bh // Hq, ki)))
-        dq_inputs.append(bias)
-    has_segments = segments is not None
-    if has_segments:
-        dq_specs.append(pl.BlockSpec((1, bq), lambda bh, qi, ki: (bh // Hq, qi)))
-        dq_specs.append(pl.BlockSpec((1, bk), lambda bh, qi, ki: (bh // Hq, ki)))
-        dq_inputs.extend([segments, segments])
+    side_specs, side_inputs = _side_inputs(
+        bias, segments, bq, bk,
+        q_map=lambda bh, qi, ki: (bh // Hq, qi, 0),
+        k_map=lambda bh, qi, ki: (bh // Hq, 0, ki),
+    )
+    dq_inputs = [qf, kf, vf, dof, lse, delta] + side_inputs
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, sm_scale=sm_scale, causal=causal,
@@ -432,12 +445,13 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, dout):
             has_segments=has_segments,
         ),
         grid=(BH, S // bq, T // bk),
-        in_specs=dq_specs,
+        in_specs=dq_specs + side_specs,
         out_specs=pl.BlockSpec((1, bq, D), q_map),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        scratch_shapes=_bwd_scratch(bq, D, n=1),
-        compiler_params=_compiler_params(),
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(),
+        name="flash_attention_dq",
     )(*dq_inputs)
 
     # dk/dv per *query* head (race-free), group-summed to kv heads after
@@ -450,20 +464,12 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, dout):
         pl.BlockSpec((1, bq, _LANES), lambda bh, ki, qi: (bh, qi, 0)),
         pl.BlockSpec((1, bq, _LANES), lambda bh, ki, qi: (bh, qi, 0)),
     ]
-    dkv_inputs = [qf, kf, vf, dof, lse, delta]
-    if has_bias:
-        dkv_specs.append(
-            pl.BlockSpec((1, bk), lambda bh, ki, qi: (bh // Hq, ki))
-        )
-        dkv_inputs.append(bias)
-    if has_segments:
-        dkv_specs.append(
-            pl.BlockSpec((1, bq), lambda bh, ki, qi: (bh // Hq, qi))
-        )
-        dkv_specs.append(
-            pl.BlockSpec((1, bk), lambda bh, ki, qi: (bh // Hq, ki))
-        )
-        dkv_inputs.extend([segments, segments])
+    side_specs, side_inputs = _side_inputs(
+        bias, segments, bq, bk,
+        q_map=lambda bh, ki, qi: (bh // Hq, qi, 0),
+        k_map=lambda bh, ki, qi: (bh // Hq, 0, ki),
+    )
+    dkv_inputs = [qf, kf, vf, dof, lse, delta] + side_inputs
     dk_per_q, dv_per_q = pl.pallas_call(
         functools.partial(
             _dkv_kernel, sm_scale=sm_scale, causal=causal,
@@ -471,7 +477,7 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, dout):
             has_segments=has_segments,
         ),
         grid=(BH, T // bk, S // bq),
-        in_specs=dkv_specs,
+        in_specs=dkv_specs + side_specs,
         out_specs=[
             pl.BlockSpec((1, bk, D), lambda bh, ki, qi: (bh, ki, 0)),
             pl.BlockSpec((1, bk, D), lambda bh, ki, qi: (bh, ki, 0)),
@@ -480,9 +486,10 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, dout):
             jax.ShapeDtypeStruct((BH, T, D), k.dtype),
             jax.ShapeDtypeStruct((BH, T, D), v.dtype),
         ],
-        scratch_shapes=_bwd_scratch(bk, D, n=2),
-        compiler_params=_compiler_params(),
+        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32)] * 2,
+        compiler_params=_COMPILER_PARAMS,
         interpret=_interpret(),
+        name="flash_attention_dkv",
     )(*dkv_inputs)
 
     dq = dq.reshape(B, Hq, S, D).transpose(0, 2, 1, 3)
@@ -501,12 +508,6 @@ def _flash_bwd(sm_scale, causal, block_q, block_k, res, dout):
         None if bias is None else jnp.zeros_like(bias),
         None,
     )
-
-
-def _bwd_scratch(rows, d, n):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return [pltpu.VMEM((rows, d), jnp.float32) for _ in range(n)]
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -550,7 +551,7 @@ def flash_attention(
             )
         bias = jnp.where(kv_mask.astype(jnp.bool_), 0.0, _NEG_INF).astype(
             jnp.float32
-        )
+        )[:, None, :]
     if segment_ids is not None:
         if S != T:
             raise ValueError("segment_ids requires self-attention (S == T)")
@@ -559,7 +560,11 @@ def flash_attention(
                 f"segment_ids must be [batch, seq] = {(B, S)}, "
                 f"got {segment_ids.shape}"
             )
-        segment_ids = segment_ids.astype(jnp.int32)
+        ids = segment_ids.astype(jnp.int32)
+        segment_ids = (
+            jnp.broadcast_to(ids[:, :, None], (B, S, _LANES)),
+            ids[:, None, :],
+        )
     return _flash(
         q, k, v, bias, segment_ids, sm_scale, causal, block_q, block_k
     )

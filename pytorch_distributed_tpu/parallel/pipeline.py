@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from pytorch_distributed_tpu.runtime.compat import axis_size, shard_map
 
 from pytorch_distributed_tpu.runtime.mesh import current_mesh
 
@@ -41,7 +40,7 @@ def _pipeline_local(stage_params, xs, *, stage_fn, axis: str):
     xs: [M, ...] all microbatches (replicated).
     """
     stage = lax.axis_index(axis)
-    n_stages = axis_size(axis)
+    n_stages = lax.axis_size(axis)
     M = xs.shape[0]
     params = jax.tree_util.tree_map(lambda p: p[0], stage_params)
 
@@ -109,7 +108,7 @@ def pipeline_forward(
                 f"stacked param leading dim {leaf.shape[0]} != pipeline "
                 f"stages {n_stages} (mesh axis {axis!r})"
             )
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_pipeline_local, stage_fn=stage_fn, axis=axis),
         mesh=mesh,
         in_specs=(P(axis), P()),

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from pytorch_distributed_tpu.runtime.compat import shard_map
 
 from pytorch_distributed_tpu.runtime.mesh import current_mesh, data_axes
 
@@ -172,7 +171,7 @@ def ring_attention(
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     spec = P(data_axes(), axis, "tp", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ring_attention_local, axis_name=axis, causal=causal,
             scale=scale, window=window, bias_fn=bias_fn,
@@ -265,7 +264,7 @@ def ulysses_attention(
         )
 
     spec = P(data_axes(), axis, "tp", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             _ulysses_local, axis_name=axis, causal=causal, inner=inner
         ),

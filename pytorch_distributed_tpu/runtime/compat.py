@@ -1,61 +1,7 @@
-"""Version shims for jax APIs that moved between releases.
-
-``shard_map`` graduated from ``jax.experimental.shard_map`` to the
-``jax`` namespace, and its replication-check kwarg was renamed
-``check_rep`` -> ``check_vma`` in the same window. The container pins
-whatever jaxlib the accelerator toolchain ships, so both spellings must
-work; every in-repo caller imports the wrapper below instead of picking
-a spelling.
-"""
+"""Two probes of jax internals that have no public spelling, kept in one
+place so a jax upgrade has one file to check."""
 
 from __future__ import annotations
-
-try:  # jax >= 0.6: public API, kwarg is check_vma
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax 0.4/0.5: experimental API, kwarg is check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` with the replication-check flag normalized to
-    the new ``check_vma`` name regardless of the installed jax."""
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_CHECK_KW: check_vma},
-    )
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a named mesh axis inside traced code —
-    ``lax.axis_size`` where it exists (newer jax), else jax 0.4's
-    ``core.axis_frame`` (which returns the int directly there)."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    import jax.core as core
-
-    frame = core.axis_frame(axis_name)
-    return getattr(frame, "size", frame)
-
-
-def abstract_mesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` across its signature change:
-    newer jax takes ``(axis_sizes, axis_names)``, jax 0.4 takes one
-    ``((name, size), ...)`` shape tuple."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
 
 
 def jit_cache_size(fn):
@@ -63,22 +9,18 @@ def jit_cache_size(fn):
 
     The recompile sentinel (runtime/tracing.py) polls this after each
     step: a steady-state loop whose count grows is silently recompiling.
-    ``_cache_size`` is private jax API present on PjitFunction across
-    the 0.4–0.6 window this repo supports; any absence/failure degrades
-    to None (sentinel off for that callable) rather than raising in the
-    hot loop.
+    ``_cache_size`` is private jax API on the jitted-function object; a
+    callable without it (a host-loop step that is not one jit) reports
+    None — sentinel off for that callable.
     """
     f = getattr(fn, "_cache_size", None)
     if not callable(f):
         return None
-    try:
-        return int(f())
-    except Exception:  # pragma: no cover - backend/version specific
-        return None
+    return int(f())
 
 
 def live_buffer_bytes():
-    """Live device-buffer bytes, or None when nothing can report them.
+    """Live device-buffer bytes.
 
     TPU/GPU backends expose per-device ``memory_stats()['bytes_in_use']``
     — the allocator's own number, preferred. XLA:CPU reports no memory
@@ -100,10 +42,4 @@ def live_buffer_bytes():
             saw = True
     if saw:
         return total
-    live = getattr(jax, "live_arrays", None)
-    if live is None:  # pragma: no cover - very old jax
-        return None
-    try:
-        return int(sum(getattr(a, "nbytes", 0) or 0 for a in live()))
-    except Exception:  # pragma: no cover - defensive: gauge must not kill
-        return None
+    return int(sum(a.nbytes for a in jax.live_arrays()))

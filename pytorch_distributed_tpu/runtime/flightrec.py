@@ -52,7 +52,7 @@ Arming the dump path:
 
 The autopsy half (:func:`load_dumps`, :func:`autopsy`) merges N dumps
 and names the failure class; ``scripts/hang_autopsy.py`` is the CLI.
-Verdict taxonomy and detection envelopes are documented in
+Verdict classes and detection envelopes are documented in
 docs/DESIGN.md §24.
 
 jax-free on purpose: imported by hostring/transport/membership workers

@@ -328,7 +328,8 @@ class Tracer:
     def write_rollups(self, writer, step: int = 0) -> None:
         """Emit rollups through the MetricsWriter JSONL protocol — one
         ``event="span_rollup"`` record per span name plus one
-        ``event="recompiles"`` record, all under ``split="trace"``."""
+        ``event="recompiles"`` record (compiles after warm-up, and the
+        cumulative count per callable), all under ``split="trace"``."""
         for name, roll in self.rollups().items():
             writer.write(
                 step, {"event": "span_rollup", "span": name, **roll},
@@ -340,6 +341,10 @@ class Tracer:
         }
         for name, n in sorted(self.recompiles.items()):
             rec[f"recompiles.{name}"] = n
+        with self._lock:
+            compiles = dict(self._compiles)
+        for name, n in sorted(compiles.items()):
+            rec[f"compiles.{name}"] = n  # cumulative, warm-up included
         writer.write(step, rec, split="trace")
 
     # -- export ------------------------------------------------------------

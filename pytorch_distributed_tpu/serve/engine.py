@@ -85,6 +85,7 @@ from pytorch_distributed_tpu.serve.disagg import (
 from pytorch_distributed_tpu.ops.paged_attention import (
     PagedView,
     paged_view,
+    refuse_kernel_for,
     resolve_paged_attention_impl,
 )
 from pytorch_distributed_tpu.runtime import faults
@@ -391,20 +392,17 @@ class ServeEngine:
         # analytic HBM accounting for the decode hot path: bytes one
         # tick moves under this mode/impl's traffic model (DESIGN.md
         # §17), accumulated host-side as plain ints so the disarmed
-        # tracing cost stays one is-None test. _attn_impl resolves
+        # tracing cost stays one is-None test. The impl resolves
         # ONCE — the accounting follows the backend the programs trace
         self._resolved_impl = (
             resolve_paged_attention_impl()
             if config.decode_mode == "paged" else "dense"
         )
-        self._attn_impl = self._resolved_impl
-        if self._attn_impl == "kernel" and getattr(
-            getattr(model, "config", None), "kv_cache_quantize", None
-        ) is not None:
-            # the kernel takes fp pools only — paged_attention falls
-            # back to the gather impl for quantized caches, and the
-            # byte accounting must price what actually runs
-            self._attn_impl = "gather"
+        if self._resolved_impl == "kernel":
+            # fail at construction, not at the first decode compile
+            refuse_kernel_for(quantized=getattr(
+                getattr(model, "config", None), "kv_cache_quantize", None
+            ) is not None)
         self._frame_bytes_target = self._frame_bytes(self.pool.cache)
         self._frame_bytes_draft = (
             self._frame_bytes(self.draft_pool.cache)
@@ -1118,7 +1116,7 @@ class ServeEngine:
             # gather traffic = the dense intermediate (pool read +
             # dense write); the attention stream reads each page once
             gather = attn = 0
-            if self._attn_impl in ("dense", "gather"):
+            if self._resolved_impl in ("dense", "gather"):
                 gather += 2 * S * n_pages * fb
             attn += S * n_pages * fb
             if self.spec is not None:
@@ -1183,6 +1181,26 @@ class ServeEngine:
                     self._keys, self._temps, self._top_ks,
                     self._top_ps, idle, n,
                 )
+
+    def trace_decode(self, n_pages: int):
+        """The plain decode tick at bucket ``n_pages``, traced but
+        neither compiled nor run (``jax.stages.Traced``): ``.lower()``
+        gives the program text — how chip_smoke.py and
+        tests/test_tpu_lowering.py establish that the tick really
+        carries the Mosaic paged-attention kernel. The compile ledger
+        is left as found: this trace serves nothing."""
+        if self._decode is None:
+            raise ValueError("trace_decode covers the plain (spec=None) tick")
+        ledger = (self.decode_compiles, dict(self._decode_bucket_compiles))
+        try:
+            return self._decode.trace(
+                self.params, self.pool.cache, self._pt, self._toks,
+                self._lengths, self._keys, self._temps, self._top_ks,
+                self._top_ps, jnp.zeros(self.config.num_slots, bool),
+                n_pages,
+            )
+        finally:
+            self.decode_compiles, self._decode_bucket_compiles = ledger
 
     def _snapshot(self) -> None:
         pool = self.pool

@@ -69,6 +69,7 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
+    ptd.enable_compilation_cache()
     ptd.seed_all(args.seed)
     ptd.init_process_group(args.backend, mesh_spec=MeshSpec(dp=args.dp))
     log_rank0("world=%d backend=%s", ptd.get_world_size(), ptd.get_backend())

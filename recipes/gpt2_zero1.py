@@ -93,6 +93,12 @@ def parse_args(argv=None):
     p.add_argument("--ckpt-dir", default=None)
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--log-every", type=int, default=10)
+    p.add_argument("--metrics-path", default=None,
+                   help="JSONL scalar log (loss per logged step, goodput, "
+                        "span rollups)")
+    p.add_argument("--trace-dir", default=None,
+                   help="span-tracer output dir: Perfetto-loadable "
+                        "trace.json + JSONL rollups (runtime/tracing.py)")
     p.add_argument(
         "--sample", type=int, default=0, metavar="N",
         help="generate N tokens from the trained model at the end",
@@ -121,6 +127,7 @@ def main(argv=None):
             "--pack is not combinable with --pp yet (the pipelined loss "
             "refuses packed batches); --pack + --vocab-chunk is supported"
         )
+    ptd.enable_compilation_cache()
     ptd.seed_all(args.seed)
     cfg = SIZES[args.size]()
     if args.remat or args.remat_policy != "full":
@@ -264,14 +271,6 @@ def main(argv=None):
         )
 
     model = GPT2LMHead(cfg)
-    variables = model.init(
-        jax.random.key(args.seed), jnp.zeros((1, seq_len), jnp.int32)
-    )
-    state = TrainState.create(
-        apply_fn=model.apply,
-        params=variables["params"],
-        tx=tx,
-    )
     # under --strategy auto the PLAN decides whether the run pipelines:
     # --pp N only opened the search space, chosen.spec.pp is the answer
     # (and carries the microbatch count the bubble was priced at)
@@ -313,6 +312,15 @@ def main(argv=None):
             model, vocab_chunk_size=args.vocab_chunk
         )
         accum_steps = args.accum_steps
+    def make_state(key):
+        variables = model.init(key, jnp.zeros((1, seq_len), jnp.int32))
+        return TrainState.create(
+            apply_fn=model.apply, params=variables["params"], tx=tx,
+        )
+
+    # built straight onto its shards: a whole f32 copy of the weights and
+    # Adam state never sits on device 0 first (5.7 GB at --size medium)
+    state = strategy.create_sharded(make_state, jax.random.key(args.seed))
     if tokenizer is not None:
         eval_ds = ds  # token-level held-out split is the user's concern;
         # the recipe reports training-distribution perplexity
@@ -339,6 +347,7 @@ def main(argv=None):
         config=TrainerConfig(
             epochs=args.epochs, log_every=args.log_every,
             ckpt_dir=args.ckpt_dir, samples_axis="input_ids",
+            metrics_path=args.metrics_path, trace=args.trace_dir,
         ),
     )
     trainer.restore_checkpoint()

@@ -107,6 +107,7 @@ def main(argv=None):
     import dataclasses
 
     args = parse_args(argv)
+    ptd.enable_compilation_cache()
     ptd.seed_all(args.seed)
     cfg = SIZES[args.size]()
     if args.remat or args.remat_policy != "full":

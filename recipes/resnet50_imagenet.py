@@ -80,6 +80,7 @@ class Config(RecipeConfig):
 
 def main(argv=None):
     cfg: Config = parse_cli(Config, argv, description=__doc__)
+    ptd.enable_compilation_cache()
     ptd.seed_all(cfg.seed)
     mesh_spec = MeshSpec(dp=cfg.dp)
     chosen = None

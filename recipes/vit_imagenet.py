@@ -74,6 +74,7 @@ class Config(RecipeConfig):
 
 def main(argv=None):
     cfg: Config = parse_cli(Config, argv, description=__doc__)
+    ptd.enable_compilation_cache()
     ptd.seed_all(cfg.seed)
     ptd.init_process_group(cfg.backend, mesh_spec=MeshSpec(dp=cfg.dp))
 

@@ -12,7 +12,7 @@
 # logic can follow up, and no process accumulates more than a few
 # hundred executables.
 #
-# Usage:  flock /tmp/ptd_bench.lock scripts/run_full_suite.sh
+# Usage:  scripts/run_full_suite.sh
 set -u
 cd "$(dirname "$0")/.."
 # static analysis first: ptdlint is seconds (no jax import) and a

@@ -238,11 +238,15 @@ def main():
     import jax
     import numpy as np
 
+    from pytorch_distributed_tpu.runtime.device import (
+        enable_compilation_cache,
+    )
     from pytorch_distributed_tpu.serve import (
         EngineConfig, ServeEngine, ServeTelemetry, SpecConfig, drive,
         prefix_shared_requests, uniform_arrivals, warm_up,
     )
 
+    enable_compilation_cache()
     model = build_model(args.model)
     vocab = model.config.vocab_size
     rng = np.random.default_rng(args.seed)

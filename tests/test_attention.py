@@ -212,17 +212,19 @@ class TestFlashAttention:
         and a fully-masked k-block."""
         from pytorch_distributed_tpu.ops.flash_attention import flash_attention
 
-        q, k, v = self._qkv(S=64, D=32)
+        # the mask rides on LANES, so its k block is 128-aligned (what
+        # the TPU lowering accepts): 256 keys make two k blocks
+        q, k, v = self._qkv(S=256, D=32)
         B = q.shape[0]
-        # lengths start at exactly one block (32): sequence 0's block
-        # [32, 64) is FULLY masked, exercising the online-softmax carry
+        # lengths start at exactly one block (128): sequence 0's block
+        # [128, 256) is FULLY masked, exercising the online-softmax carry
         # for all-masked blocks; later lengths cross block boundaries
-        lengths = np.linspace(32, 64, B).astype(np.int64)
-        mask = jnp.asarray(np.arange(64)[None, :] < lengths[:, None])
+        lengths = np.linspace(128, 256, B).astype(np.int64)
+        mask = jnp.asarray(np.arange(256)[None, :] < lengths[:, None])
 
         want = dot_product_attention(q, k, v, causal=causal, mask=mask)
         got = flash_attention(
-            q, k, v, causal=causal, kv_mask=mask, block_q=32, block_k=32
+            q, k, v, causal=causal, kv_mask=mask, block_q=64, block_k=128
         )
         valid = np.asarray(mask)[:, :, None, None]  # padded q rows are
         np.testing.assert_allclose(       # undefined on both paths
@@ -249,7 +251,7 @@ class TestFlashAttention:
             loss(
                 lambda q, k, v: flash_attention(
                     q, k, v, causal=causal, kv_mask=mask,
-                    block_q=32, block_k=32,
+                    block_q=64, block_k=128,
                 )
             ),
             argnums=(0, 1, 2),
@@ -287,13 +289,16 @@ class TestFlashAttention:
         einsum path, fwd and grads, with boundaries off block edges."""
         from pytorch_distributed_tpu.ops.flash_attention import flash_attention
 
-        q, k, v = self._qkv(S=64, D=32)
+        # key ids ride on LANES (128-aligned k blocks): 256 keys, 2 blocks
+        q, k, v = self._qkv(S=256, D=32)
         B = q.shape[0]
         rng = np.random.default_rng(0)
-        # 3 segments per row, ragged boundaries (never multiples of 32)
-        seg = np.zeros((B, 64), np.int32)
+        # 3 segments per row, ragged boundaries (never multiples of 64)
+        seg = np.zeros((B, 256), np.int32)
         for b in range(B):
-            cuts = sorted(rng.choice(np.arange(5, 60), size=2, replace=False))
+            cuts = sorted(
+                rng.choice(np.arange(5, 250, 2), size=2, replace=False)
+            )
             seg[b, :cuts[0]] = 1
             seg[b, cuts[0]:cuts[1]] = 2
             seg[b, cuts[1]:] = 3
@@ -301,7 +306,8 @@ class TestFlashAttention:
 
         want = dot_product_attention(q, k, v, causal=causal, segment_ids=seg)
         got = flash_attention(
-            q, k, v, causal=causal, segment_ids=seg, block_q=32, block_k=32
+            q, k, v, causal=causal, segment_ids=seg, block_q=64,
+            block_k=128,
         )
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-6
@@ -318,7 +324,7 @@ class TestFlashAttention:
         gotg = jax.grad(
             loss(lambda q, k, v: flash_attention(
                 q, k, v, causal=causal, segment_ids=seg,
-                block_q=32, block_k=32,
+                block_q=64, block_k=128,
             )), argnums=(0, 1, 2),
         )(q, k, v)
         for a, b in zip(ref, gotg):
@@ -332,15 +338,15 @@ class TestFlashAttention:
         from pytorch_distributed_tpu.ops.flash_attention import flash_attention
 
         rng = np.random.default_rng(1)
-        d1 = rng.normal(size=(1, 24, 2, 16)).astype(np.float32)
-        d2 = rng.normal(size=(1, 40, 2, 16)).astype(np.float32)
+        d1 = rng.normal(size=(1, 100, 2, 16)).astype(np.float32)
+        d2 = rng.normal(size=(1, 156, 2, 16)).astype(np.float32)
         packed = jnp.asarray(np.concatenate([d1, d2], axis=1))
         seg = jnp.asarray(
-            np.concatenate([np.full(24, 1), np.full(40, 2)])[None, :]
+            np.concatenate([np.full(100, 1), np.full(156, 2)])[None, :]
         )
         out = flash_attention(
             packed, packed, packed, causal=True, segment_ids=seg,
-            block_q=16, block_k=16,
+            block_q=64, block_k=128,  # 4 q blocks x 2 k blocks
         )
         a1 = dot_product_attention(
             jnp.asarray(d1), jnp.asarray(d1), jnp.asarray(d1), causal=True
@@ -349,10 +355,10 @@ class TestFlashAttention:
             jnp.asarray(d2), jnp.asarray(d2), jnp.asarray(d2), causal=True
         )
         np.testing.assert_allclose(
-            np.asarray(out[:, :24]), np.asarray(a1), rtol=2e-5, atol=2e-6
+            np.asarray(out[:, :100]), np.asarray(a1), rtol=2e-5, atol=2e-6
         )
         np.testing.assert_allclose(
-            np.asarray(out[:, 24:]), np.asarray(a2), rtol=2e-5, atol=2e-6
+            np.asarray(out[:, 100:]), np.asarray(a2), rtol=2e-5, atol=2e-6
         )
 
 

@@ -182,6 +182,7 @@ class TestSpans:
         rc = [r for r in recs if r.get("event") == "recompiles"]
         assert rc[0]["recompiles_total"] == 2
         assert rc[0]["recompiles.f"] == 2
+        assert rc[0]["compiles.f"] == 3  # cumulative, warm-up included
 
 
 # -- recompile sentinel ----------------------------------------------------

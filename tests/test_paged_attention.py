@@ -184,8 +184,8 @@ class TestPagedAttentionOp:
     def test_int8_scale_pools(self, impl):
         """Quantized pools ride as payload+scale pairs; the dequant is
         decode_cache's exact formula, so the gather impl is bitwise the
-        dense int8 path (the kernel impl falls back to gather — it
-        takes fp pools only, by contract)."""
+        dense int8 path. The kernel takes fp pools only and REFUSES a
+        quantized one by name — never a quiet reroute to gather."""
         rng = np.random.default_rng(5)
         q, kp, vp, tables, lengths = _pool_case(rng)
         k8 = jnp.asarray(
@@ -207,13 +207,21 @@ class TestPagedAttentionOp:
         ref = np.asarray(
             _dense_ref(q, kd, vd, tables, lengths), np.float32
         )
-        out = np.asarray(paged_attention(
-            q,
+        pools = (
             PagedKVQuant(k8, ks, jnp.float32),
             PagedKVQuant(v8, vs, jnp.float32),
-            page_tables=tables, lengths=lengths, impl=impl,
+        )
+        if impl == "kernel":
+            with pytest.raises(ValueError, match="int8 KV cache"):
+                paged_attention(
+                    q, *pools, page_tables=tables, lengths=lengths,
+                    impl=impl,
+                )
+            return
+        out = np.asarray(paged_attention(
+            q, *pools, page_tables=tables, lengths=lengths, impl=impl,
         ), np.float32)
-        tol = 0.0 if impl in ("gather", "kernel") else 3e-6
+        tol = 0.0 if impl == "gather" else 3e-6
         assert np.max(np.abs(out - ref)) <= tol
 
     def test_paged_write_placement_and_drop(self):
@@ -342,7 +350,7 @@ class TestPagedEngine:
         assert dense_e.decode_buckets == {dense_e.pool.max_pages}
         assert dense_e.decode_compiles == 1
 
-    def test_int8_kv_cache_dense_vs_paged_ab_parity(self):
+    def test_int8_kv_cache_dense_vs_paged_ab_parity(self, monkeypatch):
         """kv_cache_quantize='int8' rides the paged path as payload +
         scale pools (PagedKVQuant): the per-page dequant is
         decode_cache's exact formula, so dense-mode and paged-mode
@@ -370,6 +378,19 @@ class TestPagedEngine:
             )
             streams.append([h.tokens for h in hs])
         assert streams[0] == streams[1]
+        # where the kernel is what "auto" means (a TPU), the same engine
+        # is refused at construction, by name — the flag is patched
+        # directly: the setter would drop every jit cache in the process
+        import sys
+
+        monkeypatch.setattr(
+            sys.modules["pytorch_distributed_tpu.ops.paged_attention"],
+            "_IMPL", "kernel",
+        )  # (the package re-exports a function under the module's name)
+        with pytest.raises(ValueError, match="int8 KV cache"):
+            ServeEngine(model, params, EngineConfig(
+                num_slots=2, max_len=64, prefill_chunk=4, page_size=8,
+            ))
 
     def test_slot_reuse_recompiles_at_most_once_per_bucket(
         self, long_ctx

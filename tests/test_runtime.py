@@ -42,6 +42,108 @@ class TestMesh:
         assert ptd.mesh_axis_size("tp") == 2
 
 
+@pytest.fixture
+def unpinned_cpu(monkeypatch):
+    """The same CPU devices, but as JAX's own fallback: the caller never
+    pinned ``jax_platforms`` (what a machine whose TPU did not come up
+    looks like to runtime/device.py)."""
+    import types
+
+    from pytorch_distributed_tpu.runtime import device
+
+    monkeypatch.setattr(device, "jax", types.SimpleNamespace(
+        devices=jax.devices,
+        config=types.SimpleNamespace(
+            jax_platforms=None, update=jax.config.update
+        ),
+    ))
+
+
+class TestDeviceRules:
+    """Nothing hides the device: explicit CPU or an error, a known
+    accelerator or an error, one place for the compile cache."""
+
+    def test_unrequested_cpu_is_an_error(self, unpinned_cpu):
+        from pytorch_distributed_tpu.runtime import device
+
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            device.require_tpu_or_requested_cpu()
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            ptd.init_process_group()
+        assert ptd.init_process_group("cpu").backend == "cpu"
+
+    def test_requested_cpu_is_fine(self):
+        from pytorch_distributed_tpu.runtime import device
+
+        # the suite pins jax_platforms=cpu (conftest): the CPU was asked for
+        assert device.require_tpu_or_requested_cpu() == "cpu"
+
+    def test_bench_entry_refuses_an_unrequested_cpu(self, unpinned_cpu):
+        import bench  # the repo root is importable wherever the package is
+
+        with pytest.raises(RuntimeError, match="JAX_PLATFORMS=cpu"):
+            bench.main()
+        assert not ptd.is_initialized()  # refused before any set-up
+
+    def test_unknown_accelerator_has_no_peak(self, monkeypatch):
+        from pytorch_distributed_tpu.runtime import device
+
+        assert device.peak_flops() is None  # the CPU: no MFU is quoted
+        monkeypatch.setattr(device, "device_kind", lambda: "TPU v5 lite")
+        assert device.peak_flops() == 197e12
+        monkeypatch.setattr(device, "device_kind", lambda: "TPU v99")
+        monkeypatch.setattr(device, "platform", lambda: "tpu")
+        with pytest.raises(ValueError, match="TPU v99"):
+            device.peak_flops()
+
+    def test_compile_cache_dir_comes_from_outside_when_set(
+        self, monkeypatch, tmp_path
+    ):
+        from pytorch_distributed_tpu.runtime import device
+
+        updates = []
+        monkeypatch.setattr(
+            device.jax.config, "update",
+            lambda k, v: updates.append((k, v)),
+        )
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert device.enable_compilation_cache() == str(tmp_path)
+        # JAX reads the variable itself: no directory is set in code
+        assert "jax_compilation_cache_dir" not in dict(updates)
+        assert dict(updates)["jax_persistent_cache_min_entry_size_bytes"] == 0
+
+    def test_compile_cache_default_is_one_fixed_path_in_the_checkout(
+        self, monkeypatch
+    ):
+        import os
+        import subprocess
+        import sys
+
+        from pytorch_distributed_tpu.runtime import device
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        updates = []
+        monkeypatch.setattr(
+            device.jax.config, "update",
+            lambda k, v: updates.append((k, v)),
+        )
+        path = device.enable_compilation_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert os.path.dirname(path) == os.path.join(repo, ".jax_cache")
+        assert dict(updates)["jax_compilation_cache_dir"] == path
+        # another process, another cwd: the same directory
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        other = subprocess.run(
+            [sys.executable, "-c",
+             "from pytorch_distributed_tpu.runtime.device import "
+             "enable_compilation_cache as e; print(e())"],
+            env=dict(env, PYTHONPATH=repo, JAX_PLATFORMS="cpu"), cwd="/",
+            capture_output=True, text=True, timeout=120,
+        )
+        assert other.stdout.strip() == path, other.stderr[-2000:]
+
+
 class TestProcessGroupFacade:
     def test_init_defaults_cpu_backend(self):
         g = ptd.init_process_group()

@@ -1,0 +1,143 @@
+"""Every ``pallas_call`` in ``ops/`` must lower for the TPU — checked
+here, on the CPU, in seconds.
+
+Off the chip every kernel runs ``interpret=True``, which skips Mosaic's
+block-spec rules entirely, so the ordinary parity tests cannot see a
+spec the TPU lowering refuses (a 1-row block over a ``[B, T]`` side
+input did exactly that, and the first decode compile of any
+``ServeEngine`` on a chip raised). With interpret mode off,
+``jit(f).trace(...).lower(lowering_platforms=("tpu",))`` runs the same
+checks the chip's compile starts with. Lowering is necessary, not
+sufficient: what Mosaic then makes of VMEM and tiling is only known on
+the chip — ``chip_smoke.py`` compiles and runs the same shapes there.
+"""
+
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import pytorch_distributed_tpu.ops  # noqa: F401 — registers the submodules
+from pytorch_distributed_tpu.ops.flash_attention import flash_attention
+from pytorch_distributed_tpu.ops.paged_attention import paged_attention
+
+# the package re-exports functions under the submodules' names, so the
+# modules themselves are reached through sys.modules
+_FLASH = sys.modules["pytorch_distributed_tpu.ops.flash_attention"]
+_PAGED = sys.modules["pytorch_distributed_tpu.ops.paged_attention"]
+
+# (query heads, kv heads, head dim): GPT-2-medium — the chip smoke's
+# model — and the 32/8 x 128 GQA geometry of the Mistral-width cells
+GEOMETRIES = [(16, 16, 64), (32, 8, 128)]
+
+
+@pytest.fixture(autouse=True)
+def compiled_kernels(monkeypatch):
+    """Interpret mode off: the kernels lower through Mosaic."""
+    monkeypatch.setattr(_FLASH, "_interpret", lambda: False)
+    monkeypatch.setattr(_PAGED, "_interpret", lambda: False)
+
+
+def _assert_lowers_for_tpu(fn, *args):
+    text = jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+    assert "tpu_custom_call" in text
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("window", [None, 256])
+@pytest.mark.parametrize("W", [1, 5])
+@pytest.mark.parametrize("Hq,Hkv,D", GEOMETRIES)
+def test_paged_kernel_lowers(Hq, Hkv, D, W, window, dtype):
+    """Decode (W=1) and the speculative verify (W=5) at batch 8 over a
+    1024-token pool of 16-token pages."""
+    B, ps, n = 8, 16, 64
+    _assert_lowers_for_tpu(
+        lambda q, k, v, t, l: paged_attention(
+            q, k, v, page_tables=t, lengths=l, window=window,
+            impl="kernel",
+        ),
+        _sds((B, W, Hq, D), dtype),
+        _sds((B * n + 1, ps, Hkv, D), dtype),
+        _sds((B * n + 1, ps, Hkv, D), dtype),
+        _sds((B, n), "int32"),
+        _sds((B,), "int32"),
+    )
+
+
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("side", ["plain", "segment_ids", "kv_mask"])
+@pytest.mark.parametrize(
+    "B,S,Hq,Hkv,D", [(8, 1024, 16, 16, 64), (8, 1024, 32, 8, 128),
+                     (2, 8192, 32, 8, 128)],
+)
+def test_flash_kernel_lowers(B, S, Hq, Hkv, D, side, backward):
+    """Forward, and forward + backward (dq and dkv kernels), with and
+    without the per-batch side inputs, at batch > 1."""
+    kw = {}
+    if side == "segment_ids":
+        kw["segment_ids"] = jnp.zeros((B, S), jnp.int32)
+    elif side == "kv_mask":
+        kw["kv_mask"] = jnp.ones((B, S), bool)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True, **kw)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    _assert_lowers_for_tpu(
+        fwd_bwd if backward else fwd,
+        _sds((B, S, Hq, D), "bfloat16"),
+        _sds((B, S, Hkv, D), "bfloat16"),
+        _sds((B, S, Hkv, D), "bfloat16"),
+    )
+
+
+def test_unaligned_lengths_still_lower():
+    """A sequence with no tile-aligned divisor takes one whole-length
+    block (legal on any length) instead of a block the lowering
+    refuses."""
+    B, S = 2, 1000
+    _assert_lowers_for_tpu(
+        lambda q, k, v, m: flash_attention(
+            q, k, v, causal=True, kv_mask=m
+        ),
+        _sds((B, S, 4, 64), "float32"), _sds((B, S, 4, 64), "float32"),
+        _sds((B, S, 4, 64), "float32"), _sds((B, S), "bool"),
+    )
+
+
+def test_engine_decode_program_lowers_with_the_kernel(monkeypatch):
+    """The whole decode tick of a ``ServeEngine`` — scanned layers,
+    per-page writes, the paged kernel, sampling — lowers for the TPU
+    with the Mosaic call inside. The impl flag is patched directly:
+    ``set_paged_attention_impl`` would drop every jit cache in the test
+    process, and a fresh engine has none to go stale."""
+    from pytorch_distributed_tpu.models.gpt2 import GPT2Config, GPT2LMHead
+    from pytorch_distributed_tpu.serve import EngineConfig, ServeEngine
+
+    monkeypatch.setattr(_PAGED, "_IMPL", "kernel")
+    model = GPT2LMHead(GPT2Config.tiny())
+    params = model.init(
+        jax.random.key(0), np.zeros((1, 8), np.int32)
+    )["params"]
+    engine = ServeEngine(model, params, EngineConfig(
+        num_slots=4, max_len=64, prefill_chunk=8, page_size=16,
+    ))
+    text = engine.trace_decode(4).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+    assert "tpu_custom_call" in text
+    # the trace served nothing: the compile ledger is as it was
+    assert engine.decode_compiles == 0 and not engine.decode_buckets
