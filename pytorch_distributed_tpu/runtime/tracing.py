@@ -9,7 +9,12 @@ chaos-drill's wall clock into productive-vs-recovery seconds.
 * :func:`span` — ``with span("data.fetch"):`` around any host-side
   phase. Complete ("X") events carry microsecond ts/dur, pid/tid, so
   ``trace.json`` loads directly in Perfetto / chrome://tracing and
-  spans from loader threads land on their own track.
+  spans from loader threads land on their own track. Every armed span
+  also carries, in its ``args``, a ``span_id`` from a tracer-wide
+  counter and the ``parent_id`` of the live span enclosing it on the
+  same thread (``None`` at the top), so a reader rebuilds the tree of
+  what caused what without comparing intervals; ``sp.set(k=v)`` on a
+  live span adds args known only once its body ran.
 * :func:`instant` / :func:`counter` — point events and gauges (e.g.
   ``device_bytes_in_use``) on the same timeline.
 * :func:`note_compiles` — the recompile sentinel: instrumented code
@@ -62,6 +67,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import itertools
 import json
 import os
 import threading
@@ -97,6 +103,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **args) -> None:
+        """Late args of a live span; ignored while disarmed."""
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -118,23 +127,38 @@ def get_meta() -> Dict[str, Any]:
 
 
 class _Span:
-    """One live span: clock read on enter, record appended on exit."""
+    """One live span: id, parent and clock read on enter, record
+    appended on exit."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_stack")
 
     def __init__(self, tracer: "Tracer", name: str, args):
         self._tracer = tracer
         self._name = name
-        self._args = args
+        # this span's own dict (ids and late args land in it): callers
+        # hand in None, or one dict for every call of a site (ddp.py)
+        self._args = dict(args) if args else {}
 
     def __enter__(self):
-        self._t0 = self._tracer._clock()
+        t = self._tracer
+        stack = self._stack = t._live_stack()
+        span_id = next(t._ids)
+        self._args["span_id"] = span_id
+        self._args["parent_id"] = stack[-1] if stack else None
+        stack.append(span_id)
+        self._t0 = t._clock()
         return self
 
     def __exit__(self, *exc):
         t = self._tracer
-        t.complete(self._name, self._args, self._t0, t._clock())
+        t1 = t._clock()
+        self._stack.pop()
+        t.complete(self._name, self._args, self._t0, t1)
         return False
+
+    def set(self, **args) -> None:
+        """Args known only after the body ran (what a step did)."""
+        self._args.update(args)
 
 
 class Tracer:
@@ -167,6 +191,8 @@ class Tracer:
         self._wall0 = time.time()
         self._pid = os.getpid()
         self._lock = threading.Lock()
+        self._ids = itertools.count(1)  # span ids; next() is atomic
+        self._local = threading.local()  # .stack: this thread's live ids
         self._events: List[Dict[str, Any]] = []
         self.dropped = 0
         self._stats: Dict[str, list] = {}  # name -> [count, total_s, max_s]
@@ -178,6 +204,14 @@ class Tracer:
     # -- recording ---------------------------------------------------------
     def span(self, name: str, args: Optional[dict] = None) -> _Span:
         return _Span(self, name, args)
+
+    def _live_stack(self) -> List[int]:
+        """Ids of the calling thread's live spans, outermost first."""
+        try:
+            return self._local.stack
+        except AttributeError:
+            stack = self._local.stack = []
+            return stack
 
     def _ts_us(self, t: float) -> float:
         return (t - self._t0) * 1e6
@@ -384,7 +418,7 @@ def span(name: str, **args):
     t = _tracer
     if t is None:
         return _NULL_SPAN
-    return _Span(t, name, args or None)
+    return _Span(t, name, args)
 
 
 def instant(name: str, **args) -> None:

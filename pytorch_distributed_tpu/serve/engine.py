@@ -897,12 +897,17 @@ class ServeEngine:
                 "decode-tier engines take work via inject_migration() "
                 "only — route submissions to a prefill or solo engine"
             )
-        self._validate_request(request)
-        handle = RequestHandle(request, submitted_at=self._clock())
-        if request.deadline_s is not None:
-            self._n_deadlines += 1
-        self.scheduler.enqueue(handle)
-        self.telemetry.record_submit(handle)
+        span = (
+            tracing._NULL_SPAN if tracing._tracer is None
+            else tracing.span("serve.submit", request=request.request_id)
+        )
+        with span:
+            self._validate_request(request)
+            handle = RequestHandle(request, submitted_at=self._clock())
+            if request.deadline_s is not None:
+                self._n_deadlines += 1
+            self.scheduler.enqueue(handle)
+            self.telemetry.record_submit(handle)
         return handle
 
     def cancel(self, request_id: str) -> bool:
@@ -1038,10 +1043,50 @@ class ServeEngine:
 
     def step(self) -> bool:
         """One scheduler iteration; returns True when any device work
-        ran (a prefill chunk or a decode tick)."""
-        self._steps += 1
-        # the sweeps scan every live handle — skip them entirely on the
-        # (typical) ticks where no deadline or cancellation exists
+        ran (a prefill chunk or a decode tick).
+
+        Armed, a step is one span tree: ``serve.step`` at the root and
+        every span recorded during it below it (``parent_id``), so the
+        host's own time in a step is the root's duration minus its
+        ``serve.token_fetch`` / ``serve.first_token_fetch`` descendants
+        (the device waits)."""
+        with tracing.span("serve.step") as sp:
+            self._steps += 1
+            # the sweeps scan every live handle — skip them entirely on
+            # the (typical) ticks where no deadline or cancellation exists
+            if self._n_deadlines or self._any_cancel or self._inject_backlog:
+                with tracing.span("serve.sweep"):
+                    self._sweep()
+            if self.scheduler.queue:
+                # chain keys, allocate, the blocked head-of-line retry
+                with tracing.span("serve.schedule"):
+                    if self._store is not None:
+                        self._adopt_from_store()
+                    admitted = self.scheduler.admit(
+                        self.pool, self.draft_pool, tail=self._spec_tail
+                    )
+                for h in admitted:
+                    span = (
+                        tracing._NULL_SPAN if tracing._tracer is None
+                        else tracing.span(
+                            "serve.admit", request=h.request.request_id
+                        )
+                    )
+                    with span:
+                        self._configure_slot(h)
+            chunks = self._run_prefill()
+            decoded = self._run_decode()
+            if self.config.telemetry_every and (
+                self._steps % self.config.telemetry_every == 0
+            ):
+                self._snapshot()
+            did = bool(chunks or decoded)
+            if tracing._tracer is not None:
+                sp.set(did=did, prefill_chunks=chunks, decoded=decoded)
+        return did
+
+    def _sweep(self) -> None:
+        """Deadlines, cancellations and the migration backlog."""
         if self._n_deadlines:
             now = self._clock()
             for h in self.scheduler.sweep_expired(now):
@@ -1052,22 +1097,6 @@ class ServeEngine:
                 self._finish(h, RequestStatus.CANCELLED)
         if self._inject_backlog:
             self._drain_inject_backlog()
-        if self._store is not None and self.scheduler.queue:
-            self._adopt_from_store()
-        for h in self.scheduler.admit(
-            self.pool, self.draft_pool, tail=self._spec_tail
-        ):
-            with tracing.span(
-                "serve.admit", request=h.request.request_id
-            ):
-                self._configure_slot(h)
-        did = self._run_prefill()
-        did = self._run_decode() or did
-        if self.config.telemetry_every and (
-            self._steps % self.config.telemetry_every == 0
-        ):
-            self._snapshot()
-        return did
 
     # -- length buckets + analytic HBM accounting --------------------------
     def _compile_note(self, kind: str, n_pages: int) -> str:
@@ -1231,13 +1260,6 @@ class ServeEngine:
             **gauges,
         )
         if tracing._tracer is not None:
-            tracing.counter("serve.kv_pages_in_use", pool.pages_in_use)
-            tracing.counter(
-                "serve.kv_page_occupancy", gauges["page_occupancy"]
-            )
-            tracing.counter(
-                "serve.prefix_hit_rate", pool.prefix_hit_rate
-            )
             # the decode-path gather tax (and its removal) as recorded
             # facts — plain precomputed ints, armed-only emission
             tracing.counter(
@@ -1247,11 +1269,6 @@ class ServeEngine:
                 "serve.decode_hbm_bytes_per_token",
                 gauges["decode_hbm_bytes_per_token"],
             )
-            if self.spec is not None and self.spec_verifies:
-                tracing.counter(
-                    "serve.spec_accepted_per_verify",
-                    self.spec_accepted / self.spec_verifies,
-                )
 
     def run_until_drained(self, max_steps: int = 1_000_000) -> None:
         """Step until every submitted request reaches a terminal state."""
@@ -1348,11 +1365,19 @@ class ServeEngine:
         )
 
     # -- phase bodies ------------------------------------------------------
-    def _run_prefill(self) -> bool:
+    def _run_prefill(self) -> int:
+        """Plan and dispatch this step's prefill chunks; returns how
+        many were dispatched."""
         cfg = self.config
-        plans = self.scheduler.plan_prefill(cfg.prefill_chunks_per_step)
-        did = False
-        for plan in plans:
+        with tracing.span("serve.prefill_plan"):
+            plans = self.scheduler.plan_prefill(cfg.prefill_chunks_per_step)
+            padded = []
+            for plan in plans:
+                ids = np.zeros((1, cfg.prefill_chunk), np.int32)
+                ids[0, :plan.chunk_len] = plan.ids
+                padded.append(ids)
+        chunks = 0
+        for plan, ids in zip(plans, padded):
             h = plan.handle
             if h.done:  # evicted earlier in this very step's plan list
                 continue
@@ -1362,8 +1387,6 @@ class ServeEngine:
                 except faults.InjectedFault as e:
                     self._finish(h, RequestStatus.FAILED, error=e)
                     continue
-            ids = np.zeros((1, cfg.prefill_chunk), np.int32)
-            ids[0, :plan.chunk_len] = plan.ids
             slot = h.slot
             # the chunk can reach positions [0, start + C): gather the
             # smallest bucket covering them, not the max_len-wide row
@@ -1375,9 +1398,14 @@ class ServeEngine:
             # retrace); ALL slot-row updates — per-chunk length cursor,
             # final-chunk key/token persist — happen inside the one
             # compiled program (eager .at[].set is ms-scale here)
-            with tracing.span(
-                "serve.prefill_chunk", request=h.request.request_id
-            ):
+            span = (
+                tracing._NULL_SPAN if tracing._tracer is None
+                else tracing.span(
+                    "serve.prefill_chunk", request=h.request.request_id,
+                    n_pages=n_pages, start=plan.start, final=plan.final,
+                )
+            )
+            with span:
                 if self.spec is None:
                     tok = self._dispatch_prefill(ids, slot, plan, n_pages)
                 else:
@@ -1398,7 +1426,7 @@ class ServeEngine:
             self.pool.lengths[slot] = plan.start + plan.chunk_len
             if cfg.prefill_delay_s:
                 time.sleep(cfg.prefill_delay_s * plan.chunk_len)
-            did = True
+            chunks += 1
             if plan.final:
                 # the slot's full prompt pages now hold canonical KV —
                 # publish them for copy-free sharing by later admissions
@@ -1420,7 +1448,9 @@ class ServeEngine:
                                 "serve.kv_migrate",
                                 path=h.request.request_id,
                             )
-                        frame = self._pack_migration(h, int(tok))
+                        frame = self._pack_migration(
+                            h, self._first_token(h, tok)
+                        )
                     except faults.InjectedFault as e:
                         self._finish(h, RequestStatus.FAILED, error=e)
                         continue
@@ -1430,10 +1460,33 @@ class ServeEngine:
                     continue
                 self.scheduler.prefill_finished(h)
                 self._decoding_dirty = True
-                self._emit(h, int(tok))
-        return did
+                self._emit(h, self._first_token(h, tok))
+        return chunks
 
-    def _run_decode(self) -> bool:
+    def _first_token(self, h: RequestHandle, tok) -> int:
+        """The final chunk's sampled token, read down to the host: a
+        wait for the device (the chunk was only dispatched)."""
+        span = (
+            tracing._NULL_SPAN if tracing._tracer is None
+            else tracing.span(
+                "serve.first_token_fetch", request=h.request.request_id
+            )
+        )
+        with span:
+            return int(tok)
+
+    def _live_pages(self, decoding) -> int:
+        """Pages the tick's kernel computes on: for each active row,
+        those its length and the tick's write span reach (the kernel
+        skips a row's later grid steps; the grid still walks them)."""
+        W = 1 if self.spec is None else self.spec.num_draft_tokens + 1
+        ps = self.pool.page_size
+        lengths = self.pool.lengths
+        return sum(-(-(int(lengths[slot]) + W) // ps) for slot, _ in decoding)
+
+    def _run_decode(self) -> int:
+        """One decode tick over the decoding rows; returns how many
+        rows it carried (0: no tick)."""
         if self._decoding_dirty:
             self._decoding_cached = self.scheduler.decoding()
             active = np.zeros(self.config.num_slots, bool)
@@ -1443,7 +1496,7 @@ class ServeEngine:
             self._decoding_dirty = False
         decoding = self._decoding_cached
         if not decoding:
-            return False
+            return 0
         self._decode_ticks += 1
         n_pages = self._tick_bucket(decoding)
         if self.spec is not None:
@@ -1455,7 +1508,10 @@ class ServeEngine:
         # the serving hot path — disarmed cost stays one is-None test
         span = (
             tracing._NULL_SPAN if tracing._tracer is None
-            else tracing.span("serve.decode_tick", active=len(decoding))
+            else tracing.span(
+                "serve.decode_tick", active=len(decoding), n_pages=n_pages,
+                live_pages=self._live_pages(decoding),
+            )
         )
         with span:
             (
@@ -1481,18 +1537,21 @@ class ServeEngine:
             # the one per-tick device sync: every sampled token comes down
             nxt = np.asarray(nxt)
         fault_armed = faults.active()
-        for slot, h in decoding:
-            # the tick wrote this slot's token at lengths[slot]; mirror
-            # the in-program length advance, then judge the token
-            self.pool.lengths[slot] += 1
-            if fault_armed:
-                try:
-                    faults.check("serve.decode", path=h.request.request_id)
-                except faults.InjectedFault as e:
-                    self._finish(h, RequestStatus.FAILED, error=e)
-                    continue
-            self._emit(h, int(nxt[slot]))
-        return True
+        with tracing.span("serve.emit"):
+            for slot, h in decoding:
+                # the tick wrote this slot's token at lengths[slot];
+                # mirror the in-program length advance, then judge it
+                self.pool.lengths[slot] += 1
+                if fault_armed:
+                    try:
+                        faults.check(
+                            "serve.decode", path=h.request.request_id
+                        )
+                    except faults.InjectedFault as e:
+                        self._finish(h, RequestStatus.FAILED, error=e)
+                        continue
+                self._emit(h, int(nxt[slot]))
+        return len(decoding)
 
     def _dispatch_prefill(self, ids, slot, plan, n_pages):
         """One plain prefill-chunk dispatch; the donated pool buffer is
@@ -1526,13 +1585,14 @@ class ServeEngine:
         self.draft_pool.lengths[slot] = plan.start + plan.chunk_len
         return tok
 
-    def _run_spec_tick(self, decoding, n_pages) -> bool:
+    def _run_spec_tick(self, decoding, n_pages) -> int:
         """One fused draft+verify tick; emits 1..k+1 tokens/request."""
         span = (
             tracing._NULL_SPAN if tracing._tracer is None
             else tracing.span(
                 "serve.spec_tick", active=len(decoding),
-                k=self.spec.num_draft_tokens,
+                k=self.spec.num_draft_tokens, n_pages=n_pages,
+                live_pages=self._live_pages(decoding),
             )
         )
         with span:
@@ -1562,28 +1622,31 @@ class ServeEngine:
         k = self.spec.num_draft_tokens
         self.spec_verifies += 1
         fault_armed = faults.active()
-        for slot, h in decoding:
-            n = int(acc[slot]) + 1
-            self._decode_tokens += n
-            # mirror the in-program advances: the verify wrote k+1
-            # entries but only a+1 became sequence; the rejected tail
-            # sits beyond the accepted length where the next tick's
-            # chunk write lands before anything attends it
-            self.pool.lengths[slot] += n
-            self.draft_pool.lengths[slot] += n
-            self.spec_drafted += k
-            self.spec_accepted += n - 1
-            if fault_armed:
-                try:
-                    faults.check("serve.decode", path=h.request.request_id)
-                except faults.InjectedFault as e:
-                    self._finish(h, RequestStatus.FAILED, error=e)
-                    continue
-            for j in range(n):
-                self._emit(h, int(emit[slot, j]))
-                if h.done:  # eos / max_new truncation retires the row
-                    break
-        return True
+        with tracing.span("serve.emit"):
+            for slot, h in decoding:
+                n = int(acc[slot]) + 1
+                self._decode_tokens += n
+                # mirror the in-program advances: the verify wrote k+1
+                # entries but only a+1 became sequence; the rejected
+                # tail sits beyond the accepted length where the next
+                # tick's chunk write lands before anything attends it
+                self.pool.lengths[slot] += n
+                self.draft_pool.lengths[slot] += n
+                self.spec_drafted += k
+                self.spec_accepted += n - 1
+                if fault_armed:
+                    try:
+                        faults.check(
+                            "serve.decode", path=h.request.request_id
+                        )
+                    except faults.InjectedFault as e:
+                        self._finish(h, RequestStatus.FAILED, error=e)
+                        continue
+                for j in range(n):
+                    self._emit(h, int(emit[slot, j]))
+                    if h.done:  # eos / max_new truncation retires it
+                        break
+        return len(decoding)
 
     # -- emission / retirement ---------------------------------------------
     def _emit(self, h: RequestHandle, token: int) -> None:
@@ -1609,10 +1672,14 @@ class ServeEngine:
         if h.request.deadline_s is not None:
             self._n_deadlines -= 1
         self._decoding_dirty = True
-        with tracing.span(
-            "serve.evict",
-            request=h.request.request_id, status=status.value,
-        ):
+        span = (
+            tracing._NULL_SPAN if tracing._tracer is None
+            else tracing.span(
+                "serve.evict",
+                request=h.request.request_id, status=status.value,
+            )
+        )
+        with span:
             self.scheduler.release(h, self.pool, self.draft_pool)
         self.telemetry.record_done(h)
         if status is RequestStatus.FAILED:

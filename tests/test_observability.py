@@ -107,7 +107,9 @@ class TestSpans:
         # and its interval is contained in outer's
         assert outer["ts"] <= inner["ts"]
         assert (inner["ts"] + inner["dur"]) <= (outer["ts"] + outer["dur"])
-        assert outer["args"] == {"phase": "a"}
+        # the caller's keys (an armed span adds span_id / parent_id)
+        assert outer["args"]["phase"] == "a"
+        assert inner["args"]["parent_id"] == outer["args"]["span_id"]
         assert inner["tid"] == outer["tid"]
 
     def test_trace_json_schema(self, tmp_path):
