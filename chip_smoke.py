@@ -169,8 +169,9 @@ def check_kernels(sizes: Sizes, on_chip: bool) -> None:
                 return jnp.asarray(rng.standard_normal(shape), dtype)
 
             # -- paged decode (W=1) and speculative verify (W=5) ----------
-            k_pages = rand(B * n + 1, ps, Hkv, D).at[0].set(0)
-            v_pages = rand(B * n + 1, ps, Hkv, D).at[0].set(0)
+            # the pool as it is stored: frames lane-dense, [ps, Hkv * D]
+            k_pages = rand(B * n + 1, ps, Hkv * D).at[0].set(0)
+            v_pages = rand(B * n + 1, ps, Hkv * D).at[0].set(0)
             tables = jnp.asarray(
                 rng.permutation(np.arange(1, B * n + 1)).reshape(B, n),
                 jnp.int32,

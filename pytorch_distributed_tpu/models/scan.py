@@ -25,6 +25,7 @@ from __future__ import annotations
 from typing import Callable, Optional, Tuple, Type
 
 import flax.linen as nn
+import jax.numpy as jnp
 
 
 def remat_policy(name: Optional[str]):
@@ -74,13 +75,35 @@ def scan_stack(
     need the full ~16 GB bf16 reconstruction. Plain (unquantized)
     leaves pass through untouched, so initializing with the flag on
     still works and quantization stays a post-training transform.
+
+    Under an active ``ops.paged_attention.PagedView`` (the serving
+    engine's paged programs) the ``cache`` collection is the page pool,
+    and it rides the loop as a CARRY, whole, instead of being sliced
+    per layer and stacked back: an XLA ``while`` cannot alias an ``xs``
+    slice with a ``ys`` slice, so a scanned pool leaf is copied through
+    the loop on every call. The loop counts its own layers
+    beside the activation and names each to the view
+    (``paged_layer``), which is how ``decode_cache`` and ``attention``
+    find their plane — the blocks, and the models, learn nothing of it.
     """
+    from pytorch_distributed_tpu.ops.paged_attention import (
+        active_view,
+        paged_layer,
+    )
+
     use_remat = cfg.remat if remat is None else remat
+    paged = active_view() is not None
 
     class Body(nn.Module):
         @nn.compact
-        def __call__(self, x, *bcast):
-            return block_cls(cfg, name="block")(x, *bcast), None
+        def __call__(self, carry, *bcast):
+            block = block_cls(cfg, name="block")
+            if not paged:
+                return block(carry, *bcast), None
+            x, layer = carry
+            with paged_layer(layer):
+                x = block(x, *bcast)
+            return (x, layer + 1), None
 
     if getattr(cfg, "scan_dequant", False):
         from pytorch_distributed_tpu.ops.quant import dequantize_tree
@@ -111,18 +134,26 @@ def scan_stack(
         if use_remat
         else Body
     )
+    # cache: per-layer KV decode caches stack [L, ...] like params (a
+    # page pool is carried instead, see above); intermediates:
+    # per-layer sown values (e.g. MoE aux losses)
+    axes = {"params": 0, "cache": 0, "intermediates": 0}
+    if paged:
+        del axes["cache"]
     mod = nn.scan(
         body,
-        # cache: per-layer KV decode caches stack [L, ...] like params;
-        # intermediates: per-layer sown values (e.g. MoE aux losses)
-        variable_axes={"params": 0, "cache": 0, "intermediates": 0},
+        variable_axes=axes,
+        variable_carry="cache" if paged else False,
         split_rngs={"params": True, "dropout": True},
         in_axes=nn.broadcast,
         length=length if length is not None else cfg.num_layers,
     )(name=name)
 
     def apply_stack(x, *bcast):
-        y, _ = mod(x, *bcast)
+        if paged:
+            (y, _), _ = mod((x, jnp.zeros((), jnp.int32)), *bcast)
+        else:
+            y, _ = mod(x, *bcast)
         return y
 
     return apply_stack

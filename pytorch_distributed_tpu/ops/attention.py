@@ -288,7 +288,7 @@ def decode_cache(
 
     Under an active :class:`ops.paged_attention.PagedView` (the serving
     engine's paged decode programs), the cache variables are the PAGE
-    POOL (``[num_pages + 1, page_size, H, D]`` frames, initialized by
+    POOL (``[num_pages + 1, page_size, H * D]`` frames, initialized by
     ``serve.kv_slots.init_page_cache``): the write narrows to a
     per-page scatter of only the W deliberately-written positions
     (``paged_write`` — inactive rows drop theirs entirely, never a
@@ -383,7 +383,11 @@ def _decode_cache_paged(module, k, v, quantize, write_pos, pv):
     engine builds them via ``serve.kv_slots.init_page_cache``); a dense
     ``[B, max_len, ...]`` buffer here means a caller installed a
     ``PagedView`` around a cache it never paged — refused loudly, since
-    the write arithmetic below would silently corrupt it.
+    the write arithmetic below would silently corrupt it. Under a layer
+    scan the variables are the whole STACKED leaves (models/scan.py
+    carries them) and ``pv.layer`` names this layer's plane: the write
+    is one scatter into it and what is returned is still the whole
+    leaf, which :func:`attention` hands on with the same layer.
     """
     from pytorch_distributed_tpu.ops.paged_attention import (
         PagedKVQuant,
@@ -395,49 +399,35 @@ def _decode_cache_paged(module, k, v, quantize, write_pos, pv):
             "paged decode (an active PagedView) requires write_pos — "
             "the lockstep cache_index form has no page-table row"
         )
-    B, S, H, D = k.shape
-    names = (
-        ("cached_key", "cached_value", "cached_key_scale",
-         "cached_value_scale")
-    )
+    names = ["cached_key", "cached_value"]
+    news = [k, v]
     if quantize == "int8":
-        ck = module.variable("cache", names[0], None)
-        cks = module.variable("cache", names[2], None)
-        cv = module.variable("cache", names[1], None)
-        cvs = module.variable("cache", names[3], None)
-    else:
-        ck = module.variable("cache", names[0], None)
-        cv = module.variable("cache", names[1], None)
-    if ck.value is None or ck.value.shape[1] != pv.page_size:
+        (qk, sk), (qv, sv) = _q8_rows(k), _q8_rows(v)
+        names += ["cached_key_scale", "cached_value_scale"]
+        news = [qk, qv, sk, sv]
+    pools = [module.variable("cache", name, None) for name in names]
+    ck = pools[0].value
+    rank = 3 if pv.layer is None else 4
+    if ck is None or ck.ndim != rank or ck.shape[-2] != pv.page_size:
         raise ValueError(
-            f"paged decode needs a page-pool cache "
-            f"([num_pages + 1, page_size={pv.page_size}, H, D], from "
-            f"serve.kv_slots.init_page_cache); found "
-            f"{None if ck.value is None else ck.value.shape}"
+            f"paged decode needs a page-pool cache ([num_pages + 1, "
+            f"page_size={pv.page_size}, H * D] from "
+            f"serve.kv_slots.init_page_cache, stacked [L, ...] exactly "
+            f"when a layer scan names the layer); found "
+            f"{None if ck is None else ck.shape} with layer "
+            f"{'None' if pv.layer is None else 'given'}"
+        )
+    for pool, new in zip(pools, news):
+        pool.value = paged_write(
+            pool.value, new, pv.page_tables, write_pos, pv.keep, pv.layer
         )
     if quantize == "int8":
-        qk, sk = _q8_rows(k)
-        qv, sv = _q8_rows(v)
-        ck.value = paged_write(
-            ck.value, qk, pv.page_tables, write_pos, pv.keep
-        )
-        cks.value = paged_write(
-            cks.value, sk, pv.page_tables, write_pos, pv.keep
-        )
-        cv.value = paged_write(
-            cv.value, qv, pv.page_tables, write_pos, pv.keep
-        )
-        cvs.value = paged_write(
-            cvs.value, sv, pv.page_tables, write_pos, pv.keep
-        )
         return (
-            PagedKVQuant(ck.value, cks.value, k.dtype),
-            PagedKVQuant(cv.value, cvs.value, v.dtype),
+            PagedKVQuant(pools[0].value, pools[2].value, k.dtype),
+            PagedKVQuant(pools[1].value, pools[3].value, v.dtype),
             write_pos,
         )
-    ck.value = paged_write(ck.value, k, pv.page_tables, write_pos, pv.keep)
-    cv.value = paged_write(cv.value, v, pv.page_tables, write_pos, pv.keep)
-    return ck.value, cv.value, write_pos
+    return pools[0].value, pools[1].value, write_pos
 
 
 # --------------------------------------------------------------------------
@@ -539,7 +529,7 @@ def attention(
             )
         return _paged_attention(
             q, k, v, page_tables=pv.page_tables, lengths=q_offset,
-            scale=scale, window=window,
+            layer=pv.layer, scale=scale, window=window,
         )
 
     # q_offset may be a traced value (KV-cache decode); only a static

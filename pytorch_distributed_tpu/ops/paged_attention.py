@@ -11,10 +11,11 @@ design (vLLM, arXiv 2309.06180) expressed with the repo's own blocked
 online-softmax machinery (ops/flash_attention.py):
 
 * the decode-attention primitive takes the pooled KV frames
-  ``[num_pages + 1, page_size, Hkv, D]`` (frame 0 the reserved null
-  page), per-request page tables ``[B, n_pages]`` and per-row lengths,
-  and computes ``[B, W, Hq, D]`` attention for W queries per row
-  (W = 1 for the decode tick, W = k+1 for the fused speculative
+  ``[num_pages + 1, page_size, Hkv * D]`` (frame 0 the reserved null
+  page; with a leading ``[L]`` and a ``layer`` index when the layers
+  are scanned), per-request page tables ``[B, n_pages]`` and per-row
+  lengths, and computes ``[B, W, Hq, D]`` attention for W queries per
+  row (W = 1 for the decode tick, W = k+1 for the fused speculative
   verify) with ragged lengths masked INSIDE the op — no caller-side
   dense view;
 * the engine installs a :class:`PagedView` (the adapter object) around
@@ -46,10 +47,9 @@ Three interchangeable implementations, selected by
   per page of one row serving ALL heads, page frames resolved through
   the scalar-prefetched page table (``pltpu.PrefetchScalarGridSpec`` —
   the index map reads the table, so the DMA streams exactly the pages
-  the row owns). The ``[P1, ps, Hkv, D]`` pool is viewed
-  ``[P1, ps, Hkv * D]`` (a free reshape), so a frame arrives as one
-  lane-dense tile and kv head ``h`` is the static lane range
-  ``[h * D, (h + 1) * D)``; a query head's group shares its kv head's
+  the row owns). The pool is stored ``[P1, ps, Hkv * D]``, so a frame
+  arrives as one lane-dense tile and kv head ``h`` is the static lane
+  range ``[h * D, (h + 1) * D)``; a query head's group shares its kv head's
   tile (no KV replication to q heads); the online-softmax carry lives in
   VMEM scratch. ``interpret=True`` off-TPU, like every Pallas kernel in
   this repo.
@@ -118,6 +118,11 @@ class PagedView:
     page_tables: jnp.ndarray  # [B, n_pages] int32, bucket-sliced
     keep: jnp.ndarray         # [B] bool — write gate per row
     page_size: int
+    # the plane of a STACKED leaf ([L, P1, ps, ...], layers under
+    # nn.scan) this layer reads and writes: a traced int32 scalar the
+    # layer loop hands down (:func:`paged_layer`); None for per-layer
+    # leaves ([P1, ps, ...], an unrolled stack)
+    layer: Optional[jnp.ndarray] = None
 
 
 _VIEW: Optional[PagedView] = None
@@ -144,6 +149,12 @@ def active_view() -> Optional[PagedView]:
     return _VIEW
 
 
+def paged_layer(layer):
+    """Inside a layer loop's body (models/scan.py): the active view
+    with ``layer`` naming this iteration's plane of the stacked leaves."""
+    return paged_view(dataclasses.replace(_VIEW, layer=layer))
+
+
 class PagedKVQuant(NamedTuple):
     """An int8 page pool + its per-token scale pool, moving as one.
 
@@ -153,8 +164,8 @@ class PagedKVQuant(NamedTuple):
     ``int8 -> f32 * scale -> dtype`` formula the dense path used.
     """
 
-    pages: jnp.ndarray   # [P1, ps, H, D] int8
-    scale: jnp.ndarray   # [P1, ps, H, 1] f32
+    pages: jnp.ndarray   # [P1, ps, H * D] int8
+    scale: jnp.ndarray   # [P1, ps, H] f32
     dtype: jnp.dtype     # the compute dtype attention should see
 
 
@@ -163,19 +174,22 @@ class PagedKVQuant(NamedTuple):
 # --------------------------------------------------------------------------
 
 
-def paged_write(pool, new, page_tables, write_pos, keep):
+def paged_write(pool, new, page_tables, write_pos, keep, layer=None):
     """Scatter ``new`` rows into the page pool through the page table.
 
-    ``pool`` is ``[num_pages + 1, page_size, ...]``; ``new`` is
-    ``[B, W, ...]``: row ``b``'s W entries land at buffer positions
-    ``write_pos[b] .. write_pos[b] + W - 1``, each mapped to
-    ``page_tables[b, pos // page_size] * page_size + pos % page_size``.
-    ``keep[b]`` False redirects the row's destinations out of bounds so
-    ``mode="drop"`` discards them — free and mid-prefill rows never
-    touch the pool, the invariant ``serve.kv_slots.scatter_kv``
-    established (a kept row's positions sit inside its privately-owned
-    span by the pool's CoW admission discipline, so a refcount>1 page
-    can never be written).
+    ``pool`` is ``[num_pages + 1, page_size, F]`` (``layer`` None) or
+    the stacked ``[L, num_pages + 1, page_size, F]`` with ``layer`` its
+    plane; ``new`` is ``[B, W, ...]`` with ``F`` elements a position:
+    row ``b``'s W entries land at buffer positions ``write_pos[b] ..
+    write_pos[b] + W - 1``, each mapped to frame ``page_tables[b, pos //
+    page_size]``, row ``pos % page_size``. ONE scatter into the leaf
+    where it lies — no reshape, slice or restack of it — so a donated
+    pool is updated in place. ``keep[b]`` False redirects the row's
+    frames out of bounds so ``mode="drop"`` discards them — free and
+    mid-prefill rows never touch the pool, the invariant
+    ``serve.kv_slots.scatter_kv`` established (a kept row's positions
+    sit inside its privately-owned span by the pool's CoW admission
+    discipline, so a refcount>1 page can never be written).
 
     Only ever traced inside the engine's jitted programs (it is called
     from ``decode_cache`` under the model apply those programs trace) —
@@ -183,21 +197,21 @@ def paged_write(pool, new, page_tables, write_pos, keep):
     for, which is why the lint fixture corpus carries a twin of this
     helper.
     """
-    P1, ps = pool.shape[0], pool.shape[1]
+    P1, ps, F = pool.shape[-3:]
     B, W = new.shape[0], new.shape[1]
     pos = write_pos[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
     # positions beyond the (bucket-sliced) table clamp; such rows are
     # always keep=False, so the clamped index is dropped below anyway
     page = jnp.take_along_axis(page_tables, pos // ps, axis=1)
-    dst = page * ps + pos % ps                         # [B, W]
-    dst = jnp.where(keep[:, None], dst, P1 * ps)       # OOB -> drop
-    flat = pool.reshape((P1 * ps,) + pool.shape[2:])
-    upd = new.astype(pool.dtype).reshape((B * W,) + new.shape[2:])
-    flat = flat.at[dst.reshape(-1)].set(  # ptdlint: disable=PTD004
+    page = jnp.where(keep[:, None], page, P1)          # OOB -> drop
+    idx = (page.reshape(-1), (pos % ps).reshape(-1))
+    if layer is not None:
+        idx = (layer,) + idx
+    upd = new.astype(pool.dtype).reshape(B * W, F)
+    return pool.at[idx].set(  # ptdlint: disable=PTD004
         upd, mode="drop",
     )  # fused scatter: traced only inside the engine's jitted programs
     # (cross-module, so the per-module lint closure cannot see the jit)
-    return flat.reshape(pool.shape)
 
 
 # --------------------------------------------------------------------------
@@ -265,13 +279,21 @@ def _unpack(kv):
     return kv, None, None
 
 
+def _plane(layer, frames):
+    """Index of ``frames`` in a pool leaf: straight into the leaf when
+    it is per-layer, into plane ``layer`` when it is stacked — one
+    gather either way, never a slice of the plane first."""
+    return (frames,) if layer is None else (layer, frames)
+
+
 def paged_attention(
     q: jnp.ndarray,   # [B, W, Hq, D]
-    k_pages,          # [P1, ps, Hkv, D] or PagedKVQuant
-    v_pages,          # [P1, ps, Hkv, D] or PagedKVQuant
+    k_pages,          # [P1, ps, Hkv * D] or PagedKVQuant
+    v_pages,          # [P1, ps, Hkv * D] or PagedKVQuant
     *,
     page_tables: jnp.ndarray,  # [B, n_pages] int32 (bucket-sliced)
     lengths: jnp.ndarray,      # [B] int32 — tokens cached BEFORE this call
+    layer=None,                # int32 scalar: pools are [L, P1, ps, ...]
     scale: Optional[float] = None,
     window: Optional[int] = None,
     impl: Optional[str] = None,
@@ -288,13 +310,25 @@ def paged_attention(
     dense path always did). Unused table entries hold null page 0;
     they back positions ``>= lengths[b] + W`` and are causally masked,
     so the null page's contents are unobservable (pinned by test).
+
+    With ``layer`` the pools are the STACKED leaves of a scanned model
+    and every impl reads plane ``layer`` of them in place.
     """
     k_pages, k_scale, kdt = _unpack(k_pages)
     v_pages, v_scale, _ = _unpack(v_pages)
     B, W, Hq, D = q.shape
-    P1, ps, Hkv, Dk = k_pages.shape
-    if D != Dk:
-        raise ValueError(f"head_dim mismatch: q {D} vs pool {Dk}")
+    if k_pages.ndim != (3 if layer is None else 4):
+        raise ValueError(
+            f"the pool must be [P1, ps, Hkv * D], or [L, P1, ps, Hkv * D] "
+            f"with its layer; got {k_pages.shape} and layer "
+            f"{'None' if layer is None else 'given'}"
+        )
+    P1, ps, F = k_pages.shape[-3:]
+    if F % D:
+        raise ValueError(
+            f"head_dim mismatch: q {D} does not divide the pool's {F}"
+        )
+    Hkv = F // D
     if Hq % Hkv:
         raise ValueError(
             f"query heads {Hq} not a multiple of kv heads {Hkv}"
@@ -316,16 +350,16 @@ def paged_attention(
     if impl == "gather":
         return _paged_gather(
             q, k_pages, v_pages, page_tables, lengths, scale, window,
-            k_scale, v_scale, kdt,
+            k_scale, v_scale, kdt, layer,
         )
     if impl == "stream":
         return paged_attention_reference(
             q, k_pages, v_pages, page_tables=page_tables, lengths=lengths,
-            scale=scale, window=window, k_scale=k_scale, v_scale=v_scale,
-            out_dtype=kdt,
+            layer=layer, scale=scale, window=window, k_scale=k_scale,
+            v_scale=v_scale, out_dtype=kdt,
         )
     return _paged_kernel_call(
-        q, k_pages, v_pages, page_tables, lengths, scale, window
+        q, k_pages, v_pages, page_tables, lengths, layer, scale, window
     )
 
 
@@ -334,32 +368,38 @@ def paged_attention(
 # --------------------------------------------------------------------------
 
 
-def _gather_dense(pages, tables, scale_pages, dtype):
-    """[P1, ps, H, D] + [B, n] tables -> [B, n*ps, H, D] dense slab
-    (dequantized with decode_cache's exact formula when scales ride)."""
-    B, n = tables.shape
-    ps = pages.shape[1]
-    flat = tables.reshape(-1)
-    out = jnp.take(pages, flat, axis=0)
+def _take_frames(pages, scale_pages, frames, layer, D, dtype):
+    """``frames`` ([N] ids) of a pool -> ``[N, ps, Hkv, D]``, dequantized
+    with decode_cache's exact formula when scales ride."""
+    idx = _plane(layer, frames)
+    out = pages[idx]                                  # [N, ps, Hkv * D]
+    out = out.reshape(out.shape[:2] + (-1, D))
     if scale_pages is not None:
-        sc = jnp.take(scale_pages, flat, axis=0)
+        sc = scale_pages[idx][..., None]              # [N, ps, Hkv, 1]
         out = (out.astype(jnp.float32) * sc).astype(dtype)
-    return out.reshape((B, n * ps) + pages.shape[2:])
+    return out
 
 
 def _paged_gather(q, k_pages, v_pages, tables, lengths, scale, window,
-                  k_scale, v_scale, kdt):
+                  k_scale, v_scale, kdt, layer):
     """The exact impl: materialize the bucket slab, run the SAME
     ``dot_product_attention`` the dense engine path ran. Masked tail
     keys contribute exact zeros to every reduction (the zero-tail
     argument), so the output is bitwise the pre-paged path's."""
     from pytorch_distributed_tpu.ops.attention import dot_product_attention
 
-    kd = _gather_dense(k_pages, tables, k_scale, kdt or q.dtype)
-    vd = _gather_dense(v_pages, tables, v_scale, kdt or q.dtype)
+    B, n = tables.shape
+    D = q.shape[-1]
+
+    def dense(pages, scales):
+        out = _take_frames(
+            pages, scales, tables.reshape(-1), layer, D, kdt or q.dtype
+        )
+        return out.reshape((B, n * out.shape[1]) + out.shape[2:])
+
     return dot_product_attention(
-        q, kd, vd, causal=True, q_offset=lengths, scale=scale,
-        window=window,
+        q, dense(k_pages, k_scale), dense(v_pages, v_scale), causal=True,
+        q_offset=lengths, scale=scale, window=window,
     )
 
 
@@ -369,7 +409,7 @@ def _paged_gather(q, k_pages, v_pages, tables, lengths, scale, window,
 
 
 def paged_attention_reference(
-    q, k_pages, v_pages, *, page_tables, lengths,
+    q, k_pages, v_pages, *, page_tables, lengths, layer=None,
     scale: Optional[float] = None, window: Optional[int] = None,
     k_scale=None, v_scale=None, out_dtype=None,
 ):
@@ -384,7 +424,7 @@ def paged_attention_reference(
     is the bit-exact one.
     """
     B, W, Hq, D = q.shape
-    P1, ps, Hkv, _ = k_pages.shape
+    ps, Hkv = k_pages.shape[-2], k_pages.shape[-1] // D
     G = Hq // Hkv
     n = page_tables.shape[1]
     if scale is None:
@@ -393,13 +433,10 @@ def paged_attention_reference(
     qg = q.reshape(B, W, Hkv, G, D)
     qpos = lengths[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
 
-    def page(pages, scales, i):
-        frames = page_tables[:, i]                # [B]
-        out = jnp.take(pages, frames, axis=0)     # [B, ps, Hkv, D]
-        if scales is not None:
-            sc = jnp.take(scales, frames, axis=0)
-            out = (out.astype(jnp.float32) * sc).astype(dtype)
-        return out
+    def page(pages, scales, i):               # -> [B, ps, Hkv, D]
+        return _take_frames(
+            pages, scales, page_tables[:, i], layer, D, dtype
+        )
 
     def body(carry, i):
         m, l, acc = carry
@@ -444,9 +481,10 @@ def paged_attention_reference(
 # --------------------------------------------------------------------------
 
 
-def _kernel_body(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
-                 acc_ref, m_ref, l_ref, *, sm_scale, page_size, hkv, g, w,
-                 d, window):
+def _kernel_body(lengths_ref, tables_ref, *refs, sm_scale, page_size, hkv,
+                 g, w, d, window):
+    # a stacked pool prefetches its layer too: only the index maps read it
+    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[-7:]
     b = pl.program_id(0)
     i = pl.program_id(1)
     n = pl.num_programs(1)
@@ -510,38 +548,43 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, scale,
+def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, layer, scale,
                        window):
     B, W, Hq, D = q.shape
-    P1, ps, Hkv, _ = k_pages.shape
+    ps, F = k_pages.shape[-2:]
+    Hkv = F // D
     G = Hq // Hkv
     n = tables.shape[1]
     # one grid step serves ALL heads of one page of one row. The TPU
     # lowering wants the last two block dims to be (8, 128)-divisible or
-    # whole: a per-head (1, ps, 1, D) block over the [P1, ps, Hkv, D]
-    # pool is neither, so the pool is viewed [P1, ps, Hkv * D] (a free
-    # reshape of its two minor dims) and the block is the whole frame.
+    # whole: the block is the whole [ps, Hkv * D] frame, as the pool
+    # stores it — the operand is the leaf itself, never a view of it.
     # Queries go [B, Hkv, W * G, D], rows zero-padded to the sublane tile
     rows = -(-W * G // 8) * 8
     qf = q.reshape(B, W, Hkv, G, D).transpose(0, 2, 1, 3, 4)
     qf = qf.reshape(B, Hkv, W * G, D)
     qf = jnp.pad(qf, ((0, 0), (0, 0), (0, rows - W * G), (0, 0)))
 
-    def kv_map(b, i, lens, tabs):
-        # the page frame comes from the scalar-prefetched table — the
-        # DMA streams exactly the pages this row owns
-        return (tabs[b, i], 0, 0)
-
-    q_map = lambda b, i, lens, tabs: (b, 0, 0, 0)
+    # the page frame comes from the scalar-prefetched table — the DMA
+    # streams exactly the pages this row owns — and, for a stacked
+    # pool, the plane from the prefetched layer
+    scalars = (lengths.astype(jnp.int32), tables.astype(jnp.int32))
+    if layer is None:
+        kv_spec = pl.BlockSpec(
+            (1, ps, F), lambda b, i, lens, tabs: (tabs[b, i], 0, 0)
+        )
+    else:
+        scalars += (jnp.asarray(layer, jnp.int32).reshape(1),)
+        kv_spec = pl.BlockSpec(
+            (None, 1, ps, F),
+            lambda b, i, lens, tabs, lay: (lay[0], tabs[b, i], 0, 0),
+        )
+    q_spec = pl.BlockSpec((1, Hkv, rows, D), lambda b, i, *_: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(B, n),
-        in_specs=[
-            pl.BlockSpec((1, Hkv, rows, D), q_map),
-            pl.BlockSpec((1, ps, Hkv * D), kv_map),
-            pl.BlockSpec((1, ps, Hkv * D), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, Hkv, rows, D), q_map),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((Hkv, rows, D), jnp.float32),    # acc
             pltpu.VMEM((Hkv, rows, 128), jnp.float32),  # running max
@@ -563,7 +606,6 @@ def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, scale,
         ),
         interpret=_interpret(),
         name="paged_attention",
-    )(lengths.astype(jnp.int32), tables.astype(jnp.int32), qf,
-      k_pages.reshape(P1, ps, Hkv * D), v_pages.reshape(P1, ps, Hkv * D))
+    )(*scalars, qf, k_pages, v_pages)
     out = out[:, :, :W * G].reshape(B, Hkv, W, G, D)
     return out.transpose(0, 2, 1, 3, 4).reshape(B, W, Hq, D)

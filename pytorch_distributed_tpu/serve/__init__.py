@@ -84,6 +84,7 @@ from pytorch_distributed_tpu.serve.kv_slots import (
     frame_signature,
     gather_pages,
     init_page_cache,
+    page_axis,
     scatter_kv,
     splice_frames,
 )
@@ -130,6 +131,7 @@ __all__ = [
     "frame_signature",
     "gather_pages",
     "init_page_cache",
+    "page_axis",
     "prefix_shared_requests",
     "recv_frame",
     "roundtrip_frame",

@@ -72,7 +72,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from pytorch_distributed_tpu.generation import (
-    cache_batch_axis,
     decode_step_body,
     model_max_len,
 )
@@ -93,6 +92,7 @@ from pytorch_distributed_tpu.runtime import tracing
 from pytorch_distributed_tpu.serve.kv_slots import (
     PagedKVPool,
     extract_frames,
+    frame_nbytes,
     frame_signature,
     gather_pages,
     scatter_kv,
@@ -403,9 +403,11 @@ class ServeEngine:
             refuse_kernel_for(quantized=getattr(
                 getattr(model, "config", None), "kv_cache_quantize", None
             ) is not None)
-        self._frame_bytes_target = self._frame_bytes(self.pool.cache)
+        # bytes of ONE page frame across every KV-payload leaf (layer
+        # stacking included) — the unit of the analytic HBM accounting
+        self._frame_bytes_target = frame_nbytes(self.pool.cache)
         self._frame_bytes_draft = (
-            self._frame_bytes(self.draft_pool.cache)
+            frame_nbytes(self.draft_pool.cache)
             if self.draft_pool is not None else 0
         )
         self._tick_cost_cache: dict = {}
@@ -476,34 +478,22 @@ class ServeEngine:
                 return b
         return self._buckets[-1]
 
-    @staticmethod
-    def _frame_bytes(cache) -> int:
-        """Bytes of ONE page frame across every KV-payload leaf (layer
-        stacking included) — the unit of the analytic HBM accounting."""
-        total = 0
-        for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
-            ax = cache_batch_axis(path, leaf)
-            if ax is not None:
-                total += (
-                    int(leaf.size) // int(leaf.shape[ax])
-                    * leaf.dtype.itemsize
-                )
-        return total
-
-    def _prefill_chunk_body(self, model, params, cache, pt, ids, slot,
-                            start, n_pages):
-        """One model's chunk prefill over its page pool: gather the
-        slot's pages — only the leading ``n_pages`` bucket the chunk
-        can reach, not the full ``max_len`` span — to a dense row, run
-        the ``[1, C]`` chunk write, and scatter exactly the chunk's
-        positions back (padded final-chunk positions included — they
-        stay inside the slot's reserved private span and are
-        overwritten or masked, as before). Returns
-        (chunk logits, updated pool)."""
+    def _prefill_chunk_body(self, model, params, pool, cache, pt, ids,
+                            slot, start, n_pages):
+        """One model's chunk prefill over its page pool (``pool`` owns
+        ``cache``): gather the slot's pages — only the leading
+        ``n_pages`` bucket the chunk can reach, not the full
+        ``max_len`` span — to a dense row, run the ``[1, C]`` chunk
+        write, and scatter exactly the chunk's positions back (padded
+        final-chunk positions included — they stay inside the slot's
+        reserved private span and are overwritten or masked, as
+        before). The gather reads what the chunk attends to and the
+        scatter writes ``C`` positions into the donated pool; nothing
+        else of the pool moves. Returns (chunk logits, updated pool)."""
         C = self.config.prefill_chunk
         row_pt = jax.lax.dynamic_slice_in_dim(pt, slot, 1, axis=0)
         row_pt = jax.lax.slice_in_dim(row_pt, 0, n_pages, axis=1)
-        row = gather_pages(cache, row_pt)
+        row = gather_pages(cache, row_pt, pool.tails)
         positions = (start + jnp.arange(C))[None, :]
         logits, state = model.apply(
             {"params": params, "cache": row},
@@ -554,7 +544,8 @@ class ServeEngine:
             self._prefill_bucket_compiles.get(n_pages, 0) + 1
         )
         logits, cache = self._prefill_chunk_body(
-            self.model, params, cache, pt, ids, slot, start, n_pages
+            self.model, params, self.pool, cache, pt, ids, slot, start,
+            n_pages,
         )
         tok, toks, lengths, keys = self._prefill_tail(
             logits, slot, start, last_idx, final, toks, lengths, keys,
@@ -573,11 +564,12 @@ class ServeEngine:
             self._prefill_bucket_compiles.get(n_pages, 0) + 1
         )
         logits, cache = self._prefill_chunk_body(
-            self.model, params, cache, pt, ids, slot, start, n_pages
+            self.model, params, self.pool, cache, pt, ids, slot, start,
+            n_pages,
         )
         _, dcache = self._prefill_chunk_body(
-            self.spec.draft_model, dparams, dcache, dpt, ids, slot,
-            start, n_pages,
+            self.spec.draft_model, dparams, self.draft_pool, dcache, dpt,
+            ids, slot, start, n_pages,
         )
         tok, toks, lengths, keys = self._prefill_tail(
             logits, slot, start, last_idx, final, toks, lengths, keys,
@@ -636,8 +628,13 @@ class ServeEngine:
             # attend in place over the pool: decode_cache writes the
             # new token through per-page scatters (inactive rows drop
             # theirs) and attention streams the bucket-sliced tables —
-            # no dense intermediate, no scatter-back; the model's
-            # returned cache IS the updated pool
+            # no dense intermediate, no scatter-back. The pool leaves
+            # ride the layer loop as its carry (models/scan.py) and
+            # come back as the same buffers, donated in and aliased out
+            # (scripts/pool_hlo_check.py reads that off the compiled
+            # program, where it has to hold: the jaxpr alone can say
+            # "the returned cache IS the pool" over a program that
+            # copies every leaf)
             ptb = jax.lax.slice_in_dim(pt, 0, n_pages, axis=1)
             with paged_view(PagedView(
                 page_tables=ptb, keep=active,
@@ -650,7 +647,7 @@ class ServeEngine:
                     write_pos=lengths,
                 )
         else:
-            dense = gather_pages(cache, pt)
+            dense = gather_pages(cache, pt, self.pool.tails)
             last, dense = decode_step_body(
                 self.model, params, dense, toks,
                 cache_len=self.config.max_len,
@@ -713,7 +710,7 @@ class ServeEngine:
         # runtime branch skips it when no live row samples
         any_sampled = jnp.any(~greedy_row)
 
-        dense_d = gather_pages(dcache, dpt)
+        dense_d = gather_pages(dcache, dpt, self.draft_pool.tails)
 
         def dstep(carry, j):
             dense_d, tok = carry
@@ -792,7 +789,7 @@ class ServeEngine:
                 )
             cache = st["cache"]
         else:
-            dense_t = gather_pages(cache, pt)
+            dense_t = gather_pages(cache, pt, self.pool.tails)
             logits, st = self.model.apply(
                 {"params": params, "cache": dense_t},
                 chunk, decode=True, cache_len=max_len,

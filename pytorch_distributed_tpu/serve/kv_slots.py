@@ -6,11 +6,15 @@ HBM forever — fatal at a realistic length mix, where the p50 request is
 a fraction of the p99 the pool must be sized for. This rewrite makes the
 PAGE the allocation unit:
 
-* **Device storage is one page pool per layer**: each KV leaf is
-  ``[..., num_pages + 1, page_size, H, D]`` (page 0 is a reserved null
-  page — never allocated, padding for unused page-table entries). Pages
-  are position-agnostic frames; which request owns which page, at which
-  sequence offset, is host bookkeeping.
+* **Device storage is one page pool per KV leaf**:
+  ``[..., num_pages + 1, page_size, H * D]`` (page 0 is a reserved null
+  page — never allocated, padding for unused page-table entries; a
+  scanned model's leading ``[L]`` is part of the same leaf). A frame is
+  lane-dense — heads folded into the minor dimension — which is the
+  layout the paged-attention kernel reads in place (ops/paged_attention
+  says what the chip does with any other). Pages are position-agnostic
+  frames; which request owns which page, at which sequence offset, is
+  host bookkeeping.
 * **Requests hold a page table** (``[max_pages]`` int32 per slot) instead
   of a buffer row. The jitted programs gather a request's pages into a
   dense ``[max_len]`` view, run the unchanged model decode contract
@@ -117,15 +121,22 @@ def auto_page_size(max_len: int, cap: int = 32) -> int:
     return ps
 
 
-def init_page_cache(model, params, num_pages: int, page_size: int):
-    """Zeroed page-pool pytree: ``num_pages + 1`` frames of ``page_size``.
+def page_axis(path, leaf) -> Optional[int]:
+    """Page axis of a POOL leaf, or None for shared counters: KV
+    payloads and their int8 scales are ``[..., P + 1, page_size, F]``
+    (a leading ``[L]`` when layers are scanned), so it is ``ndim - 3``.
+    The pool's counterpart of ``generation.cache_batch_axis``, which
+    speaks for the DENSE ``[..., B, T, H, D]`` views (and knows the
+    leaves by name for both)."""
+    return None if cache_batch_axis(path, leaf) is None else leaf.ndim - 3
 
-    Shapes come from ``jax.eval_shape`` over the model's own decode
-    apply (batch = page frames, length = page size), so the pool is
-    EXACTLY the leaf set the model mutates — scan layouts, int8 KV
-    scale buffers and all — reinterpreted as position-agnostic frames.
-    Frame 0 is the reserved null page backing unused page-table entries.
-    """
+
+def _page_cache(model, params, num_pages: int, page_size: int):
+    """(zeroed pool pytree, ``{KV leaf path: (H, D)}``) from ONE
+    abstract trace of the model's own decode apply (batch = page
+    frames, length = page size). The second is what a frame's folded
+    minor dimension unfolds to in the model's dense cache — the one
+    thing :func:`gather_pages` cannot read off a pool."""
 
     def shape_fn(p):
         _, state = model.apply(
@@ -137,31 +148,70 @@ def init_page_cache(model, params, num_pages: int, page_size: int):
         )
         return state["cache"]
 
-    shapes = jax.eval_shape(shape_fn, params)
-    return jax.tree_util.tree_map(
-        lambda s: jnp.zeros(s.shape, s.dtype), shapes
+    tails = {}
+
+    def f(path, s):
+        shape = s.shape
+        if cache_batch_axis(path, s) is not None:
+            tails[jax.tree_util.keystr(path)] = shape[-2:]
+            shape = shape[:-2] + (shape[-2] * shape[-1],)
+        return jnp.zeros(shape, s.dtype)
+
+    cache = jax.tree_util.tree_map_with_path(
+        f, jax.eval_shape(shape_fn, params)
+    )
+    return cache, tails
+
+
+def init_page_cache(model, params, num_pages: int, page_size: int):
+    """Zeroed page-pool pytree: ``num_pages + 1`` frames of ``page_size``.
+
+    Shapes come from ``jax.eval_shape`` over the model's own decode
+    apply (batch = page frames, length = page size), so the pool is
+    EXACTLY the leaf set the model mutates — scan layouts, int8 KV
+    scale buffers and all — reinterpreted as position-agnostic frames,
+    each KV leaf's ``[H, D]`` tail folded into one lane-dense ``[H * D]``.
+    Frame 0 is the reserved null page backing unused page-table entries.
+    """
+    return _page_cache(model, params, num_pages, page_size)[0]
+
+
+def _lead_index(leaf, ax: int):
+    """Index arrays over a pool leaf's axes before its page axis (a
+    scanned model's ``[L]``), each broadcasting against a trailing
+    ``[N]`` of frames. Gathers and scatters of frames INDEX those axes
+    and never slice them: with a window that spans the layers the
+    chip's compiler re-lays the whole leaf out layer-minor around a
+    scatter (and back), and splits it in two copies ahead of a gather."""
+    return tuple(
+        jnp.arange(n).reshape((n,) + (1,) * (ax - i))
+        for i, n in enumerate(leaf.shape[:ax])
     )
 
 
-def gather_pages(cache, page_tables: jnp.ndarray):
+def gather_pages(cache, page_tables: jnp.ndarray, tails):
     """Pool pytree + ``[B, max_pages]`` tables -> dense ``[B, T]`` view.
 
-    ``T = max_pages * page_size``. Only KV-payload leaves (those with a
-    batch axis per ``generation.cache_batch_axis`` — int8 scale buffers
-    included) are gathered; shared counters pass through untouched, as
-    in the old per-slot slicing. The result is a valid decode cache for
-    ``model.apply`` with per-row ``write_pos``/``positions``.
+    ``T = max_pages * page_size``; ``tails`` is the pool's
+    ``PagedKVPool.tails``. Only KV-payload leaves (:func:`page_axis` —
+    int8 scale buffers included) are gathered; shared counters pass
+    through untouched. The result is a valid decode cache for
+    ``model.apply`` with per-row ``write_pos``/``positions``. One
+    gather along the leaf's own page axis: what it reads and writes is
+    as wide as the tables, not the pool.
     """
     B, mp = page_tables.shape
     flat = page_tables.reshape(-1)
 
     def f(path, x):
-        ax = cache_batch_axis(path, x)
+        ax = page_axis(path, x)
         if ax is None:
             return x
         ps = x.shape[ax + 1]
-        g = jnp.take(x, flat, axis=ax)
-        return g.reshape(x.shape[:ax] + (B, mp * ps) + x.shape[ax + 2:])
+        g = x[_lead_index(x, ax) + (flat,)]     # [.., B * mp, ps, F]
+        return g.reshape(
+            x.shape[:ax] + (B, mp * ps) + tails[jax.tree_util.keystr(path)]
+        )
 
     return jax.tree_util.tree_map_with_path(f, cache)
 
@@ -178,14 +228,18 @@ def scatter_kv(cache, dense, page_tables, positions, keep):
     admission, and ``PagedKVPool.check_consistency`` + the shared-page
     checksum test pin it.
 
-    Callers are the engine's jitted programs only (prefill chunk, decode
-    tick, speculative verify); the scatter itself is a fused
-    ``dynamic_update``-class op inside those compiles.
+    Callers are the engine's jitted programs only (prefill chunk, dense
+    decode tick, the speculative draft). ONE scatter per leaf along the
+    leaf's own page and row axes, the leaf where it lies: the compiled
+    program writes ``B * W`` positions into the donated pool and reads
+    or moves nothing else of it. (Moving the page axis to the front
+    for the scatter is, on the chip, a transpose of the whole pool each
+    way.)
     """
     B, W = positions.shape
 
     def f(path, x, d):
-        ax = cache_batch_axis(path, x)
+        ax = page_axis(path, x)
         if ax is None:
             return x
         npp, ps = x.shape[ax], x.shape[ax + 1]
@@ -193,20 +247,18 @@ def scatter_kv(cache, dense, page_tables, positions, keep):
         # clamp (jnp.take_along_axis default) — such rows are always
         # keep=False so the clamped garbage index is dropped anyway
         page = jnp.take_along_axis(page_tables, positions // ps, axis=1)
-        dst = page * ps + positions % ps                    # [B, W]
-        dst = jnp.where(keep, dst, npp * ps)                # OOB -> drop
+        page = jnp.where(keep, page, npp)                   # OOB -> drop
         idx = positions.reshape((1,) * ax + (B, W, 1, 1))
         upd = jnp.take_along_axis(d, idx, axis=ax + 1)      # [.., B, W, H, D]
-        flat = x.reshape(x.shape[:ax] + (npp * ps,) + x.shape[ax + 2:])
-        flat = jnp.moveaxis(flat, ax, 0)
-        upd = upd.reshape(upd.shape[:ax] + (B * W,) + upd.shape[ax + 2:])
-        upd = jnp.moveaxis(upd, ax, 0)
-        flat = flat.at[dst.reshape(-1)].set(  # ptdlint: disable=PTD004
-            upd.astype(flat.dtype), mode="drop",
+        upd = upd.reshape(x.shape[:ax] + (B * W, x.shape[-1]))
+        at = _lead_index(x, ax) + (
+            page.reshape(-1), (positions % ps).reshape(-1),
+        )
+        return x.at[at].set(  # ptdlint: disable=PTD004
+            upd.astype(x.dtype), mode="drop",
         )  # fused scatter: only ever traced inside the engine's jitted
         # programs (cross-module, so the per-module lint closure cannot
         # see the jit wrapping it)
-        return jnp.moveaxis(flat, 0, ax).reshape(x.shape)
 
     return jax.tree_util.tree_map_with_path(f, cache, dense)
 
@@ -218,7 +270,7 @@ def _frame_leaves(cache):
     payloads) is defined over."""
     out = []
     for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
-        ax = cache_batch_axis(path, leaf)
+        ax = page_axis(path, leaf)
         if ax is not None:
             name = getattr(path[-1], "key", None) or str(path[-1])
             out.append((name, ax, leaf))
@@ -294,7 +346,7 @@ def splice_frames(cache, pages, payload):
 
     def f(path, leaf):
         nonlocal off
-        ax = cache_batch_axis(path, leaf)
+        ax = page_axis(path, leaf)
         if ax is None:
             return leaf
         shape = leaf.shape[:ax] + (n,) + leaf.shape[ax + 1:]
@@ -308,11 +360,10 @@ def splice_frames(cache, pages, payload):
             leaf.dtype
         ).reshape(shape)
         off += count
-        m = jnp.moveaxis(leaf, ax, 0)
-        m = m.at[idx].set(  # ptdlint: disable=PTD004
-            jnp.moveaxis(jnp.asarray(frames), ax, 0)
+        at = (slice(None),) * ax + (idx,)
+        return leaf.at[at].set(  # ptdlint: disable=PTD004
+            jnp.asarray(frames)
         )  # once per migrated request (bounded, priced), never per tick
-        return jnp.moveaxis(m, 0, ax)
 
     out = jax.tree_util.tree_map_with_path(f, cache)
     if off != buf.size:
@@ -387,7 +438,10 @@ class PagedKVPool:
                 f"max-length request ({self.max_pages} pages)"
             )
         self.prefix_cache = prefix_cache
-        self.cache = init_page_cache(model, params, self.num_pages, ps)
+        # the pool, and each KV leaf's dense (H, D) for gather_pages
+        self.cache, self.tails = _page_cache(
+            model, params, self.num_pages, ps
+        )
         self.lengths = np.zeros(num_slots, np.int32)
         self.page_tables = np.zeros(
             (num_slots, self.max_pages), np.int32
@@ -695,9 +749,8 @@ class PagedKVPool:
         """Resident bytes of the page pool's KV-payload leaves (null
         page included — it is real allocated memory)."""
         total = 0
-        for path, leaf in jax.tree_util.tree_leaves_with_path(self.cache):
-            if cache_batch_axis(path, leaf) is not None:
-                total += int(leaf.size) * leaf.dtype.itemsize
+        for _, _, leaf in _frame_leaves(self.cache):
+            total += int(leaf.size) * leaf.dtype.itemsize
         return total
 
     def device_page_table(self, slot: int) -> np.ndarray:

@@ -1,0 +1,252 @@
+#!/usr/bin/env python3
+"""Does the compiled serving program leave the KV page pool where it lies?
+
+Compiles ``ServeEngine._decode_fn`` and ``_prefill_fn`` at a benchmark
+cell's real shapes (from ``perfbench/workloads/<cell>.json`` and its
+configuration; shapes only, no weights are made) and reads the OPTIMISED
+HLO: every instruction, in every computation, whose result is as large
+as a pool leaf, a layer's plane of one or a good part of a plane, must
+be a parameter, a tuple or its element, a bitcast, the layer loop
+(``while``), a scatter, or a fusion around those; a ``copy``,
+``transpose``, ``reshape``, ``slice``, ``dynamic-slice``,
+``dynamic-update-slice`` or an allocated buffer of that size is a pass
+over the pool that a tick or a chunk would pay for, and is listed. The
+pool parameters must be aliased to the pool results.
+
+    python scripts/pool_hlo_check.py                  # on the chip
+    python scripts/pool_hlo_check.py --describe v5e:2x2   # anywhere
+
+``--describe`` compiles for a chip that is described and not attached
+(the TPU compiler is part of the installation; ``JAX_PLATFORMS=cpu``
+stays set). Code that asks ``jax.default_backend()`` then still sees the
+CPU, so the script itself selects the kernel and turns the interpreter
+off. A compile is not a run: it says what the program holds, not what it
+costs. Exit code 1 when any program moves the pool or fails to alias it.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+CELLS = ("mistral-serve-sat", "gpt2m-serve-chat-p80")
+# what may have a pool-sized result: the pool itself passing by, the
+# loop that carries it, and the one operation that writes it
+ALLOWED = {
+    "parameter", "get-tuple-element", "tuple", "bitcast", "while",
+    "scatter", "fusion", "call", "conditional", "optimization-barrier",
+}
+_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>[\w.\-]+) = (?P<type>\(?[a-z0-9]+\[[^=]*?) "
+    r"(?P<op>[a-z][a-z\-]*)\("
+)
+_SHAPE = re.compile(r"(?:bf16|f16|f32|s8|u8|s32|u32|pred)\[([0-9,]*)\]")
+
+
+def pool_sized(type_text: str, frames: int, floor: int) -> bool:
+    """A result (or one element of a tuple result) that holds a pool
+    leaf, a plane or a part of one: its element count is a multiple of
+    the frame count ``P + 1`` — which no weight's is — and at least
+    ``floor`` elements."""
+    for dims in _SHAPE.findall(type_text):
+        n = 1
+        for d in dims.split(","):
+            if d:
+                n *= int(d)
+        if n >= floor and n % frames == 0:
+            return True
+    return False
+
+
+def pool_passes(hlo_text: str, frames: int, floor: int):
+    """(instruction, opcode, type) of every pool-sized result that is
+    not in :data:`ALLOWED`, anywhere in the module."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if not m or m["op"] in ALLOWED:
+            continue
+        if m["op"] == "custom-call" and "tpu_custom_call" in line:
+            continue  # the kernel's own result, never pool-sized anyway
+        if pool_sized(m["type"], frames, floor):
+            out.append((m["name"], m["op"], m["type"].strip()))
+    return out
+
+
+def aliased_outputs(hlo_text: str):
+    """``{output index: parameter number}`` from the module header."""
+    head = hlo_text.split("\n", 1)[0]
+    m = re.search(r"input_output_alias=\{(.*?)\}, entry", head)
+    pairs = re.findall(r"\{(\d+)\}: \((\d+), \{\}", m.group(1) if m else "")
+    return {int(o): int(p) for o, p in pairs}
+
+
+def entry_parameters(hlo_text: str):
+    """Names of the ENTRY computation's parameters, in order."""
+    m = re.search(r"^ENTRY [^(]*\((.*?)\) -> ", hlo_text, re.M | re.S)
+    return re.findall(r"([\w.]+): ", m.group(1)) if m else []
+
+
+def check_program(name, compiled, frames, floor, n_leaves):
+    text = compiled.as_text()
+    passes = pool_passes(text, frames, floor)
+    params = entry_parameters(text)
+    alias = aliased_outputs(text)
+    pool_params = [
+        i for i, p in enumerate(params)
+        if "cached_key" in p or "cached_value" in p
+    ]
+    aliased = sorted(set(alias.values()) & set(pool_params))
+    mem = compiled.memory_analysis()
+    ok = not passes and len(aliased) == len(pool_params) == n_leaves
+    print(json.dumps({
+        "program": name,
+        "ok": ok,
+        "pool_parameters": [params[i] for i in pool_params],
+        "pool_parameters_aliased_to_outputs": len(aliased),
+        "pool_sized_passes": [
+            {"instruction": i, "op": o, "type": t} for i, o, t in passes
+        ],
+        "temp_bytes": mem.temp_size_in_bytes,
+        "argument_bytes": mem.argument_size_in_bytes,
+        "kernel_calls": text.count('custom_call_target="tpu_custom_call"'),
+    }))
+    return ok
+
+
+def compile_cell(cell_name, sharding):
+    """Compile the cell's two programs over shapes alone, at the widest
+    bucket: yields (program name, compiled, frames, floor, leaves)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.harness.cells import Cell
+    from pytorch_distributed_tpu.runtime import precision
+    from pytorch_distributed_tpu.serve import (
+        EngineConfig, ServeEngine, page_axis,
+    )
+
+    cell = Cell(cell_name)
+    cfg, fam, es = cell.config, cell.family(), cell.spec["engine"]
+    prec = cfg["precision"]
+    policy = precision.Policy(
+        param_dtype=jnp.dtype(prec["param_dtype"]),
+        compute_dtype=jnp.dtype(prec["compute_dtype"]),
+        output_dtype=jnp.dtype(prec["output_dtype"]),
+    )
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    S, C = es["num_slots"], es["prefill_chunk"]
+    mp = es["max_len"] // es["page_size"]
+    frames = es["num_pages"] + 1
+    with precision.use_policy(policy):
+        model = fam.build_model(cfg)
+        params = jax.tree_util.tree_map(
+            lambda s: sds(s.shape, policy.param_dtype),
+            jax.eval_shape(
+                lambda k: model.init(k, np.zeros((1, 8), np.int32)),
+                jax.random.key(0),
+            )["params"],
+        )
+        # a pool of the smallest legal size is enough to build the
+        # engine; the programs are compiled against the cell's own
+        engine = ServeEngine(model, params, EngineConfig(
+            num_slots=S, max_len=es["max_len"], prefill_chunk=C,
+            page_size=es["page_size"], num_pages=mp,
+            prefix_cache=es["prefix_cache"],
+        ))
+        planes = []  # elements of one layer's plane, per pool leaf
+
+        def leaf(path, x):
+            ax = page_axis(path, x)
+            if ax is None:
+                return sds(x.shape, x.dtype)
+            shape = x.shape[:ax] + (frames,) + x.shape[ax + 1:]
+            planes.append(int(np.prod(shape[ax:])))
+            return sds(shape, x.dtype)
+
+        cache = jax.tree_util.tree_map_with_path(leaf, engine.pool.cache)
+        # a quarter of the smallest plane: the compiler has been seen
+        # to split a leaf in halves ahead of a gather
+        floor = min(planes) // 4
+        print(f"# {cell_name}: {len(planes)} pool leaves, {frames} frames "
+              f"of {es['page_size']} positions, bucket {mp} pages")
+
+        def i32(*shape):
+            return sds(shape, jnp.int32)
+
+        def f32(*shape):
+            return sds(shape, jnp.float32)
+
+        rows = (i32(S), i32(S), sds((S, 2), jnp.uint32), f32(S), i32(S),
+                f32(S))
+        decode = jax.jit(
+            engine._decode_fn, donate_argnums=(1, 3, 4, 5),
+            static_argnums=(10,),
+        ).lower(params, cache, i32(S, mp), *rows, sds((S,), jnp.bool_), mp)
+        yield "_decode_fn", decode.compile(), frames, floor, len(planes)
+        prefill = jax.jit(
+            engine._prefill_fn, donate_argnums=(1,), static_argnums=(14,),
+        ).lower(
+            params, cache, i32(S, mp), i32(1, C), i32(), i32(), i32(),
+            sds((), jnp.bool_), *rows, mp,
+        )
+        yield "_prefill_fn", prefill.compile(), frames, floor, len(planes)
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--cell", action="append", choices=CELLS)
+    ap.add_argument(
+        "--describe", metavar="TOPOLOGY",
+        help="compile for a described chip (e.g. v5e:2x2), none attached",
+    )
+    args = ap.parse_args(argv)
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+
+    import pytorch_distributed_tpu.ops  # noqa: F401 — registers submodules
+
+    sharding = None
+    if args.describe:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        # a described compile cannot be read back from the cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name=args.describe
+        )
+        sharding = SingleDeviceSharding(topo.devices[0])
+        # the package re-exports a function under the module's name
+        paged = sys.modules["pytorch_distributed_tpu.ops.paged_attention"]
+        paged._interpret = lambda: False
+        paged._IMPL = "kernel"
+        print(f"# compiled for a described {args.describe}, not run")
+    else:
+        dev = jax.devices()[0]
+        if dev.platform != "tpu":
+            raise SystemExit(
+                f"no TPU here ({dev.platform}): pass --describe v5e:2x2"
+            )
+        print(f"# compiled on {dev.device_kind}")
+
+    ok = True
+    for cell in args.cell or CELLS:
+        for name, compiled, frames, floor, n in compile_cell(cell, sharding):
+            ok &= check_program(f"{cell}/{name}", compiled, frames, floor, n)
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

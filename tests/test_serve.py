@@ -8,6 +8,8 @@ same seed and sampling params, and the decode step compiles exactly
 once for the whole workload (the static-shape invariant).
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -58,13 +60,23 @@ def _solo(model, params, req: Request):
     return toks
 
 
-def test_mixed_workload_parity_single_compile(gpt2):
+@pytest.mark.parametrize("layers", [2, 1, 3])
+def test_mixed_workload_parity_single_compile(gpt2, layers):
     """THE acceptance test: staggered arrivals, ragged prompt/new
     lengths, heterogeneous sampling params, one cancellation, one
     fault-evicted request, more requests than slots (slot reuse) — and
     every completed stream equals its solo generate bit for bit, with
-    ONE decode compile and ONE prefill compile."""
+    ONE decode compile and ONE prefill compile. At every depth: the
+    page pool rides the layer loop whole and each layer finds
+    its own plane of it."""
     model, params = gpt2
+    if layers != 2:
+        model = GPT2LMHead(dataclasses.replace(
+            model.config, num_layers=layers
+        ))
+        params = model.init(
+            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
+        )["params"]
     rng = np.random.default_rng(7)
     engine = ServeEngine(model, params, EngineConfig(
         num_slots=3, max_len=64, prefill_chunk=4,

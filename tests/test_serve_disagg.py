@@ -176,13 +176,25 @@ class TestRoles:
                 prefix_store=InProcPrefixStore(),
             )
 
-    def test_migration_parity_greedy_and_sampled(self, gpt2):
+    @pytest.mark.parametrize("body", ["gpt2-L2", "gpt2-L1", "llama-gqa-L3"])
+    def test_migration_parity_greedy_and_sampled(self, gpt2, body):
         """THE correctness gate: prefill -> wire -> decode streams are
-        bit-identical to the solo engine's, greedy and sampled alike."""
+        bit-identical to the solo engine's, greedy and sampled alike.
+        Frames are whole lane-dense page frames of every layer's
+        plane: both ends commit to the same ``frame_signature`` and the
+        bytes splice losslessly, at any depth, MHA or GQA."""
+        from tests.test_serve_paged import make_body
+
+        if body != "gpt2-L2":
+            gpt2 = make_body(body)
         reqs = _requests(6, seed=21)
         want = _solo_streams(*gpt2, reqs)
         pre = ServeEngine(*gpt2, EngineConfig(role="prefill", **ECFG))
         dec = ServeEngine(*gpt2, EngineConfig(role="decode", **ECFG))
+        assert pre.migration_signature == dec.migration_signature
+        assert frame_signature(
+            pre.pool.cache, pre.pool.page_size
+        ) in pre.migration_signature
         hs = {r.request_id: pre.submit(r) for r in reqs}
         pre.run_until_drained()
         assert all(
@@ -326,6 +338,9 @@ class TestWire:
         )
         assert s_f32 != s_int8
         assert "ps=8" in s_f32
+        # a frame is every layer's [ps, H * D] rows of one page (the pool
+        # here has 32-token pages), heads folded lane-dense as stored
+        assert "cached_key:(2, 32, 32):" in s_f32
 
 
 # -- prefix registry -------------------------------------------------------

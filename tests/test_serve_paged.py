@@ -71,6 +71,54 @@ def draft(gpt2):
     return model, params
 
 
+# the pool's passage (a stacked leaf carried through the layer
+# loop, each layer writing and reading its own plane in place) pinned
+# across what it can observe: depth 1 and deeper, MHA and GQA
+BODIES = ("gpt2-L2", "gpt2-L1", "llama-gqa-L3")
+
+
+def _init(model, seed):
+    return model.init(
+        jax.random.key(seed), jnp.zeros((1, 8), jnp.int32)
+    )["params"]
+
+
+def _llama(layers, hidden=32, n_positions=96):
+    from pytorch_distributed_tpu.models.llama import (
+        LlamaConfig, LlamaForCausalLM,
+    )
+
+    return LlamaForCausalLM(LlamaConfig(
+        vocab_size=97, hidden_size=hidden, num_layers=layers, num_heads=4,
+        num_kv_heads=2, intermediate_size=2 * hidden,
+        max_seq_len=n_positions,
+    ))
+
+
+def make_body(name, n_positions=96):
+    """(model, params) of one of :data:`BODIES` (vocab 97) — shared by
+    the parity suites that pin the pool's passage per body."""
+    if name == "llama-gqa-L3":
+        model = _llama(3, n_positions=n_positions)
+    else:
+        model = GPT2LMHead(GPT2Config(
+            vocab_size=97, n_positions=n_positions, hidden_size=32,
+            num_layers=int(name[-1]), num_heads=2, dropout_rate=0.0,
+        ))
+    return model, _init(model, 0)
+
+
+@pytest.fixture(scope="module", params=BODIES)
+def body(request, gpt2, draft):
+    """((model, params), (draft model, draft params)) per body."""
+    if request.param == "gpt2-L2":
+        return gpt2, draft
+    if request.param == "llama-gqa-L3":
+        dmodel = _llama(1, hidden=16)
+        draft = dmodel, _init(dmodel, 1)
+    return make_body(request.param), draft
+
+
 def _solo(model, params, req: Request):
     out = np.asarray(generate(
         model, params, jnp.asarray(req.prompt_ids[None]),
@@ -104,11 +152,11 @@ def _assert_bucketed_compiles(engine):
 def _page_bytes(pool, pages):
     """Concatenated bytes of the given page frames across every
     KV-payload leaf — the read-only checksum for CoW tests."""
-    from pytorch_distributed_tpu.generation import cache_batch_axis
+    from pytorch_distributed_tpu.serve import page_axis
 
     chunks = []
     for path, leaf in jax.tree_util.tree_leaves_with_path(pool.cache):
-        ax = cache_batch_axis(path, leaf)
+        ax = page_axis(path, leaf)
         if ax is None:
             continue
         arr = np.asarray(jnp.moveaxis(leaf, ax, 0)[np.array(pages)])
@@ -125,11 +173,11 @@ def test_auto_page_size():
         EngineConfig(num_slots=1, max_len=64, page_size=24)
 
 
-def test_prefix_sharing_is_copy_free_and_exact(gpt2):
+def test_prefix_sharing_is_copy_free_and_exact(body):
     """Second request with the same system prompt shares pages
     (refcount, zero prefill for the shared span), its tokens equal the
     solo run, and the shared pages' device bytes never change."""
-    model, params = gpt2
+    model, params = body[0]
     rng = np.random.default_rng(3)
     sys_p = rng.integers(1, 97, size=12).astype(np.int32)
     r1 = Request(
@@ -274,13 +322,12 @@ def test_mid_flight_eviction_releases_only_private_pages(gpt2):
         assert engine.pool._ref[pg] >= 1
 
 
-def test_spec_greedy_parity_mixed_workload(gpt2, draft):
+def test_spec_greedy_parity_mixed_workload(body):
     """THE speculative acceptance test: greedy requests under a fused
     draft+verify tick emit bit-identical streams to solo generate,
     across slot reuse, chunked prefill, a cancellation and a
     fault-evicted victim — with ONE prefill and ONE tick compile."""
-    model, params = gpt2
-    dmodel, dparams = draft
+    (model, params), (dmodel, dparams) = body
     rng = np.random.default_rng(7)
     engine = ServeEngine(
         model, params,
@@ -452,7 +499,9 @@ def test_spec_full_accept_round_leaves_no_draft_cache_hole():
     assert not h.done  # the slot (and its pages) must still be live
     slot = h.slot
     L = int(np.asarray(engine._lengths)[slot])
-    dense = gather_pages(engine.draft_pool.cache, engine._dpt)
+    dense = gather_pages(
+        engine.draft_pool.cache, engine._dpt, engine.draft_pool.tails
+    )
     for path, leaf in jax.tree_util.tree_leaves_with_path(dense):
         name = getattr(path[-1], "key", None) or str(path[-1])
         if name not in ("cached_key", "cached_value"):

@@ -339,3 +339,55 @@ def test_ragged_rejects_right_padding():
             target, tp, draft, dp, ids, max_new_tokens=4,
             num_draft_tokens=2, prompt_mask=bad,
         )
+
+
+@pytest.mark.parametrize("body", ["gpt2-L2", "gpt2-L1", "llama-gqa-L3"])
+def test_engine_spec_tick_equals_offline_speculative(body):
+    """The serving engine's fused draft+verify tick, whose ``[k+1]``
+    verify writes and reads the stacked page pool in place,
+    emits what the offline ``generate_speculative`` emits, which is the
+    target's own greedy stream — per request, at any depth, MHA or
+    GQA, with both pools consistent afterwards."""
+    from pytorch_distributed_tpu.serve import (
+        EngineConfig, Request, RequestStatus, ServeEngine, SpecConfig,
+    )
+
+    if body == "llama-gqa-L3":
+        kw = dict(vocab_size=97, num_heads=4, num_kv_heads=2,
+                  max_seq_len=96)
+        target = LlamaForCausalLM(LlamaConfig(
+            hidden_size=32, num_layers=3, intermediate_size=64, **kw))
+        draft = LlamaForCausalLM(LlamaConfig(
+            hidden_size=16, num_layers=1, intermediate_size=32, **kw))
+        ids = jnp.asarray(np.random.default_rng(7).integers(
+            97, size=(3, 6)).astype(np.int32))
+        tp = target.init(jax.random.key(0), ids)["params"]
+        dp = draft.init(jax.random.key(1), ids)["params"]
+    else:
+        target, tp, draft, dp, ids = _gpt2_pair()
+        if body == "gpt2-L1":
+            target, tp = draft, dp  # depth 1 on both sides: all accepted
+    want, stats = generate_speculative(
+        target, tp, draft, dp, ids, max_new_tokens=12,
+        num_draft_tokens=3, return_stats=True,
+    )
+    engine = ServeEngine(
+        target, tp,
+        EngineConfig(num_slots=3, max_len=48, prefill_chunk=4,
+                     page_size=4),
+        spec=SpecConfig(draft, dp, num_draft_tokens=3),
+    )
+    hs = [
+        engine.submit(Request(np.asarray(row), max_new_tokens=12))
+        for row in ids
+    ]
+    engine.run_until_drained()
+    for row, h in zip(np.asarray(want), hs):
+        assert h.status is RequestStatus.COMPLETED
+        assert h.tokens == [int(t) for t in row[ids.shape[1]:]]
+    assert engine.spec_verifies > 0
+    assert 0 <= engine.spec_accepted <= engine.spec_drafted
+    if body == "gpt2-L1":
+        assert engine.spec_accepted == engine.spec_drafted
+    engine.pool.check_consistency()
+    engine.draft_pool.check_consistency()

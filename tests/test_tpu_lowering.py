@@ -51,24 +51,30 @@ def _sds(shape, dtype):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
 
 
+@pytest.mark.parametrize("L", [None, 3], ids=["leaf", "stacked"])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("window", [None, 256])
 @pytest.mark.parametrize("W", [1, 5])
 @pytest.mark.parametrize("Hq,Hkv,D", GEOMETRIES)
-def test_paged_kernel_lowers(Hq, Hkv, D, W, window, dtype):
+def test_paged_kernel_lowers(Hq, Hkv, D, W, window, dtype, L):
     """Decode (W=1) and the speculative verify (W=5) at batch 8 over a
-    1024-token pool of 16-token pages."""
+    1024-token pool of 16-token pages, stored lane-dense: one leaf per
+    layer, or the stacked leaf of a scanned model with the layer as a
+    third prefetched scalar."""
     B, ps, n = 8, 16, 64
+    pool = (B * n + 1, ps, Hkv * D)
+    stacked = L is not None
     _assert_lowers_for_tpu(
-        lambda q, k, v, t, l: paged_attention(
+        lambda q, k, v, t, l, lay: paged_attention(
             q, k, v, page_tables=t, lengths=l, window=window,
-            impl="kernel",
+            layer=lay if stacked else None, impl="kernel",
         ),
         _sds((B, W, Hq, D), dtype),
-        _sds((B * n + 1, ps, Hkv, D), dtype),
-        _sds((B * n + 1, ps, Hkv, D), dtype),
+        _sds((L,) + pool if stacked else pool, dtype),
+        _sds((L,) + pool if stacked else pool, dtype),
         _sds((B, n), "int32"),
         _sds((B,), "int32"),
+        _sds((), "int32"),
     )
 
 
@@ -141,3 +147,73 @@ def test_engine_decode_program_lowers_with_the_kernel(monkeypatch):
     assert "tpu_custom_call" in text
     # the trace served nothing: the compile ledger is as it was
     assert engine.decode_compiles == 0 and not engine.decode_buckets
+
+
+# -- the compiled program, for a described chip ------------------------------
+# Lowering says Mosaic accepts the kernels; only the TPU compiler's own
+# output says what a program does with the KV page pool. It is part of
+# the installation and compiles for a chip that is described, not
+# attached. The library is loaded inside the fixture, by the one worker
+# that runs this file, and never while anything is imported.
+
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A described compile is written to the persistent cache and can
+    never be read back: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize(
+    "cell", ["mistral-serve-sat", "gpt2m-serve-chat-p80"]
+)
+def test_compiled_serving_programs_leave_the_pool_in_place(
+    cell, one_v5e, no_compile_cache, monkeypatch
+):
+    """``_decode_fn`` and ``_prefill_fn`` at the benchmark cell's own
+    shapes, compiled for a v5e: no instruction of the optimised HLO has
+    a result the size of a pool leaf, a layer's plane or a good part of
+    one unless it is the pool passing by, the layer loop or the scatter,
+    and the pool parameters alias the pool results. What
+    ``scripts/pool_hlo_check.py`` prints on the chip, held here."""
+    import os
+
+    scripts = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "scripts",
+    )
+    monkeypatch.syspath_prepend(scripts)
+    import pool_hlo_check
+
+    monkeypatch.setattr(_PAGED, "_IMPL", "kernel")
+    programs = list(pool_hlo_check.compile_cell(cell, one_v5e))
+    assert [name for name, *_ in programs] == ["_decode_fn", "_prefill_fn"]
+    for name, compiled, frames, floor, n_leaves in programs:
+        text = compiled.as_text()
+        assert pool_hlo_check.pool_passes(text, frames, floor) == [], name
+        params = pool_hlo_check.entry_parameters(text)
+        pool = {i for i, p in enumerate(params) if "cached_" in p}
+        aliased = set(pool_hlo_check.aliased_outputs(text).values())
+        assert len(pool) == n_leaves and pool <= aliased, name
+    assert "tpu_custom_call" in programs[0][1].as_text()
