@@ -178,19 +178,17 @@ def cache_batch_axis(path, leaf) -> Optional[int]:
     KV payload buffers are ``[..., B, T, H, D]`` (a leading ``[L]`` when
     layers are scanned), so the batch axis is ``ndim - 4``; the int8
     cache's per-token scale buffers carry the SAME layout and must move
-    in lockstep with their payloads. Index/position counters have no
-    batch dim and return None. Shared by ``generate_beam`` (beam
-    replicate/reorder) and the serving engine's slot pool (per-slot
-    insert/extract) so the two can never disagree about which leaves
-    are per-sequence state.
+    in lockstep with their payloads, and a latent cache's one leaf
+    (``[..., B, T, 1, F]``) is of the same form. Index/position counters
+    have no batch dim (rank 0, or ``[L]``) and return None: a leaf is
+    told by its geometry, never by its name, so a block that caches
+    something else declares it by caching it. Shared by ``generate_beam``
+    (beam replicate/reorder) and the serving engine's slot pool
+    (per-slot insert/extract) so the two can never disagree about which
+    leaves are per-sequence state.
     """
-    name = getattr(path[-1], "key", None) or str(path[-1])
-    if name in (
-        "cached_key", "cached_value",
-        "cached_key_scale", "cached_value_scale",
-    ):
-        return leaf.ndim - 4
-    return None
+    del path
+    return leaf.ndim - 4 if leaf.ndim >= 4 else None
 
 
 def decode_step_body(
@@ -203,6 +201,7 @@ def decode_step_body(
     positions: Optional[jnp.ndarray] = None,
     kv_mask: Optional[jnp.ndarray] = None,
     write_pos: Optional[jnp.ndarray] = None,
+    with_intermediates: bool = False,
 ):
     """One KV-cache decode tick: ``[B]`` tokens -> ``([B, V] logits, cache)``.
 
@@ -214,6 +213,8 @@ def decode_step_body(
     ``write_pos`` is the slot-pool contract (per-row KV writes at each
     row's own length, ``ops.attention.decode_cache``); the lockstep
     paths leave it None and let the model's scalar cache_index advance.
+    ``with_intermediates`` also returns what the blocks sowed (an expert
+    layer's routing counters), as a third element.
     """
     extra = {}
     if positions is not None:
@@ -227,9 +228,12 @@ def decode_step_body(
         tok[:, None],
         decode=True,
         cache_len=cache_len,
-        mutable=["cache"],
+        mutable=["cache", "intermediates"] if with_intermediates
+        else ["cache"],
         **extra,
     )
+    if with_intermediates:
+        return logits[:, -1], state["cache"], state.get("intermediates", {})
     return logits[:, -1], state["cache"]
 
 

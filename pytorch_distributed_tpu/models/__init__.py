@@ -77,6 +77,11 @@ from pytorch_distributed_tpu.models.qwen3 import (
     Qwen3ForCausalLM,
     qwen3_partition_rules,
 )
+from pytorch_distributed_tpu.models.deepseek_v3 import (
+    DeepseekV3Config,
+    DeepseekV3ForCausalLM,
+    deepseek_v3_partition_rules,
+)
 from pytorch_distributed_tpu.models.mixtral import (
     MixtralConfig,
     MixtralForCausalLM,
@@ -120,6 +125,9 @@ __all__ = [
     "Qwen3Config",
     "Qwen3ForCausalLM",
     "qwen3_partition_rules",
+    "DeepseekV3Config",
+    "DeepseekV3ForCausalLM",
+    "deepseek_v3_partition_rules",
     "MixtralConfig",
     "MixtralForCausalLM",
     "mixtral_partition_rules",

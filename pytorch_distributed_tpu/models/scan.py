@@ -54,6 +54,7 @@ def scan_stack(
     remat: Optional[bool] = None,
     static_argnums: Tuple[int, ...] = (),
     name: str = "blocks",
+    with_layer: bool = False,
 ) -> Callable:
     """Build the scanned stack and return ``f(x, *bcast) -> x``.
 
@@ -85,6 +86,13 @@ def scan_stack(
     beside the activation and names each to the view
     (``paged_layer``), which is how ``decode_cache`` and ``attention``
     find their plane — the blocks, and the models, learn nothing of it.
+
+    ``with_layer`` hands every block its own index in the stack as the
+    argument after ``x`` (a traced int32 scalar): for a block that reads
+    a leaf the loop must NOT slice a layer — a kernel's operand is
+    copied out of a scanned leaf every iteration, where a broadcast
+    ``[L, ...]`` leaf indexed inside the kernel stays where it lies
+    (``ops/moe.py``'s expert weights).
     """
     from pytorch_distributed_tpu.ops.paged_attention import (
         active_view,
@@ -93,16 +101,21 @@ def scan_stack(
 
     use_remat = cfg.remat if remat is None else remat
     paged = active_view() is not None
+    counted = paged or with_layer
 
     class Body(nn.Module):
         @nn.compact
         def __call__(self, carry, *bcast):
             block = block_cls(cfg, name="block")
-            if not paged:
+            if not counted:
                 return block(carry, *bcast), None
             x, layer = carry
-            with paged_layer(layer):
-                x = block(x, *bcast)
+            args = (layer,) + bcast if with_layer else bcast
+            if paged:
+                with paged_layer(layer):
+                    x = block(x, *args)
+            else:
+                x = block(x, *args)
             return (x, layer + 1), None
 
     if getattr(cfg, "scan_dequant", False):
@@ -150,7 +163,7 @@ def scan_stack(
     )(name=name)
 
     def apply_stack(x, *bcast):
-        if paged:
+        if counted:
             (y, _), _ = mod((x, jnp.zeros((), jnp.int32)), *bcast)
         else:
             y, _ = mod(x, *bcast)

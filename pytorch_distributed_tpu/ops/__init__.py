@@ -18,6 +18,7 @@ from pytorch_distributed_tpu.ops.attention import (
 from pytorch_distributed_tpu.ops.flash_attention import flash_attention
 from pytorch_distributed_tpu.ops.paged_attention import (
     PagedKVQuant,
+    PagedPrefix,
     PagedView,
     get_paged_attention_impl,
     paged_attention,
@@ -61,6 +62,7 @@ __all__ = [
     "dot_product_attention",
     "flash_attention",
     "PagedKVQuant",
+    "PagedPrefix",
     "PagedView",
     "get_paged_attention_impl",
     "paged_attention",

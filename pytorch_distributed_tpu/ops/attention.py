@@ -41,7 +41,8 @@ def rope_frequencies(
       low_freq_factor`` are slowed by ``factor``, wavelengths shorter
       than ``original / high_freq_factor`` kept, the band between
       smoothly interpolated. Matches HF ``_compute_llama3_parameters``
-      so converted Llama-3.1 checkpoints score identically.
+      so converted Llama-3.1 checkpoints score identically;
+    * ``"yarn"`` — :func:`yarn_inverse_frequencies` (DeepSeek-V3).
     """
     inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     t = jnp.arange(max_seq_len, dtype=jnp.float32)
@@ -65,14 +66,51 @@ def rope_frequencies(
                 inv / scaling.factor,  # low-freq: fully slowed
                 jnp.where(wavelen < hi_w, inv, smoothed),  # high: kept
             )
+        elif kind == "yarn":
+            inv = yarn_inverse_frequencies(head_dim, theta, scaling)
         else:
             raise NotImplementedError(
-                f"rope scaling type {kind!r} (supported: linear, llama3; "
+                f"rope scaling type {kind!r} (supported: linear, llama3, yarn; "
                 "'dynamic' NTK rescales per sequence length — a dynamic "
                 "shape under jit — use llama3 or linear instead)"
             )
     freqs = jnp.outer(t, inv)  # [S, D/2]
     return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def yarn_inverse_frequencies(head_dim: int, theta: float, scaling):
+    """YaRN (Peng et al. 2023) as DeepSeek-V3 applies it: a rotary pair
+    that turns more than ``beta_fast`` times inside the original window
+    keeps its frequency, one that turns fewer than ``beta_slow`` times
+    is slowed by ``factor``, and the pairs between are blended on a
+    linear ramp over the pair index. ``scaling`` carries ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``.
+    (The matching softmax temperature is :func:`yarn_mscale`.)"""
+    half = head_dim // 2
+    extra = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                             / head_dim))
+    inter = extra / scaling.factor
+    orig = scaling.original_max_position_embeddings
+
+    def pair_at(turns):  # the pair that makes ``turns`` turns in ``orig``
+        return (head_dim * math.log(orig / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_at(scaling.beta_fast)), 0)
+    high = min(math.ceil(pair_at(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0
+    )
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``
+    (1 for ``factor <= 1``); DeepSeek-V3 multiplies the softmax scale by
+    its square."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def apply_rope(
@@ -110,7 +148,8 @@ def dot_product_attention(
     dropout_rng=None,
     window: Optional[int] = None,
 ) -> jnp.ndarray:
-    """MXU-friendly grouped attention; returns [B, S, Hq, D] in q.dtype.
+    """MXU-friendly grouped attention; returns [B, S, Hq, Dv] in q.dtype
+    (``v``'s head size may differ from ``q``'s and ``k``'s).
 
     ``q_offset`` shifts query positions for the causal mask — used by
     sequence-parallel shards where the local block starts mid-sequence.
@@ -202,7 +241,7 @@ def dot_product_attention(
         )
         weights = jnp.where(keep, weights / (1.0 - dropout_rate), 0.0)
     out = jnp.einsum("bkgst,btkd->bskgd", weights.astype(q.dtype), v)
-    return out.reshape(B, S, Hq, D)
+    return out.reshape(B, S, Hq, v.shape[-1])
 
 
 def decode_positions(module, seq_len: int) -> jnp.ndarray:
@@ -245,6 +284,36 @@ def validate_write_pos(write_pos, decode: bool, positions) -> None:
             "write_pos (slot-pool decode) requires decode=True AND "
             "explicit per-row positions"
         )
+
+
+def _dense_writer(module, S: int, write_pos):
+    """``(cache_index variable, offset, advance, write(buf, new))`` of a
+    dense ``[B, max_len, H, D]`` decode cache: lockstep (every row at
+    the shared scalar ``cache_index``, which the caller advances to
+    ``advance``) or, with ``write_pos [B]``, per row (``advance`` None:
+    the scalar counter stays untouched)."""
+    ci = module.variable(
+        "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
+    )
+    if write_pos is not None:
+
+        def write(buf, new):
+            # row b's [S, H, D] update lands at its own buffer position
+            return jax.vmap(
+                lambda row, upd, pos: jax.lax.dynamic_update_slice(
+                    row, upd, (pos, 0, 0)
+                )
+            )(buf, new.astype(buf.dtype), write_pos)
+
+        return ci, write_pos, None, write
+    offset = ci.value
+
+    def write(buf, new):
+        return jax.lax.dynamic_update_slice(
+            buf, new.astype(buf.dtype), (0, offset, 0, 0)
+        )
+
+    return ci, offset, offset + S, write
 
 
 def decode_cache(
@@ -307,30 +376,7 @@ def decode_cache(
     pv = active_view()
     if pv is not None:
         return _decode_cache_paged(module, k, v, quantize, write_pos, pv)
-    ci = module.variable(
-        "cache", "cache_index", lambda: jnp.zeros((), jnp.int32)
-    )
-    if write_pos is not None:
-        offset = write_pos
-        advance = None  # per-row mode: the scalar counter stays untouched
-
-        def _write(buf, new):
-            # row b's [S, H, D] update lands at its own buffer position
-            return jax.vmap(
-                lambda row, upd, pos: jax.lax.dynamic_update_slice(
-                    row, upd, (pos, 0, 0)
-                )
-            )(buf, new.astype(buf.dtype), write_pos)
-
-    else:
-        offset = ci.value
-        advance = offset + S
-
-        def _write(buf, new):
-            return jax.lax.dynamic_update_slice(
-                buf, new.astype(buf.dtype), (0, offset, 0, 0)
-            )
-
+    ci, offset, advance, _write = _dense_writer(module, S, write_pos)
     if quantize == "int8":
         ck = module.variable(
             "cache", "cached_key", jnp.zeros, (B, max_len, H, D), jnp.int8
@@ -375,15 +421,69 @@ def decode_cache(
     return ck.value, cv.value, offset
 
 
+def decode_latent_cache(module, latent, max_len: int, value_dim: int,
+                        write_pos=None):
+    """Append ``latent [B, S, 1, F]`` to a block's ONE-leaf decode cache
+    (``cached_latent``): multi-head latent attention caches one frame a
+    token that every head shares, whose first ``value_dim`` lanes are
+    also the values. Returns ``(k_all, v_all, offset)`` for
+    :func:`attention`, as :func:`decode_cache` does: the dense
+    ``[B, max_len, 1, F]`` buffer and its ``[..., :value_dim]``; under
+    an active ``PagedView`` the pool leaf where it lies, and a
+    :class:`~.paged_attention.PagedPrefix` of it."""
+    from pytorch_distributed_tpu.ops.paged_attention import (
+        PagedPrefix,
+        active_view,
+        paged_write,
+    )
+
+    B, S, H, F = latent.shape
+    pv = active_view()
+    if pv is not None:
+        if write_pos is None:
+            raise ValueError(
+                "paged decode (an active PagedView) requires write_pos"
+            )
+        pool = module.variable("cache", "cached_latent", None)
+        _check_pool_leaf(pool.value, pv)
+        pool.value = paged_write(
+            pool.value, latent, pv.page_tables, write_pos, pv.keep, pv.layer
+        )
+        return pool.value, PagedPrefix(pool.value, value_dim), write_pos
+    ci, offset, advance, write = _dense_writer(module, S, write_pos)
+    buf = module.variable(
+        "cache", "cached_latent", jnp.zeros, (B, max_len, H, F), latent.dtype
+    )
+    buf.value = write(buf.value, latent)
+    if advance is not None:
+        ci.value = advance
+    return buf.value, buf.value[..., :value_dim], offset
+
+
+def _check_pool_leaf(leaf, pv):
+    """A cache variable under an active ``PagedView`` must be a page-pool
+    leaf; a dense ``[B, max_len, ...]`` buffer there means a caller
+    installed the view around a cache it never paged — refused loudly,
+    since the per-page write arithmetic would silently corrupt it."""
+    rank = 3 if pv.layer is None else 4
+    if leaf is None or leaf.ndim != rank or leaf.shape[-2] != pv.page_size:
+        raise ValueError(
+            f"paged decode needs a page-pool cache ([num_pages + 1, "
+            f"page_size={pv.page_size}, H * D] from "
+            f"serve.kv_slots.init_page_cache, stacked [L, ...] exactly "
+            f"when a layer scan names the layer); found "
+            f"{None if leaf is None else leaf.shape} with layer "
+            f"{'None' if pv.layer is None else 'given'}"
+        )
+
+
 def _decode_cache_paged(module, k, v, quantize, write_pos, pv):
     """The paged-pool form of ``decode_cache``: per-page writes into the
     pool frames, pool buffers returned for in-place paged attention.
 
     The cache variables must already exist with pool geometry (the
-    engine builds them via ``serve.kv_slots.init_page_cache``); a dense
-    ``[B, max_len, ...]`` buffer here means a caller installed a
-    ``PagedView`` around a cache it never paged — refused loudly, since
-    the write arithmetic below would silently corrupt it. Under a layer
+    engine builds them via ``serve.kv_slots.init_page_cache``;
+    :func:`_check_pool_leaf`). Under a layer
     scan the variables are the whole STACKED leaves (models/scan.py
     carries them) and ``pv.layer`` names this layer's plane: the write
     is one scatter into it and what is returned is still the whole
@@ -406,17 +506,7 @@ def _decode_cache_paged(module, k, v, quantize, write_pos, pv):
         names += ["cached_key_scale", "cached_value_scale"]
         news = [qk, qv, sk, sv]
     pools = [module.variable("cache", name, None) for name in names]
-    ck = pools[0].value
-    rank = 3 if pv.layer is None else 4
-    if ck is None or ck.ndim != rank or ck.shape[-2] != pv.page_size:
-        raise ValueError(
-            f"paged decode needs a page-pool cache ([num_pages + 1, "
-            f"page_size={pv.page_size}, H * D] from "
-            f"serve.kv_slots.init_page_cache, stacked [L, ...] exactly "
-            f"when a layer scan names the layer); found "
-            f"{None if ck is None else ck.shape} with layer "
-            f"{'None' if pv.layer is None else 'given'}"
-        )
+    _check_pool_leaf(pools[0].value, pv)
     for pool, new in zip(pools, news):
         pool.value = paged_write(
             pool.value, new, pv.page_tables, write_pos, pv.keep, pv.layer

@@ -169,6 +169,18 @@ class PagedKVQuant(NamedTuple):
     dtype: jnp.dtype     # the compute dtype attention should see
 
 
+class PagedPrefix(NamedTuple):
+    """The VALUE pool of a latent cache: the first ``width`` lanes of
+    every frame of ``pages``, which is the key pool itself (multi-head
+    latent attention caches one frame a token; its scores run over the
+    whole frame and its values are the frame's leading lanes).
+    ``ops.attention.decode_latent_cache`` returns it in paged mode;
+    every impl reads the one pool once and never slices it."""
+
+    pages: jnp.ndarray   # the key pool, [.., P1, ps, F]
+    width: int           # lanes of a frame that are the value
+
+
 # --------------------------------------------------------------------------
 # per-page writes
 # --------------------------------------------------------------------------
@@ -289,7 +301,7 @@ def _plane(layer, frames):
 def paged_attention(
     q: jnp.ndarray,   # [B, W, Hq, D]
     k_pages,          # [P1, ps, Hkv * D] or PagedKVQuant
-    v_pages,          # [P1, ps, Hkv * D] or PagedKVQuant
+    v_pages,          # [P1, ps, Hkv * Dv], PagedKVQuant or PagedPrefix
     *,
     page_tables: jnp.ndarray,  # [B, n_pages] int32 (bucket-sliced)
     lengths: jnp.ndarray,      # [B] int32 — tokens cached BEFORE this call
@@ -298,7 +310,7 @@ def paged_attention(
     window: Optional[int] = None,
     impl: Optional[str] = None,
 ) -> jnp.ndarray:
-    """Decode attention over the page pool; returns [B, W, Hq, D].
+    """Decode attention over the page pool; returns [B, W, Hq, Dv].
 
     Query ``j`` of row ``b`` sits at absolute position
     ``lengths[b] + j`` and attends buffer positions ``<= lengths[b] + j``
@@ -313,9 +325,27 @@ def paged_attention(
 
     With ``layer`` the pools are the STACKED leaves of a scanned model
     and every impl reads plane ``layer`` of them in place.
+
+    The case is told from the shapes handed in: the kv heads are the
+    key frame's width over ``q``'s head size, the value's head size the
+    value frame's width over them, and it may differ from the key's. A
+    :class:`PagedPrefix` value (a latent cache: one frame a token,
+    shared by every query head, values its leading lanes) reads the key
+    pool alone.
     """
     k_pages, k_scale, kdt = _unpack(k_pages)
-    v_pages, v_scale, _ = _unpack(v_pages)
+    # a latent cache hands ONE pool: its values are a prefix of the
+    # key's frame, and score and value widths differ
+    dv = None
+    if isinstance(v_pages, PagedPrefix):
+        if v_pages.pages is not k_pages or k_scale is not None:
+            raise ValueError(
+                "a PagedPrefix value must be a prefix of the (floating-"
+                "point) key pool it is handed with"
+            )
+        dv, v_pages, v_scale = v_pages.width, None, None
+    else:
+        v_pages, v_scale, _ = _unpack(v_pages)
     B, W, Hq, D = q.shape
     if k_pages.ndim != (3 if layer is None else 4):
         raise ValueError(
@@ -332,6 +362,13 @@ def paged_attention(
     if Hq % Hkv:
         raise ValueError(
             f"query heads {Hq} not a multiple of kv heads {Hkv}"
+        )
+    if dv is None:
+        dv = v_pages.shape[-1] // Hkv
+    elif Hkv != 1 or not 0 < dv <= D:
+        raise ValueError(
+            f"a PagedPrefix value of {dv} lanes needs one kv head of at "
+            f"least that many; the pool's frame is {Hkv} x {D}"
         )
     if page_tables.ndim != 2 or page_tables.shape[0] != B:
         raise ValueError(
@@ -350,16 +387,16 @@ def paged_attention(
     if impl == "gather":
         return _paged_gather(
             q, k_pages, v_pages, page_tables, lengths, scale, window,
-            k_scale, v_scale, kdt, layer,
+            k_scale, v_scale, kdt, layer, dv,
         )
     if impl == "stream":
         return paged_attention_reference(
             q, k_pages, v_pages, page_tables=page_tables, lengths=lengths,
             layer=layer, scale=scale, window=window, k_scale=k_scale,
-            v_scale=v_scale, out_dtype=kdt,
+            v_scale=v_scale, out_dtype=kdt, value_dim=dv,
         )
     return _paged_kernel_call(
-        q, k_pages, v_pages, page_tables, lengths, layer, scale, window
+        q, k_pages, v_pages, page_tables, lengths, layer, scale, window, dv
     )
 
 
@@ -381,7 +418,7 @@ def _take_frames(pages, scale_pages, frames, layer, D, dtype):
 
 
 def _paged_gather(q, k_pages, v_pages, tables, lengths, scale, window,
-                  k_scale, v_scale, kdt, layer):
+                  k_scale, v_scale, kdt, layer, dv):
     """The exact impl: materialize the bucket slab, run the SAME
     ``dot_product_attention`` the dense engine path ran. Masked tail
     keys contribute exact zeros to every reduction (the zero-tail
@@ -391,15 +428,16 @@ def _paged_gather(q, k_pages, v_pages, tables, lengths, scale, window,
     B, n = tables.shape
     D = q.shape[-1]
 
-    def dense(pages, scales):
+    def dense(pages, scales, d):
         out = _take_frames(
-            pages, scales, tables.reshape(-1), layer, D, kdt or q.dtype
+            pages, scales, tables.reshape(-1), layer, d, kdt or q.dtype
         )
         return out.reshape((B, n * out.shape[1]) + out.shape[2:])
 
+    k = dense(k_pages, k_scale, D)
+    v = k[..., :dv] if v_pages is None else dense(v_pages, v_scale, dv)
     return dot_product_attention(
-        q, dense(k_pages, k_scale), dense(v_pages, v_scale), causal=True,
-        q_offset=lengths, scale=scale, window=window,
+        q, k, v, causal=True, q_offset=lengths, scale=scale, window=window,
     )
 
 
@@ -411,7 +449,7 @@ def _paged_gather(q, k_pages, v_pages, tables, lengths, scale, window,
 def paged_attention_reference(
     q, k_pages, v_pages, *, page_tables, lengths, layer=None,
     scale: Optional[float] = None, window: Optional[int] = None,
-    k_scale=None, v_scale=None, out_dtype=None,
+    k_scale=None, v_scale=None, out_dtype=None, value_dim=None,
 ):
     """One page of K/V per ``lax.scan`` step, online-softmax carry.
 
@@ -421,11 +459,13 @@ def paged_attention_reference(
     ``[B, n*ps]`` dense slab. Reductions are reassociated page-by-page
     (rescale by ``exp(m_prev - m_new)``), so outputs match the dense
     path to last-ulp tolerance per dtype, not bitwise — the gather impl
-    is the bit-exact one.
+    is the bit-exact one. ``v_pages`` None reads the values off the key
+    frame's first ``value_dim`` lanes (a latent cache).
     """
     B, W, Hq, D = q.shape
     ps, Hkv = k_pages.shape[-2], k_pages.shape[-1] // D
     G = Hq // Hkv
+    dv = value_dim or v_pages.shape[-1] // Hkv
     n = page_tables.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(D)
@@ -433,15 +473,15 @@ def paged_attention_reference(
     qg = q.reshape(B, W, Hkv, G, D)
     qpos = lengths[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
 
-    def page(pages, scales, i):               # -> [B, ps, Hkv, D]
+    def page(pages, scales, i, d):            # -> [B, ps, Hkv, d]
         return _take_frames(
-            pages, scales, page_tables[:, i], layer, D, dtype
+            pages, scales, page_tables[:, i], layer, d, dtype
         )
 
     def body(carry, i):
         m, l, acc = carry
-        k = page(k_pages, k_scale, i)
-        v = page(v_pages, v_scale, i)
+        k = page(k_pages, k_scale, i, D)
+        v = k[..., :dv] if v_pages is None else page(v_pages, v_scale, i, dv)
         s = jnp.einsum(
             "bwkgd,bpkd->bwkgp", qg, k,
             preferred_element_type=jnp.float32,
@@ -467,13 +507,13 @@ def paged_attention_reference(
     # exp(_NEG_INF - m) terms underflow to exact 0.0 ever after
     m0 = jnp.full((B, W, Hkv, G), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, W, Hkv, G), jnp.float32)
-    acc0 = jnp.zeros((B, W, Hkv, G, D), jnp.float32)
+    acc0 = jnp.zeros((B, W, Hkv, G, dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, acc0), jnp.arange(n), length=n
     )
     safe = jnp.where(l > 0, l, 1.0)
     out = (acc / safe[..., None]).astype(q.dtype)
-    return out.reshape(B, W, Hq, D)
+    return out.reshape(B, W, Hq, dv)
 
 
 # --------------------------------------------------------------------------
@@ -482,9 +522,13 @@ def paged_attention_reference(
 
 
 def _kernel_body(lengths_ref, tables_ref, *refs, sm_scale, page_size, hkv,
-                 g, w, d, window):
+                 g, w, d, dv, window, v_in_k):
     # a stacked pool prefetches its layer too: only the index maps read it
-    q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[-7:]
+    if v_in_k:  # a latent cache: the values are the key frame's prefix
+        q_ref, k_ref, o_ref, acc_ref, m_ref, l_ref = refs[-6:]
+        v_ref = k_ref
+    else:
+        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[-7:]
     b = pl.program_id(0)
     i = pl.program_id(1)
     n = pl.num_programs(1)
@@ -521,7 +565,7 @@ def _kernel_body(lengths_ref, tables_ref, *refs, sm_scale, page_size, hkv,
             # kv head h is the static lane range [h * D, (h + 1) * D)
             q = q_ref[0, h]                          # [rows, D]
             k = k_ref[0, :, h * d:(h + 1) * d]       # [ps, D]
-            v = v_ref[0, :, h * d:(h + 1) * d]
+            v = v_ref[0, :, h * dv:(h + 1) * dv]
             s = _mxu_dot(q, k, 1, 1) * sm_scale      # [rows, ps]
             s = jnp.where(keep, s, _NEG_INF)
             m_prev = m_ref[h, :, :1]                 # [rows, 1] (lanes
@@ -549,7 +593,7 @@ def _interpret() -> bool:
 
 
 def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, layer, scale,
-                       window):
+                       window, dv):
     B, W, Hq, D = q.shape
     ps, F = k_pages.shape[-2:]
     Hkv = F // D
@@ -569,24 +613,32 @@ def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, layer, scale,
     # streams exactly the pages this row owns — and, for a stacked
     # pool, the plane from the prefetched layer
     scalars = (lengths.astype(jnp.int32), tables.astype(jnp.int32))
-    if layer is None:
-        kv_spec = pl.BlockSpec(
-            (1, ps, F), lambda b, i, lens, tabs: (tabs[b, i], 0, 0)
-        )
-    else:
+    if layer is not None:
         scalars += (jnp.asarray(layer, jnp.int32).reshape(1),)
-        kv_spec = pl.BlockSpec(
-            (None, 1, ps, F),
+
+    def kv_spec(width):
+        if layer is None:
+            return pl.BlockSpec(
+                (1, ps, width), lambda b, i, lens, tabs: (tabs[b, i], 0, 0)
+            )
+        return pl.BlockSpec(
+            (None, 1, ps, width),
             lambda b, i, lens, tabs, lay: (lay[0], tabs[b, i], 0, 0),
         )
-    q_spec = pl.BlockSpec((1, Hkv, rows, D), lambda b, i, *_: (b, 0, 0, 0))
+
+    def row_spec(width):
+        return pl.BlockSpec(
+            (1, Hkv, rows, width), lambda b, i, *_: (b, 0, 0, 0)
+        )
+
+    pools = (k_pages,) if v_pages is None else (k_pages, v_pages)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(B, n),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=q_spec,
+        in_specs=[row_spec(D)] + [kv_spec(p.shape[-1]) for p in pools],
+        out_specs=row_spec(dv),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, rows, D), jnp.float32),    # acc
+            pltpu.VMEM((Hkv, rows, dv), jnp.float32),   # acc
             pltpu.VMEM((Hkv, rows, 128), jnp.float32),  # running max
             pltpu.VMEM((Hkv, rows, 128), jnp.float32),  # running sum
         ],
@@ -594,10 +646,10 @@ def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, layer, scale,
     out = pl.pallas_call(
         functools.partial(
             _kernel_body, sm_scale=scale, page_size=ps, hkv=Hkv, g=G,
-            w=W, d=D, window=window,
+            w=W, d=D, dv=dv, window=window, v_in_k=v_pages is None,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, dv), q.dtype),
         # rows are independent; the page dimension is sequential
         # ("arbitrary"): the online-softmax scratch must persist across
         # page steps, like flash's k dimension
@@ -606,6 +658,6 @@ def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, layer, scale,
         ),
         interpret=_interpret(),
         name="paged_attention",
-    )(*scalars, qf, k_pages, v_pages)
-    out = out[:, :, :W * G].reshape(B, Hkv, W, G, D)
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, W, Hq, D)
+    )(*scalars, qf, *pools)
+    out = out[:, :, :W * G].reshape(B, Hkv, W, G, dv)
+    return out.transpose(0, 2, 1, 3, 4).reshape(B, W, Hq, dv)

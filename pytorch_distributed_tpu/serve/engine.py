@@ -87,6 +87,7 @@ from pytorch_distributed_tpu.ops.paged_attention import (
     refuse_kernel_for,
     resolve_paged_attention_impl,
 )
+from pytorch_distributed_tpu.ops.moe import collect_route_stats
 from pytorch_distributed_tpu.runtime import faults
 from pytorch_distributed_tpu.runtime import tracing
 from pytorch_distributed_tpu.serve.kv_slots import (
@@ -115,6 +116,27 @@ from pytorch_distributed_tpu.speculative import speculative_accept
 from pytorch_distributed_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
+
+
+def _with_route_stats(tokens, routed):
+    """The tokens a program sends down, with its expert layers' routing
+    counters (``ops.moe.collect_route_stats``: ``[layers, 3]``) behind
+    them in the same int32 vector; a model without expert layers sends
+    its tokens as they are."""
+    if routed is None:
+        return tokens
+    return jnp.concatenate([tokens.reshape(-1), routed.reshape(-1)])
+
+
+def _route_args(down, n_tokens: int):
+    """Span args from what :func:`_with_route_stats` packed behind the
+    ``n_tokens`` tokens, one entry an expert layer."""
+    stats = np.asarray(down).reshape(-1)[n_tokens:].reshape(-1, 3)
+    return {
+        "expert_pairs": stats[:, 0].tolist(),
+        "experts_hit": stats[:, 1].tolist(),
+        "expert_peak": stats[:, 2].tolist(),
+    }
 
 
 @dataclasses.dataclass(frozen=True)
@@ -374,6 +396,9 @@ class ServeEngine:
         self._active_cached = None
         self._steps = 0
         self._decode_ticks = 0
+        # armed only: (span, tokens + routing counters) of dispatched
+        # chunks whose counters have not been read down yet
+        self._route_pending: list = []
         self.prefill_compiles = 0
         self.decode_compiles = 0
         # length buckets: the static widths the prefill/decode programs
@@ -500,7 +525,7 @@ class ServeEngine:
             ids,
             decode=True,
             cache_len=n_pages * self.pool.page_size,
-            mutable=["cache"],
+            mutable=["cache", "intermediates"],
             positions=positions,
             write_pos=jnp.asarray(start, jnp.int32)[None],
         )
@@ -508,7 +533,9 @@ class ServeEngine:
             cache, state["cache"], row_pt, positions,
             jnp.ones((1, C), bool),
         )
-        return logits, cache
+        return logits, cache, collect_route_stats(
+            state.get("intermediates", {})
+        )
 
     def _prefill_tail(self, logits, slot, start, last_idx, final, toks,
                       lengths, keys, temps, top_ks, top_ps):
@@ -543,7 +570,7 @@ class ServeEngine:
         self._prefill_bucket_compiles[n_pages] = (
             self._prefill_bucket_compiles.get(n_pages, 0) + 1
         )
-        logits, cache = self._prefill_chunk_body(
+        logits, cache, routed = self._prefill_chunk_body(
             self.model, params, self.pool, cache, pt, ids, slot, start,
             n_pages,
         )
@@ -551,7 +578,7 @@ class ServeEngine:
             logits, slot, start, last_idx, final, toks, lengths, keys,
             temps, top_ks, top_ps,
         )
-        return cache, tok, toks, lengths, keys
+        return cache, _with_route_stats(tok, routed), toks, lengths, keys
 
     def _prefill_spec_fn(self, params, dparams, cache, dcache, pt, dpt,
                          ids, slot, start, last_idx, final, toks,
@@ -563,11 +590,11 @@ class ServeEngine:
         self._prefill_bucket_compiles[n_pages] = (
             self._prefill_bucket_compiles.get(n_pages, 0) + 1
         )
-        logits, cache = self._prefill_chunk_body(
+        logits, cache, _ = self._prefill_chunk_body(
             self.model, params, self.pool, cache, pt, ids, slot, start,
             n_pages,
         )
-        _, dcache = self._prefill_chunk_body(
+        _, dcache, _ = self._prefill_chunk_body(
             self.spec.draft_model, dparams, self.draft_pool, dcache, dpt,
             ids, slot, start, n_pages,
         )
@@ -640,19 +667,19 @@ class ServeEngine:
                 page_tables=ptb, keep=active,
                 page_size=self.pool.page_size,
             )):
-                last, cache = decode_step_body(
+                last, cache, sown = decode_step_body(
                     self.model, params, cache, toks,
                     cache_len=self.config.max_len,
                     positions=lengths[:, None],
-                    write_pos=lengths,
+                    write_pos=lengths, with_intermediates=True,
                 )
         else:
             dense = gather_pages(cache, pt, self.pool.tails)
-            last, dense = decode_step_body(
+            last, dense, sown = decode_step_body(
                 self.model, params, dense, toks,
                 cache_len=self.config.max_len,
                 positions=lengths[:, None],
-                write_pos=lengths,
+                write_pos=lengths, with_intermediates=True,
             )
             # persist ONLY the decoding rows' written token; free and
             # mid-prefill rows drop their write on the floor
@@ -668,7 +695,10 @@ class ServeEngine:
         toks_out = jnp.where(active, nxt, toks)
         lengths_out = lengths + active.astype(jnp.int32)
         keys_out = jnp.where(active[:, None], pair[:, 0], keys)
-        return cache, nxt, toks_out, lengths_out, keys_out
+        # an expert layer's routing counters ride down with the tokens
+        # the host fetches anyway: no transfer or sync of their own
+        down = _with_route_stats(nxt, collect_route_stats(sown))
+        return cache, down, toks_out, lengths_out, keys_out
 
     def _spec_fn(self, params, dparams, cache, dcache, pt, dpt, toks,
                  lengths, keys, temps, top_ks, top_ps, active, n_pages):
@@ -1409,6 +1439,12 @@ class ServeEngine:
                     tok = self._dispatch_prefill_spec(
                         ids, slot, plan, n_pages
                     )
+            if tracing._tracer is not None and tok.ndim:
+                # the chunk's routing counters sit behind its token; they
+                # land on the span once this step has waited for the
+                # device anyway (a token fetch), never by a wait of
+                # their own
+                self._route_pending.append((span, tok))
             # the recompile sentinel's once-contract is per PROGRAM —
             # with buckets, a bucket IS a program, so single-bucket
             # engines keep the plain name and multi-bucket engines get
@@ -1470,7 +1506,18 @@ class ServeEngine:
             )
         )
         with span:
-            return int(tok)
+            # behind the token of an expert model ride its counters
+            tok = int(np.asarray(tok)[0]) if tok.ndim else int(tok)
+        self._land_route_stats()
+        return tok
+
+    def _land_route_stats(self) -> None:
+        """Armed only, right after a token fetch: the chunks dispatched
+        before it have run, so their counters are read without waiting
+        and set on their (closed) ``serve.prefill_chunk`` spans."""
+        for span, tok in self._route_pending:
+            span.set(**_route_args(tok, 1))
+        self._route_pending.clear()
 
     def _live_pages(self, decoding) -> int:
         """Pages the tick's kernel computes on: for each active row,
@@ -1533,6 +1580,9 @@ class ServeEngine:
         with tracing.span("serve.token_fetch"):
             # the one per-tick device sync: every sampled token comes down
             nxt = np.asarray(nxt)
+        if tracing._tracer is not None and nxt.size > self.config.num_slots:
+            span.set(**_route_args(nxt, self.config.num_slots))
+            self._land_route_stats()
         fault_armed = faults.active()
         with tracing.span("serve.emit"):
             for slot, h in decoding:

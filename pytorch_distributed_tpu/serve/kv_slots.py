@@ -123,12 +123,15 @@ def auto_page_size(max_len: int, cap: int = 32) -> int:
 
 def page_axis(path, leaf) -> Optional[int]:
     """Page axis of a POOL leaf, or None for shared counters: KV
-    payloads and their int8 scales are ``[..., P + 1, page_size, F]``
-    (a leading ``[L]`` when layers are scanned), so it is ``ndim - 3``.
-    The pool's counterpart of ``generation.cache_batch_axis``, which
-    speaks for the DENSE ``[..., B, T, H, D]`` views (and knows the
-    leaves by name for both)."""
-    return None if cache_batch_axis(path, leaf) is None else leaf.ndim - 3
+    payloads, their int8 scales and a latent cache's one leaf are
+    ``[..., P + 1, page_size, F]`` (a leading ``[L]`` when layers are
+    scanned; a model may hold stacked and unstacked leaves side by
+    side), so it is ``ndim - 3``; counters are rank 0 (``[L]`` when
+    scanned). The pool's counterpart of ``generation.cache_batch_axis``,
+    which speaks for the DENSE ``[..., B, T, H, D]`` views; both tell a
+    leaf by its geometry, not its name."""
+    del path
+    return leaf.ndim - 3 if leaf.ndim >= 3 else None
 
 
 def _page_cache(model, params, num_pages: int, page_size: int):

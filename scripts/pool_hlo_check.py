@@ -35,7 +35,7 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-CELLS = ("mistral-serve-sat", "gpt2m-serve-chat-p80")
+CELLS = ("mistral-serve-sat", "gpt2m-serve-chat-p80", "gigachat-serve-sat")
 # what may have a pool-sized result: the pool itself passing by, the
 # loop that carries it, and the one operation that writes it
 ALLOWED = {
@@ -100,7 +100,7 @@ def check_program(name, compiled, frames, floor, n_leaves):
     alias = aliased_outputs(text)
     pool_params = [
         i for i, p in enumerate(params)
-        if "cached_key" in p or "cached_value" in p
+        if "cached_" in p  # cached_key / cached_value / cached_latent
     ]
     aliased = sorted(set(alias.values()) & set(pool_params))
     mem = compiled.memory_analysis()
@@ -232,6 +232,9 @@ def main(argv=None) -> int:
         paged = sys.modules["pytorch_distributed_tpu.ops.paged_attention"]
         paged._interpret = lambda: False
         paged._IMPL = "kernel"
+        sys.modules["pytorch_distributed_tpu.ops.moe"]._interpret = (
+            lambda: False
+        )
         print(f"# compiled for a described {args.describe}, not run")
     else:
         dev = jax.devices()[0]

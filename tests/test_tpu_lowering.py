@@ -27,6 +27,7 @@ from pytorch_distributed_tpu.ops.paged_attention import paged_attention
 # modules themselves are reached through sys.modules
 _FLASH = sys.modules["pytorch_distributed_tpu.ops.flash_attention"]
 _PAGED = sys.modules["pytorch_distributed_tpu.ops.paged_attention"]
+_MOE = sys.modules["pytorch_distributed_tpu.ops.moe"]
 
 # (query heads, kv heads, head dim): GPT-2-medium — the chip smoke's
 # model — and the 32/8 x 128 GQA geometry of the Mistral-width cells
@@ -38,6 +39,7 @@ def compiled_kernels(monkeypatch):
     """Interpret mode off: the kernels lower through Mosaic."""
     monkeypatch.setattr(_FLASH, "_interpret", lambda: False)
     monkeypatch.setattr(_PAGED, "_interpret", lambda: False)
+    monkeypatch.setattr(_MOE, "_interpret", lambda: False)
 
 
 def _assert_lowers_for_tpu(fn, *args):
@@ -75,6 +77,49 @@ def test_paged_kernel_lowers(Hq, Hkv, D, W, window, dtype, L):
         _sds((B, n), "int32"),
         _sds((B,), "int32"),
         _sds((), "int32"),
+    )
+
+
+@pytest.mark.parametrize("L", [None, 4], ids=["leaf", "stacked"])
+@pytest.mark.parametrize("W", [1, 2])
+def test_paged_kernel_lowers_on_latent_pages(W, L):
+    """The latent case at the published widths: 64 query heads against
+    ONE 640-lane frame a token (512 latent + 64 rotary key + padding),
+    the values its first 512 lanes, told from the shapes and the
+    ``PagedPrefix`` handed in."""
+    B, ps, n, H, F, r = 8, 16, 64, 64, 640, 512
+    pool = (B * n + 1, ps, F)
+    stacked = L is not None
+    _assert_lowers_for_tpu(
+        lambda q, k, t, l, lay: paged_attention(
+            q, k, _PAGED.PagedPrefix(k, r), page_tables=t, lengths=l,
+            layer=lay if stacked else None, impl="kernel", scale=0.1,
+        ),
+        _sds((B, W, H, F), "bfloat16"),
+        _sds((L,) + pool if stacked else pool, "bfloat16"),
+        _sds((B, n), "int32"),
+        _sds((B,), "int32"),
+        _sds((), "int32"),
+    )
+
+
+@pytest.mark.parametrize("T,dtype", [(128, "bfloat16"), (512, "bfloat16"),
+                                     (24, "float32")])
+def test_expert_gmm_lowers(T, dtype):
+    """The grouped product at the published expert widths (16 held of
+    256, 8 a token): a tick's 128 rows and a chunk's 512, both ways
+    through an expert (7168 -> 2048 -> 7168)."""
+    E, K, D, F = 16, 8, 7168, 2048
+    tm = _MOE.row_tile(T * K)
+    tiles = -(-T * K // tm) + E
+
+    def both(x, w_in, w_out, te, nt):
+        h = _MOE.expert_gmm(x, w_in, te, nt, tm)
+        return _MOE.expert_gmm(h, w_out, te, nt, tm)
+
+    _assert_lowers_for_tpu(
+        both, _sds((tiles * tm, D), dtype), _sds((E, D, F), dtype),
+        _sds((E, F, D), dtype), _sds((tiles,), "int32"), _sds((), "int32"),
     )
 
 
@@ -186,7 +231,8 @@ def no_compile_cache():
 
 
 @pytest.mark.parametrize(
-    "cell", ["mistral-serve-sat", "gpt2m-serve-chat-p80"]
+    "cell",
+    ["mistral-serve-sat", "gpt2m-serve-chat-p80", "gigachat-serve-sat"],
 )
 def test_compiled_serving_programs_leave_the_pool_in_place(
     cell, one_v5e, no_compile_cache, monkeypatch
@@ -216,4 +262,9 @@ def test_compiled_serving_programs_leave_the_pool_in_place(
         pool = {i for i, p in enumerate(params) if "cached_" in p}
         aliased = set(pool_hlo_check.aliased_outputs(text).values())
         assert len(pool) == n_leaves and pool <= aliased, name
-    assert "tpu_custom_call" in programs[0][1].as_text()
+    tick = programs[0][1].as_text()
+    assert "tpu_custom_call" in tick
+    if cell == "gigachat-serve-sat":
+        # the absorbed kernel (the dense layer's leaf and the stack's)
+        # and the expert layer's three grouped products
+        assert tick.count('custom_call_target="tpu_custom_call"') == 5
