@@ -619,7 +619,7 @@ def attention(
             )
         return _paged_attention(
             q, k, v, page_tables=pv.page_tables, lengths=q_offset,
-            layer=pv.layer, scale=scale, window=window,
+            layer=pv.layer, keep=pv.keep, scale=scale, window=window,
         )
 
     # q_offset may be a traced value (KV-cache decode); only a static

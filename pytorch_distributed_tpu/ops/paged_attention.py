@@ -43,16 +43,31 @@ Three interchangeable implementations, selected by
   transient). Online softmax REORDERS the reductions, so parity with
   the dense path is last-ulp-class, not bitwise — pinned per dtype
   with explicit tolerances.
-* ``"kernel"`` — the Pallas TPU kernel: grid ``(B, n_pages)``, one step
-  per page of one row serving ALL heads, page frames resolved through
-  the scalar-prefetched page table (``pltpu.PrefetchScalarGridSpec`` —
-  the index map reads the table, so the DMA streams exactly the pages
-  the row owns). The pool is stored ``[P1, ps, Hkv * D]``, so a frame
-  arrives as one lane-dense tile and kv head ``h`` is the static lane
-  range ``[h * D, (h + 1) * D)``; a query head's group shares its kv head's
-  tile (no KV replication to q heads); the online-softmax carry lives in
-  VMEM scratch. ``interpret=True`` off-TPU, like every Pallas kernel in
-  this repo.
+* ``"kernel"`` — the Pallas TPU kernel. It walks what a row OWNS, many
+  pages a step: the grid is over groups of rows, and each row runs as
+  many steps as it has BLOCKS of live pages (``row_walk``: the pages
+  its prefetched length and the call's W queries reach, in blocks of
+  ``block_pages`` pages, 512 tokens at the serving cells' frames) — a
+  slot that is not decoding (``keep`` False) runs none and reads zeros,
+  and a table wider than its rows costs nothing. The pools are operands
+  in whatever memory they lie in (``pl.ANY``): a block's live page
+  frames are copied one ``make_async_copy`` each, through the prefetched
+  table (and layer, for a stacked leaf), into one of two VMEM buffers
+  ``[k * ps, Hkv * D]``, and the next block's copies — the row's next
+  block, or the first block of the next row that has any — are started
+  before the current block's products, so the fetch hides behind the
+  arithmetic. A block is multiplied as it lies, lane-dense: the queries
+  of ALL kv heads form one block-diagonal ``[Hkv * rows, Hkv * D]``
+  operand (head ``h``'s rows hold its queries in lanes ``[h * D, (h + 1)
+  * D)``, zeros elsewhere), so scores are ONE ``[Hkv * rows, D'] x [D',
+  k * ps]`` product and values one ``[.., k * ps] x [k * ps, Hkv * Dv]``
+  a block, every frame passing the multiplier once in whole 128-lane
+  tiles; head ``h``'s output is lanes ``[h * Dv, (h + 1) * Dv)`` of its
+  own rows. The partial last block masks by position (causal,
+  ``window``); its unfetched tail keeps finite stale frames that the
+  masked scores weigh by an exact 0.0, so no page past a row's length
+  is ever read. The online-softmax carry lives in VMEM scratch.
+  ``interpret=True`` off-TPU, like every Pallas kernel in this repo.
 
 ``"auto"`` resolves to ``"kernel"`` on TPU and ``"gather"`` elsewhere:
 the gather impl is the provably-exact CPU/CI path, and on the chip the
@@ -61,9 +76,10 @@ libtpu 0.0.34, chip_smoke.py): the kernel compiles in a few seconds and
 matches ``"gather"`` — f32 to ~2e-6, bf16 to ~5e-3 of the output scale —
 for 16/16 heads of 64 and 32/8 heads of 128, W = 1 and 5, with and
 without a window, at page sizes 4-32 (f32) and 8-32 (bf16); smaller
-pages were not tried. GPT-2-medium serves through it end to end. It has
-no steady-state timing against ``"gather"`` yet, so which of the two
-should be the TPU default is still open.
+pages were not tried (the single-page body of PR 21; the blocked walk
+of PR 28 was compared with ``"gather"`` at the serving cells' shapes by
+``scripts/paged_kernel_bench.py``, bf16 to ~4e-3). Every serving cell
+of the benchmark serves through it; PERF.md §6 has its timings.
 
 int8 KV caches (``kv_cache_quantize="int8"``): payload + per-token
 scale pools ride together (:class:`PagedKVQuant`); gather/stream
@@ -272,7 +288,7 @@ def resolve_paged_attention_impl(impl: Optional[str] = None) -> str:
     return "kernel" if jax.default_backend() == "tpu" else "gather"
 
 
-def refuse_kernel_for(*, quantized: bool) -> None:
+def refuse_kernel_for(*, quantized: bool, page_size: int = 8) -> None:
     """Raise for the pools the ``"kernel"`` impl cannot serve, naming
     the way out — never a quiet reroute to another impl, which would
     hide from a measurement what actually ran."""
@@ -282,6 +298,13 @@ def refuse_kernel_for(*, quantized: bool) -> None:
             "only; an int8 KV cache (kv_cache_quantize='int8') needs "
             "set_paged_attention_impl('gather') (or 'stream') — on a "
             "TPU 'auto' resolves to 'kernel'"
+        )
+    if page_size % 8 and not _interpret():
+        raise ValueError(
+            f"the paged-attention kernel copies each page frame to a "
+            f"whole sublane tile of its block: on a TPU page_size must "
+            f"be a multiple of 8, got {page_size} — pass a larger "
+            f"page_size or set_paged_attention_impl('gather')"
         )
 
 
@@ -306,6 +329,7 @@ def paged_attention(
     page_tables: jnp.ndarray,  # [B, n_pages] int32 (bucket-sliced)
     lengths: jnp.ndarray,      # [B] int32 — tokens cached BEFORE this call
     layer=None,                # int32 scalar: pools are [L, P1, ps, ...]
+    keep: Optional[jnp.ndarray] = None,  # [B] bool: rows that decode
     scale: Optional[float] = None,
     window: Optional[int] = None,
     impl: Optional[str] = None,
@@ -324,7 +348,10 @@ def paged_attention(
     so the null page's contents are unobservable (pinned by test).
 
     With ``layer`` the pools are the STACKED leaves of a scanned model
-    and every impl reads plane ``layer`` of them in place.
+    and every impl reads plane ``layer`` of them in place. ``keep``
+    (the engine's write gate) names the rows that decode: the kernel
+    walks no page of a row it marks False and returns zeros for it; the
+    other impls ignore it, and the caller discards such rows anyway.
 
     The case is told from the shapes handed in: the kv heads are the
     key frame's width over ``q``'s head size, the value's head size the
@@ -383,7 +410,7 @@ def paged_attention(
         scale = 1.0 / math.sqrt(D)
     impl = resolve_paged_attention_impl(impl)
     if impl == "kernel":
-        refuse_kernel_for(quantized=k_scale is not None)
+        refuse_kernel_for(quantized=k_scale is not None, page_size=ps)
     if impl == "gather":
         return _paged_gather(
             q, k_pages, v_pages, page_tables, lengths, scale, window,
@@ -396,7 +423,8 @@ def paged_attention(
             v_scale=v_scale, out_dtype=kdt, value_dim=dv,
         )
     return _paged_kernel_call(
-        q, k_pages, v_pages, page_tables, lengths, layer, scale, window, dv
+        q, k_pages, v_pages, page_tables, lengths, keep, layer, scale,
+        window, dv,
     )
 
 
@@ -517,144 +545,270 @@ def paged_attention_reference(
 
 
 # --------------------------------------------------------------------------
-# "kernel": Pallas, pages streamed through the scalar-prefetched table
+# "kernel": Pallas, a row's live pages fetched a block at a time
 # --------------------------------------------------------------------------
 
+# what ONE pool's double buffer may take of VMEM, and the most tokens a
+# block spans: the table of scripts/paged_kernel_bench.py (PERF.md §6,
+# PR 28) chose both
+_BLOCK_VMEM_BYTES = 2 << 20
+_BLOCK_MAX_TOKENS = 512
 
-def _kernel_body(lengths_ref, tables_ref, *refs, sm_scale, page_size, hkv,
-                 g, w, d, dv, window, v_in_k):
-    # a stacked pool prefetches its layer too: only the index maps read it
-    if v_in_k:  # a latent cache: the values are the key frame's prefix
-        q_ref, k_ref, o_ref, acc_ref, m_ref, l_ref = refs[-6:]
-        v_ref = k_ref
-    else:
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs[-7:]
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    n = pl.num_programs(1)
-    rows = q_ref.shape[2]     # g * w query rows per kv head, sublane-padded
 
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+def block_pages(page_size: int, token_bytes: int, n_pages: int) -> int:
+    """Pages the kernel fetches and multiplies a step (a BLOCK), derived
+    from what the call shows: two blocks of the widest pool's frames
+    (``token_bytes`` a token) fit the VMEM budget, a block spans at most
+    ``_BLOCK_MAX_TOKENS`` and never more than the table's ``n_pages``;
+    a power of two."""
+    k = min(
+        _BLOCK_VMEM_BYTES // (2 * page_size * token_bytes),
+        _BLOCK_MAX_TOKENS // page_size,
+        n_pages,
+    )
+    return 1 << max(k, 1).bit_length() - 1
 
-    length = lengths_ref[b]
 
-    # pages wholly past the row's last query hold nothing it may see
-    # (their table entries are the null page, whose repeated block index
-    # the pipeline does not even re-fetch): skip their compute
-    @pl.when(i * page_size <= length + w - 1)
-    def _compute():
-        # rows are ordered (query j, group member): row r is query r // g.
-        # Counted with compares because Mosaic has no vector integer divide
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, page_size), 0)
-        j = jnp.zeros_like(row)
-        for t in range(1, w):
-            j = j + (row >= t * g).astype(jnp.int32)
-        qpos = length + j
-        kpos = i * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1
+def row_walk(lengths, w: int, page_size: int, n_pages: int, k: int):
+    """What the kernel walks of each row: ``(pages, blocks)``, the pages
+    a row of ``lengths`` cached tokens and ``w`` queries reaches (never
+    past the table) and the blocks of ``k`` pages it fetches them in.
+    Plain arithmetic over a numpy or a jax array: the kernel's wrapper
+    and the engine's ``fetched_pages`` share it."""
+    pages = (-(-(lengths + w) // page_size)).clip(None, n_pages)
+    return pages, -(-pages // k)
+
+
+def _kernel_body(lengths_ref, pages_ref, next_ref, tables_ref, *refs,
+                 sm_scale, page_size, k, hkv, g, w, d, dv, window,
+                 n_pools, stacked):
+    # after the prefetched scalars (a stacked pool's plane among them):
+    # the queries, the pools where they lie, the output, then scratch
+    refs = list(refs)
+    layer_ref = refs.pop(0) if stacked else None
+    q_ref, o_ref = refs.pop(0), refs.pop(n_pools)
+    pools, bufs = refs[:n_pools], refs[n_pools:2 * n_pools]
+    sems, slot_ref, q_all_ref, acc_ref, m_ref, l_ref = refs[2 * n_pools:]
+    # a latent cache: the values are the key frame's leading lanes
+    k_buf, v_buf = bufs[0], bufs[-1]
+    # batch rows a grid step; g * w query rows a kv head, sublane-padded
+    group, _, rows, _ = q_ref.shape
+    n_rows = pl.num_programs(0) * group
+    step = pl.program_id(0)
+    kps = k * page_size
+
+    def copies(row, blk, slot, wait):
+        """Start (or wait for) the copies of block ``blk`` of ``row``:
+        one per LIVE page frame and pool, from where the frame lies in
+        the pool to its place in buffer ``slot``. Pages past the row's
+        last are neither fetched nor waited for."""
+        first = blk * k
+
+        def page(j, carry):
+            frame = tables_ref[row, first + j]
+            at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for p in range(n_pools):
+                src = (
+                    pools[p].at[layer_ref[0], frame] if stacked
+                    else pools[p].at[frame]
+                )
+                copy = pltpu.make_async_copy(
+                    src, bufs[p].at[slot, at], sems.at[p, slot]
+                )
+                copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(k, pages_ref[row] - first), page, 0
         )
-        keep = qpos >= kpos
-        if window is not None:
-            keep = jnp.logical_and(keep, qpos - kpos < window)
-        for h in range(hkv):
-            # the page frame arrives as one lane-dense [ps, Hkv * D] tile;
-            # kv head h is the static lane range [h * D, (h + 1) * D)
-            q = q_ref[0, h]                          # [rows, D]
-            k = k_ref[0, :, h * d:(h + 1) * d]       # [ps, D]
-            v = v_ref[0, :, h * dv:(h + 1) * dv]
-            s = _mxu_dot(q, k, 1, 1) * sm_scale      # [rows, ps]
-            s = jnp.where(keep, s, _NEG_INF)
-            m_prev = m_ref[h, :, :1]                 # [rows, 1] (lanes
-            l_prev = l_ref[h, :, :1]                 #  replicated)
-            m_cur = jnp.max(s, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + _mxu_dot(
-                p.astype(v.dtype), v, 1, 0
-            )
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
-    @pl.when(i == n - 1)
-    def _finalize():
-        l = l_ref[:, :, :1]
-        safe = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_ref[:] / safe).astype(o_ref.dtype)
+    @pl.when(step == 0)
+    def _prologue():
+        # a block's unfetched tail keeps what the buffer held: masked
+        # scores weigh it by an exact 0.0, so it has to be finite —
+        # zeros now, live frames fetched earlier ever after
+        def fill(j, carry):
+            at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for buf in bufs:
+                for slot in range(2):
+                    buf[slot, at, :] = jnp.zeros(
+                        (page_size, buf.shape[2]), buf.dtype
+                    )
+            return carry
+
+        jax.lax.fori_loop(0, k, fill, 0)
+        # the queries of all kv heads as ONE block-diagonal operand:
+        # head h's rows hold its queries in lanes [h * D, (h + 1) * D),
+        # the frame's own layout, and zeros elsewhere, for good
+        q_all_ref[:] = jnp.zeros_like(q_all_ref)
+        slot_ref[0] = 0
+
+        @pl.when(next_ref[0] < n_rows)
+        def _first():
+            copies(next_ref[0], 0, 0, wait=False)
+
+    # rows are ordered (kv head, query j, group member): row r of a
+    # head's ``rows`` is query r // g. Counted with compares because
+    # Mosaic has no vector integer divide
+    row_of = jax.lax.broadcasted_iota(jnp.int32, (rows, kps), 0)
+    j = jnp.zeros_like(row_of)
+    for t in range(1, w):
+        j = j + (row_of >= t * g).astype(jnp.int32)
+    j = jnp.concatenate([j] * hkv, axis=0)            # [hkv * rows, kps]
+    col = jax.lax.broadcasted_iota(jnp.int32, j.shape, 1)
+
+    def row(r, carry):
+        b = step * group + r
+        n_blocks = -(-pages_ref[b] // k)
+
+        @pl.when(n_blocks == 0)
+        def _not_decoding():
+            o_ref[r] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+        @pl.when(n_blocks > 0)
+        def _walk():
+            for h in range(hkv):
+                q_all_ref[h * rows:(h + 1) * rows, h * d:(h + 1) * d] = (
+                    q_ref[r, h]
+                )
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            qpos = lengths_ref[b] + j
+
+            def block(i, slot):
+                # the block after this one — the row's next, or the
+                # first of the next row that has any — is on its way
+                # while this one multiplies
+                last = i + 1 == n_blocks
+                nrow = jnp.where(last, next_ref[b + 1], b)
+
+                @pl.when(nrow < n_rows)
+                def _prefetch():
+                    copies(
+                        nrow, jnp.where(last, 0, i + 1), 1 - slot,
+                        wait=False,
+                    )
+
+                copies(b, i, slot, wait=True)
+                kpos = i * kps + col
+                keep = qpos >= kpos
+                if window is not None:
+                    keep = jnp.logical_and(keep, qpos - kpos < window)
+                # ONE product against the block as it lies, [k * ps,
+                # Hkv * D] lane-dense: the zeros of the block-diagonal
+                # queries keep the heads apart, and every frame passes
+                # the multiplier once, in whole tiles
+                s = _mxu_dot(q_all_ref[:], k_buf[slot], 1, 1) * sm_scale
+                s = jnp.where(keep, s, _NEG_INF)        # [hkv * rows, kps]
+                m_prev = m_ref[:, :1]                   # [.., 1] (lanes
+                l_prev = l_ref[:, :1]                   #  replicated)
+                m_cur = jnp.max(s, axis=-1, keepdims=True)
+                m_new = jnp.maximum(m_prev, m_cur)
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - m_new)
+                l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                v = v_buf[slot, :, :hkv * dv]
+                acc_ref[:] = acc_ref[:] * alpha + _mxu_dot(
+                    p.astype(v.dtype), v, 1, 0
+                )
+                m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+                l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+                return 1 - slot
+
+            # as many steps as the row has blocks
+            slot_ref[0] = jax.lax.fori_loop(0, n_blocks, block, slot_ref[0])
+            l = l_ref[:, :1]
+            safe = jnp.where(l > 0, l, 1.0)
+            for h in range(hkv):  # head h's own lanes of its own rows
+                at = slice(h * rows, (h + 1) * rows)
+                o_ref[r, h] = (
+                    acc_ref[at, h * dv:(h + 1) * dv] / safe[at]
+                ).astype(o_ref.dtype)
+
+        return carry
+
+    jax.lax.fori_loop(0, group, row, 0)
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, layer, scale,
-                       window, dv):
+def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, keep, layer,
+                       scale, window, dv):
     B, W, Hq, D = q.shape
     ps, F = k_pages.shape[-2:]
     Hkv = F // D
     G = Hq // Hkv
     n = tables.shape[1]
-    # one grid step serves ALL heads of one page of one row. The TPU
-    # lowering wants the last two block dims to be (8, 128)-divisible or
-    # whole: the block is the whole [ps, Hkv * D] frame, as the pool
-    # stores it — the operand is the leaf itself, never a view of it.
+    pools = (k_pages,) if v_pages is None else (k_pages, v_pages)
+    k = block_pages(ps, F * k_pages.dtype.itemsize, n)
+    # the grid is the rows; a row's steps are its own blocks, counted
+    # in the kernel from the prefetched pages it reaches. A row that is
+    # not decoding reaches none
+    pages, _ = row_walk(lengths.astype(jnp.int32), W, ps, n, k)
+    if keep is not None:
+        pages = jnp.where(keep, pages, 0)
+    # next_row[b]: the first row at or after b with a page (B: none)
+    rows_with = jnp.where(pages > 0, jnp.arange(B, dtype=jnp.int32), B)
+    next_row = jnp.concatenate([
+        jax.lax.cummin(rows_with, reverse=True),
+        jnp.full((1,), B, jnp.int32),
+    ])
     # Queries go [B, Hkv, W * G, D], rows zero-padded to the sublane tile
     rows = -(-W * G // 8) * 8
     qf = q.reshape(B, W, Hkv, G, D).transpose(0, 2, 1, 3, 4)
     qf = qf.reshape(B, Hkv, W * G, D)
     qf = jnp.pad(qf, ((0, 0), (0, 0), (0, rows - W * G), (0, 0)))
 
-    # the page frame comes from the scalar-prefetched table — the DMA
-    # streams exactly the pages this row owns — and, for a stacked
-    # pool, the plane from the prefetched layer
-    scalars = (lengths.astype(jnp.int32), tables.astype(jnp.int32))
+    scalars = (
+        lengths.astype(jnp.int32), pages, next_row, tables.astype(jnp.int32)
+    )
     if layer is not None:
         scalars += (jnp.asarray(layer, jnp.int32).reshape(1),)
 
-    def kv_spec(width):
-        if layer is None:
-            return pl.BlockSpec(
-                (1, ps, width), lambda b, i, lens, tabs: (tabs[b, i], 0, 0)
-            )
-        return pl.BlockSpec(
-            (None, 1, ps, width),
-            lambda b, i, lens, tabs, lay: (lay[0], tabs[b, i], 0, 0),
-        )
+    # a grid step serves a group of rows (a row alone costs a step's
+    # fixed price, which a slot that is not decoding would pay too)
+    group = max(r for r in range(1, 9) if B % r == 0)
 
     def row_spec(width):
         return pl.BlockSpec(
-            (1, Hkv, rows, width), lambda b, i, *_: (b, 0, 0, 0)
+            (group, Hkv, rows, width), lambda b, *_: (b, 0, 0, 0)
         )
 
-    pools = (k_pages,) if v_pages is None else (k_pages, v_pages)
+    # the pools stay where they lie: the operand is the leaf itself in
+    # whatever memory it has, and the kernel copies the frames it needs
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(B, n),
-        in_specs=[row_spec(D)] + [kv_spec(p.shape[-1]) for p in pools],
+        grid=(B // group,),
+        in_specs=[row_spec(D)] + [
+            pl.BlockSpec(memory_space=pl.ANY) for _ in pools
+        ],
         out_specs=row_spec(dv),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, rows, dv), jnp.float32),   # acc
-            pltpu.VMEM((Hkv, rows, 128), jnp.float32),  # running max
-            pltpu.VMEM((Hkv, rows, 128), jnp.float32),  # running sum
+            pltpu.VMEM((2, k * ps, p.shape[-1]), p.dtype) for p in pools
+        ] + [
+            pltpu.SemaphoreType.DMA((len(pools), 2)),
+            pltpu.SMEM((1,), jnp.int32),                # the buffer in turn
+            pltpu.VMEM((Hkv * rows, F), q.dtype),       # queries, all heads
+            pltpu.VMEM((Hkv * rows, Hkv * dv), jnp.float32),  # acc
+            pltpu.VMEM((Hkv * rows, 128), jnp.float32),  # running max
+            pltpu.VMEM((Hkv * rows, 128), jnp.float32),  # running sum
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _kernel_body, sm_scale=scale, page_size=ps, hkv=Hkv, g=G,
-            w=W, d=D, dv=dv, window=window, v_in_k=v_pages is None,
+            _kernel_body, sm_scale=scale, page_size=ps, k=k, hkv=Hkv, g=G,
+            w=W, d=D, dv=dv, window=window, n_pools=len(pools),
+            stacked=layer is not None,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rows, dv), q.dtype),
-        # rows are independent; the page dimension is sequential
-        # ("arbitrary"): the online-softmax scratch must persist across
-        # page steps, like flash's k dimension
+        # sequential: a row's last step starts the next row's first
+        # fetch, and the buffer in turn passes from row to row
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("arbitrary",)
         ),
         interpret=_interpret(),
         name="paged_attention",

@@ -83,9 +83,11 @@ from pytorch_distributed_tpu.serve.disagg import (
 )
 from pytorch_distributed_tpu.ops.paged_attention import (
     PagedView,
+    block_pages,
     paged_view,
     refuse_kernel_for,
     resolve_paged_attention_impl,
+    row_walk,
 )
 from pytorch_distributed_tpu.ops.moe import collect_route_stats
 from pytorch_distributed_tpu.runtime import faults
@@ -98,6 +100,7 @@ from pytorch_distributed_tpu.serve.kv_slots import (
     gather_pages,
     scatter_kv,
     splice_frames,
+    token_nbytes,
 )
 from pytorch_distributed_tpu.serve.sampling import (
     TOP_K_OFF,
@@ -427,10 +430,13 @@ class ServeEngine:
             # fail at construction, not at the first decode compile
             refuse_kernel_for(quantized=getattr(
                 getattr(model, "config", None), "kv_cache_quantize", None
-            ) is not None)
+            ) is not None, page_size=self.pool.page_size)
         # bytes of ONE page frame across every KV-payload leaf (layer
         # stacking included) — the unit of the analytic HBM accounting
         self._frame_bytes_target = frame_nbytes(self.pool.cache)
+        # and of one token in one layer's widest leaf: the kernel's
+        # block size follows it (the tick spans' ``fetched_pages``)
+        self._token_bytes = token_nbytes(self.pool.cache)
         self._frame_bytes_draft = (
             frame_nbytes(self.draft_pool.cache)
             if self.draft_pool is not None else 0
@@ -1519,14 +1525,26 @@ class ServeEngine:
             span.set(**_route_args(tok, 1))
         self._route_pending.clear()
 
-    def _live_pages(self, decoding) -> int:
-        """Pages the tick's kernel computes on: for each active row,
-        those its length and the tick's write span reach (the kernel
-        skips a row's later grid steps; the grid still walks them)."""
+    def _tick_pages(self, decoding, n_pages) -> dict:
+        """A tick span's page counts. ``live_pages``: for each decoding
+        row, the pages its length and the tick's write span reach.
+        ``fetched_pages``: the pages the tick's attention goes over for
+        them — the kernel's blocks of the live pages, whole (it copies
+        a block's live pages only; its products span the block), by the
+        kernel's own arithmetic; any other impl gathers every slot's
+        bucket."""
         W = 1 if self.spec is None else self.spec.num_draft_tokens + 1
         ps = self.pool.page_size
-        lengths = self.pool.lengths
-        return sum(-(-(int(lengths[slot]) + W) // ps) for slot, _ in decoding)
+        lengths = np.asarray(
+            [self.pool.lengths[slot] for slot, _ in decoding], np.int64
+        )
+        k = block_pages(ps, self._token_bytes, n_pages)
+        pages, blocks = row_walk(lengths, W, ps, n_pages, k)
+        fetched = (
+            int(blocks.sum()) * k if self._resolved_impl == "kernel"
+            else self.config.num_slots * n_pages
+        )
+        return {"live_pages": int(pages.sum()), "fetched_pages": fetched}
 
     def _run_decode(self) -> int:
         """One decode tick over the decoding rows; returns how many
@@ -1554,7 +1572,7 @@ class ServeEngine:
             tracing._NULL_SPAN if tracing._tracer is None
             else tracing.span(
                 "serve.decode_tick", active=len(decoding), n_pages=n_pages,
-                live_pages=self._live_pages(decoding),
+                **self._tick_pages(decoding, n_pages),
             )
         )
         with span:
@@ -1639,7 +1657,7 @@ class ServeEngine:
             else tracing.span(
                 "serve.spec_tick", active=len(decoding),
                 k=self.spec.num_draft_tokens, n_pages=n_pages,
-                live_pages=self._live_pages(decoding),
+                **self._tick_pages(decoding, n_pages),
             )
         )
         with span:

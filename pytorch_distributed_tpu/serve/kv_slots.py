@@ -304,6 +304,16 @@ def frame_nbytes(cache) -> int:
     return total
 
 
+def token_nbytes(cache) -> int:
+    """Bytes of ONE token in the widest KV-payload leaf of one layer —
+    what the paged-attention kernel sizes its blocks by
+    (``ops.paged_attention.block_pages``)."""
+    return max(
+        leaf.shape[-1] * leaf.dtype.itemsize
+        for _, _, leaf in _frame_leaves(cache)
+    )
+
+
 def frame_f32_nbytes(cache) -> int:
     """Bytes ONE page frame would cost with an f32 KV cache: payload
     elements at 4 bytes, no scale sidecars (an f32 cache has none).

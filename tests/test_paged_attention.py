@@ -27,6 +27,7 @@ Contracts under test, on top of test_serve_paged.py's parity suite:
 """
 
 import logging
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -341,6 +342,126 @@ def test_int8_scale_pools(impl, L):
     ), np.float32)
     tol = 0.0 if impl == "gather" else 3e-6
     assert np.max(np.abs(out - ref)) <= tol
+
+
+# -- the kernel's walk: a row's live pages, a block of them a step ---------
+
+_PAGED = sys.modules["pytorch_distributed_tpu.ops.paged_attention"]
+
+
+@pytest.fixture
+def blocks_of_two(monkeypatch):
+    """Blocks of 2 pages of 8: rows of a few dozen tokens then walk
+    several blocks, which the shipped 512-token block would swallow."""
+    monkeypatch.setattr(_PAGED, "_BLOCK_MAX_TOKENS", 16)
+
+
+# lengths against blocks of 16 tokens: an empty row, one inside its
+# first block, one whose queries end exactly on a block's edge (W 1 and
+# W 5), one a token past the edge, and one 20x the others
+RAGGED = (0, 5, 15, 11, 16, 167)
+
+
+def _ragged_case(rng, *, W, n=24, lengths=RAGGED, **kw):
+    """Rows of the given lengths over a table wider than any of them;
+    entries past a row's last page hold the null page."""
+    q, kp, vp, tables, _ = _pool_case(rng, B=len(lengths), W=W, n=n, **kw)
+    ps = kp.shape[1]
+    own = np.asarray([-(-(x + W) // ps) for x in lengths])
+    tables = jnp.where(np.arange(n)[None, :] < own[:, None], tables, 0)
+    return q, kp, vp, tables, jnp.asarray(lengths, jnp.int32), own
+
+
+@pytest.mark.parametrize("window", [None, 12])
+@pytest.mark.parametrize("W", [1, 5])
+@pytest.mark.parametrize("L", STACKS)
+def test_kernel_walks_each_rows_own_blocks(blocks_of_two, L, W, window):
+    """Ragged rows, each walked for as many blocks as it has: the
+    kernel against the exact ``gather`` impl."""
+    rng = np.random.default_rng(20)
+    q, kp, vp, tables, lengths, _ = _ragged_case(rng, W=W)
+    assert _PAGED.block_pages(8, kp.shape[2] * kp.shape[3] * 4, 24) == 2
+    kw = dict(page_tables=tables, lengths=lengths, window=window)
+    ref = np.asarray(_paged(q, kp, vp, L, impl="gather", **kw))
+    out = np.asarray(_paged(q, kp, vp, L, impl="kernel", **kw))
+    assert np.max(np.abs(out - ref)) <= 3e-6
+
+
+@pytest.mark.parametrize("W", [1, 5])
+@pytest.mark.parametrize("L", STACKS)
+def test_kernel_never_uses_a_dead_page(blocks_of_two, L, W):
+    """The null page, every page past a row's length and every page of
+    a row that is not decoding hold NaN: the outputs are finite and
+    the clean pool's, and a row that is not decoding reads zeros."""
+    rng = np.random.default_rng(21)
+    q, kp, vp, tables, lengths, own = _ragged_case(rng, W=W)
+    keep = np.asarray([True, True, False, True, True, True])
+    n = tables.shape[1]
+    # each row's pages are its own span of the pool (``_pool_case``)
+    live = np.zeros(kp.shape[0], bool)
+    for b in np.flatnonzero(keep):
+        live[1 + b * n:1 + b * n + own[b]] = True
+    dead = jnp.asarray(~live)[:, None, None, None]
+    kw = dict(page_tables=tables, lengths=lengths)
+    ref = np.asarray(_paged(q, kp, vp, L, impl="gather", **kw))
+    out = np.asarray(_paged(
+        q, jnp.where(dead, jnp.nan, kp), jnp.where(dead, jnp.nan, vp), L,
+        impl="kernel", keep=jnp.asarray(keep), **kw,
+    ))
+    assert np.isfinite(out).all()
+    assert np.max(np.abs(out[keep] - ref[keep])) <= 3e-6
+    assert not out[~keep].any()
+
+
+@pytest.mark.parametrize("W", [1, 5])
+@pytest.mark.parametrize("L", STACKS)
+def test_kernel_blocks_on_latent_pages(blocks_of_two, L, W):
+    """The latent case over several blocks: 8 query heads against ONE
+    640-lane frame a token, the values its first 512 lanes."""
+    rng = np.random.default_rng(22)
+    lengths, n, ps, H, F, r = (0, 15, 16, 70), 10, 8, 8, 640, 512
+    B = len(lengths)
+    q = jnp.asarray(rng.standard_normal((B, W, H, F)), jnp.float32)
+    pool = jnp.asarray(rng.standard_normal((B * n + 1, ps, F)), jnp.float32)
+    leaf, layer = _leaf(pool, L, fold=1)
+    tables = jnp.asarray(np.arange(1, B * n + 1).reshape(B, n), jnp.int32)
+    kw = dict(
+        page_tables=tables, lengths=jnp.asarray(lengths, jnp.int32),
+        layer=layer, scale=F ** -0.5,
+    )
+    ref, out = (
+        np.asarray(paged_attention(
+            q, leaf, _PAGED.PagedPrefix(leaf, r), impl=impl, **kw
+        ))
+        for impl in ("gather", "kernel")
+    )
+    assert out.shape == (B, W, H, r)
+    assert np.max(np.abs(out - ref)) <= 3e-6
+
+
+@pytest.mark.parametrize("page_size,token_bytes,n_pages,want", [
+    (16, 2048, 256, 32),    # Mistral and GPT-2: 512 tokens a block
+    (16, 1280, 512, 32),    # latent frames: the token cap, not the bytes
+    (16, 2048, 8, 8),       # never wider than the table
+    (16, 2048, 24, 16),     # a power of two
+    (16, 8192, 256, 8),     # f32 frames four times as wide: the budget
+    (1024, 2048, 4, 1),     # a page above the cap is still one page
+])
+def test_block_pages_follow_the_frame_and_the_table(
+    page_size, token_bytes, n_pages, want
+):
+    assert _PAGED.block_pages(page_size, token_bytes, n_pages) == want
+
+
+@pytest.mark.parametrize("w", [1, 5])
+def test_row_walk_counts_pages_and_blocks(w):
+    """By hand at pages of 16 in blocks of 4: a row of L tokens and w
+    queries reaches ceil((L + w) / 16) pages, never past the table."""
+    lengths = np.asarray([0, 15, 16, 63, 64, 5000])
+    pages, blocks = _PAGED.row_walk(lengths, w, 16, 64, 4)
+    by_hand = {1: ([1, 1, 2, 4, 5, 64], [1, 1, 1, 1, 2, 16]),
+               5: ([1, 2, 2, 5, 5, 64], [1, 1, 1, 2, 2, 16])}[w]
+    assert pages.tolist() == by_hand[0] and blocks.tolist() == by_hand[1]
 
 
 # -- engine wiring ----------------------------------------------------------

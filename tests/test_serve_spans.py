@@ -9,6 +9,7 @@ is the shared null span. The readers of the tree are checked under
 ``tests/benchmark/``.
 """
 
+import sys
 import threading
 
 import jax
@@ -26,6 +27,8 @@ from pytorch_distributed_tpu.serve import (
 )
 
 pytestmark = [pytest.mark.serve, pytest.mark.obs]
+
+_PAGED = sys.modules["pytorch_distributed_tpu.ops.paged_attention"]
 
 PAGE = 4
 CHUNK = 8
@@ -195,8 +198,45 @@ def test_tick_carries_its_bucket_and_the_pages_its_rows_reach(drive):
         )
         assert a["n_pages"] * PAGE >= max(lengths) + drive.W
         assert a["live_pages"] <= a["active"] * a["n_pages"]
+        # off a TPU the attention is the gather impl: every slot's
+        # bucket is gathered, whatever its row reaches
+        assert a["fetched_pages"] == 2 * a["n_pages"]
         if drive.mode == "spec":
             assert a["k"] == SPEC_K
+
+
+def test_fetched_pages_is_the_kernels_own_block_arithmetic(
+    monkeypatch, drive
+):
+    """With the kernel serving the ticks (interpreted here, in blocks
+    of 2 pages of 4), ``fetched_pages`` is what the kernel's wrapper
+    derives for the tick's lengths — the blocks of each decoding row,
+    whole — and the streams are the gather engine's."""
+    mode = drive.mode
+    monkeypatch.setattr(_PAGED, "_IMPL", "kernel")
+    monkeypatch.setattr(_PAGED, "_BLOCK_MAX_TOKENS", 2 * PAGE)
+    served = Drive(mode)
+    tick = "serve.decode_tick" if mode == "plain" else "serve.spec_tick"
+    ticks = served.named(tick)
+    assert len(ticks) == len(served.lengths_at_tick) > 3
+    whole_blocks = 0
+    for e, lengths in zip(ticks, served.lengths_at_tick):
+        a = e["args"]
+        k = _PAGED.block_pages(PAGE, 32 * 4, a["n_pages"])
+        assert k == min(2, a["n_pages"])
+        pages, blocks = _PAGED.row_walk(
+            np.asarray(lengths), served.W, PAGE, a["n_pages"], k
+        )
+        assert a["live_pages"] == pages.sum()
+        assert a["fetched_pages"] == blocks.sum() * k
+        assert a["live_pages"] <= a["fetched_pages"] <= (
+            a["active"] * a["n_pages"]
+        )
+        whole_blocks += a["fetched_pages"] > a["live_pages"]
+    assert whole_blocks  # some row ended inside a block
+    assert [h.tokens for h in served.handles] == [
+        h.tokens for h in drive.handles
+    ]
 
 
 def test_plain_tick_pages_by_hand():

@@ -80,6 +80,23 @@ def test_paged_kernel_lowers(Hq, Hkv, D, W, window, dtype, L):
     )
 
 
+@pytest.mark.parametrize("page_size", [1, 4, 12])
+def test_paged_kernel_refuses_a_page_under_a_sublane_tile(page_size):
+    """A block's frames land on whole sublane tiles of its VMEM buffer:
+    a page that is no multiple of 8 positions is refused by name where
+    the kernel is compiled (interpreted, any page size runs)."""
+    B, n, Hq, Hkv, D = 4, 8, 4, 2, 64
+    pool = _sds((B * n + 1, page_size, Hkv * D), "bfloat16")
+    with pytest.raises(ValueError, match="multiple of 8"):
+        jax.eval_shape(
+            lambda q, k, v, t, l: paged_attention(
+                q, k, v, page_tables=t, lengths=l, impl="kernel",
+            ),
+            _sds((B, 1, Hq, D), "bfloat16"), pool, pool,
+            _sds((B, n), "int32"), _sds((B,), "int32"),
+        )
+
+
 @pytest.mark.parametrize("L", [None, 4], ids=["leaf", "stacked"])
 @pytest.mark.parametrize("W", [1, 2])
 def test_paged_kernel_lowers_on_latent_pages(W, L):
