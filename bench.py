@@ -1151,146 +1151,6 @@ def bench_serving_spec(on_tpu: bool) -> None:
     )
 
 
-def bench_serving_paged_attn(on_tpu: bool) -> None:
-    """Paged-attention decode vs the dense-gather tick: tokens/sec and
-    analytic HBM bytes/token, SAME greedy workload, parity asserted
-    in-phase — the round-12 claim at the regime it exists for.
-
-    The workload is the long-``max_len``/short-live-length mix: the
-    pool is sized for a 512-token worst case while live requests decode
-    at < 32 tokens, so the dense tick gathers a 16-page ``[S, max_len]``
-    view every token while the paged tick streams the 1-page live
-    bucket. Bytes/token comes off the ``serve.decode_hbm_bytes_per_
-    token`` armed-only tracing counter (the same number the snapshot
-    gauges and obs_report carry), under the impl's analytic model
-    (DESIGN.md §17): ANALYTIC, not a hardware counter — on this CPU the
-    default impl still materializes the bucket-wide slab, and the
-    counter says so honestly (gather term included). Output parity and
-    the per-bucket compile contract are enforced in-phase, so neither
-    ratio can come from wrong tokens or hidden recompiles.
-    """
-    from pytorch_distributed_tpu.models.gpt2 import GPT2Config, GPT2LMHead
-    from pytorch_distributed_tpu.runtime import tracing
-    from pytorch_distributed_tpu.serve import (
-        EngineConfig,
-        Request,
-        ServeEngine,
-        warm_up,
-    )
-
-    if on_tpu:
-        cfg = GPT2Config(
-            vocab_size=GPT2Config.small().vocab_size, n_positions=2048,
-            hidden_size=768, num_layers=12, num_heads=12,
-            dropout_rate=0.0,
-        )
-        slots, max_len, ps, chunk, n_req = 8, 2048, 32, 32, 24
-        p_rng, n_rng = (16, 48), (32, 64)
-    else:
-        cfg = GPT2Config(
-            vocab_size=128, n_positions=512, hidden_size=32,
-            num_layers=2, num_heads=2, dropout_rate=0.0,
-        )
-        slots, max_len, ps, chunk, n_req = 8, 512, 32, 8, 16
-        p_rng, n_rng = (4, 10), (8, 16)
-
-    model = GPT2LMHead(cfg)
-    rng = np.random.default_rng(0)
-    params = model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
-    )["params"]
-    protos = [
-        (
-            rng.integers(1, cfg.vocab_size, size=int(
-                rng.integers(p_rng[0], p_rng[1] + 1)
-            )).astype(np.int32),
-            int(rng.integers(n_rng[0], n_rng[1] + 1)),
-        )
-        for _ in range(n_req)
-    ]
-
-    def run(mode):
-        engine = ServeEngine(model, params, EngineConfig(
-            num_slots=slots, max_len=max_len, prefill_chunk=chunk,
-            page_size=ps, telemetry_every=8, decode_mode=mode,
-        ))
-        with tracing.enabled() as t:
-            warm_up(engine, protos[0][0][:2])
-            t0 = time.perf_counter()
-            handles = [
-                engine.submit(Request(p, max_new_tokens=new))
-                for p, new in protos
-            ]
-            engine.run_until_drained()
-            dt = time.perf_counter() - t0
-        s = engine.telemetry.summary()
-        if s.get("completed") != n_req:
-            raise RuntimeError(
-                f"paged-attn workload incomplete ({mode}): {s}"
-            )
-        _check_bucketed_compiles(engine)
-        bpt = [
-            e["args"]["value"] for e in t._events
-            if e.get("ph") == "C"
-            and e["name"] == "serve.decode_hbm_bytes_per_token"
-        ]
-        if not bpt or bpt[-1] <= 0:
-            raise RuntimeError(
-                f"no serve.decode_hbm_bytes_per_token counter recorded "
-                f"({mode}) — the armed-only accounting went dark"
-            )
-        toks = s["completed_tokens"]
-        return engine, toks / dt, bpt[-1], [h.tokens for h in handles]
-
-    dense_e, dense_tok_s, dense_bpt, dense_toks = run("dense")
-    paged_e, paged_tok_s, paged_bpt, paged_toks = run("paged")
-    if paged_toks != dense_toks:
-        bad = sum(a != b for a, b in zip(paged_toks, dense_toks))
-        raise RuntimeError(
-            f"paged-attention output diverged from the dense tick on "
-            f"{bad}/{n_req} requests"
-        )
-    ratio = dense_bpt / max(paged_bpt, 1e-9)
-    _emit(
-        {
-            "metric": "serving_paged_attn_tokens_per_sec",
-            "value": round(paged_tok_s, 1),
-            "unit": f"decode tokens/sec, paged-attention tick "
-            f"(impl={paged_e._resolved_impl}, buckets="
-            f"{sorted(paged_e.decode_buckets)} of {max_len // ps} "
-            f"pages), slots={slots} max_len={max_len} page={ps} "
-            f"n={n_req}; dense-gather tick {dense_tok_s:.1f} tok/s on "
-            f"the same workload",
-            "vs_baseline": round(paged_tok_s / dense_tok_s, 3),
-        }
-    )
-    _emit(
-        {
-            "metric": "serving_paged_attn_bytes_per_token_ratio",
-            "value": round(ratio, 3),
-            "unit": f"analytic decode HBM bytes/token, dense-gather "
-            f"({dense_bpt:,.0f}) / paged ({paged_bpt:,.0f}) at the "
-            f"long-context mix (max_len={max_len}, live < "
-            f"{n_rng[1] + p_rng[1]}); recorded off the armed-only "
-            f"serve.decode_* counters under DESIGN.md §17's per-impl "
-            f"model — analytic, not a hardware counter",
-            "vs_baseline": None,
-            "paged_bytes_per_token": round(paged_bpt, 1),
-            "dense_bytes_per_token": round(dense_bpt, 1),
-            "paged_impl": paged_e._resolved_impl,
-            "decode_buckets": sorted(paged_e.decode_buckets),
-        }
-    )
-    print(
-        f"# serving_paged_attn: paged={paged_tok_s:.0f} tok/s dense="
-        f"{dense_tok_s:.0f} tok/s speed x"
-        f"{paged_tok_s / dense_tok_s:.2f}, bytes/token "
-        f"{dense_bpt:,.0f} -> {paged_bpt:,.0f} (x{ratio:.1f} less, "
-        f"impl={paged_e._resolved_impl})",
-        file=sys.stderr,
-    )
-
-
 def bench_observability() -> None:
     """Traced-vs-untraced hot-loop overhead: the tracer's near-zero-cost
     claim as a number, pinned by test_bench_contract (< 2% budget).
@@ -1786,9 +1646,9 @@ def bench_pipeline() -> None:
     retry (contended box).
 
     Part B measures the bubble the planner prices: a delay-shaped run
-    (``delay_s`` sleeps before each compute op, OUTSIDE the math — the
-    r18 prefill_delay_s idiom, so sleeps overlap across stage
-    processes and the 1-core box behaves like a real S-deep pipeline)
+    (``delay_s`` sleeps before each compute op, OUTSIDE the math, so
+    sleeps overlap across stage processes and the 1-core box behaves
+    like a real S-deep pipeline)
     exports per-rank chrome traces; the merged steady-state window
     (last 2M compute spans per rank — step 0's compiles and the
     inter-step optimizer boundary are excluded by construction) must
@@ -2363,497 +2223,6 @@ def bench_multihost() -> None:
             f"hierarchical ({wall_hier:.2f}s) did not beat flat-over-TCP "
             f"({wall_flat:.2f}s) by >= 1.3x: {ratio:.2f}x"
         )
-
-
-# -- disaggregated serving fleet (r18) --------------------------------------
-# Synthetic per-token compute (EngineConfig prefill/decode_delay_s — the
-# r15 shard_delay_s idiom for serving): sleeps overlap across processes,
-# so the 1-core box behaves like a 4-way fleet; the python between
-# sleeps serializes and dilutes ratios, never inflates them. Prefill is
-# priced cheaper per token than decode (compute-dense chunk vs
-# memory-bound tick) — the asymmetry disaggregation exists to exploit.
-_DISAGG_PREFILL_DELAY_S = 0.008
-_DISAGG_DECODE_DELAY_S = 0.03
-_DISAGG_TTFT_BUDGET_MS = 2500.0
-_DISAGG_SEED = 12
-_DISAGG_LONG, _DISAGG_SHORT = 64, 4
-_DISAGG_N_LONG, _DISAGG_N = 4, 32
-_DISAGG_PROMPT = 24  # 3 full pages @ ps=8: every frame ships 3 pages
-
-
-def _disagg_workload():
-    """The pinned heavy-tailed storm: 32 unique 24-token prompts, 4
-    long decodes (64 tokens) among 28 short (4). Seed 12 is a
-    representative draw where the static round-robin split exhibits
-    the tail clustering heavy-tailed arrivals produce — decode-token
-    bins [32, 212, 92, 32] across 4 independent engines vs the
-    length-aware router placement's [184, 184] over 2 decode ranks.
-    The fleet's win is balance + tier overlap, NOT the draw: LPT bins
-    are ~D/2 for every seed; only the BASELINE's pain varies."""
-    rng = np.random.default_rng(_DISAGG_SEED)
-    kinds = rng.permutation(
-        [_DISAGG_LONG] * _DISAGG_N_LONG
-        + [_DISAGG_SHORT] * (_DISAGG_N - _DISAGG_N_LONG)
-    )
-    return [
-        (rng.integers(1, 211, size=_DISAGG_PROMPT).tolist(), int(n))
-        for n in kinds
-    ]
-
-
-def _disagg_lpt_assignment(spec):
-    """The router's placement made static for the blocking-transport
-    world: longest-processing-time over the two decode ranks {2, 3},
-    ties to the lower rank. Every rank evaluates this on the identical
-    pinned workload — lockstep by construction (the train/balance
-    membership-view idiom), so no control messages are needed."""
-    bins = {2: 0, 3: 0}
-    assign = {}
-    for i in sorted(range(len(spec)), key=lambda j: (-spec[j][1], j)):
-        dst = min(bins, key=lambda d: (bins[d], d))
-        assign[i] = dst
-        bins[dst] += spec[i][1]
-    return assign
-
-
-def _disagg_fleet_worker(rank: int, world: int, name: str, q) -> None:
-    """4-rank disaggregated-fleet makespan worker (bench ``disagg``).
-
-    One spawn, four runs over the IDENTICAL pinned workload (compile
-    paid once per proc, delays identical wherever work runs): a
-    no-delay solo reference on rank 0 (the bit-parity anchor), the
-    indep-4 and indep-2 static round-robin baselines, then the
-    2-prefill + 2-decode fleet — rank r<2 prefills and ships frames
-    over the ring's real P2P mailboxes to its paired decode rank r+2,
-    placement by the LPT assignment. Walls are barrier-to-barrier, so
-    every rank reports the MAKESPAN. Decode ranks pin the exact int8
-    payload accounting frame by frame."""
-    try:
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-        import jax.numpy as jnp
-
-        from pytorch_distributed_tpu.models.gpt2 import (
-            GPT2Config,
-            GPT2LMHead,
-        )
-        from pytorch_distributed_tpu.runtime.hostring import HostRingGroup
-        from pytorch_distributed_tpu.serve import (
-            EngineConfig,
-            Request,
-            RequestStatus,
-            ServeEngine,
-            frame_f32_nbytes,
-            frame_nbytes,
-            recv_frame,
-            roundtrip_frame,
-            send_frame,
-        )
-
-        cfg = GPT2Config(
-            vocab_size=211, n_positions=96, hidden_size=32, num_layers=2,
-            num_heads=2, dropout_rate=0.0, kv_cache_quantize="int8",
-        )
-        model = GPT2LMHead(cfg)
-        params = model.init(
-            jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
-        )["params"]
-        spec = _disagg_workload()
-        reqs = [
-            Request(
-                np.asarray(p, np.int32), max_new_tokens=n,
-                request_id=f"dg-{i}",
-                temperature=(0.8 if i % 2 else 0.0),
-                top_k=(20 if i % 2 else None), seed=300 + i,
-            )
-            for i, (p, n) in enumerate(spec)
-        ]
-        ecfg = dict(num_slots=4, max_len=96, prefill_chunk=8, page_size=8)
-        delay = dict(
-            prefill_delay_s=_DISAGG_PREFILL_DELAY_S,
-            decode_delay_s=_DISAGG_DECODE_DELAY_S,
-        )
-
-        warm_ids = np.asarray(spec[0][0], np.int32)
-
-        def warm_solo(eng):
-            h = eng.submit(Request(
-                warm_ids, max_new_tokens=2, request_id="warm",
-            ))
-            eng.run_until_drained()
-            if h.status is not RequestStatus.COMPLETED:
-                raise RuntimeError(f"warm-up failed: {h.status}")
-            eng.precompile_decode_buckets()
-
-        def warm_frame(eng):
-            """One warm prefill to a packed frame (role='prefill')."""
-            h = eng.submit(Request(
-                warm_ids, max_new_tokens=2, request_id="warm",
-            ))
-            while eng.has_work():
-                eng.step()
-            if h.status is not RequestStatus.MIGRATED or not eng.outbox:
-                raise RuntimeError(f"warm-up prefill: {h.status}")
-            return eng.outbox.popleft()
-
-        def serve(eng, mine):
-            hs = [eng.submit(r) for r in mine]
-            eng.run_until_drained()
-            out = {}
-            for r, h in zip(mine, hs):
-                if h.status is not RequestStatus.COMPLETED:
-                    raise RuntimeError(
-                        f"{r.request_id}: {h.status} {h.error!r}"
-                    )
-                out[r.request_id] = list(h.tokens)
-            return out
-
-        res = {}
-        with HostRingGroup(name, rank, world, timeout_s=300) as ring:
-            # build + compile EVERY engine before any timed barrier —
-            # walls measure steady-state serving, never XLA compiles
-            ieng = ServeEngine(model, params, EngineConfig(
-                **ecfg, **delay,
-            ))
-            warm_solo(ieng)
-            if rank < 2:
-                feng = ServeEngine(model, params, EngineConfig(
-                    role="prefill", engine_id=f"p{rank}", **ecfg, **delay,
-                ))
-                warm_frame(feng)
-            else:
-                feng = ServeEngine(model, params, EngineConfig(
-                    role="decode", engine_id=f"d{rank}", **ecfg, **delay,
-                ))
-                helper = ServeEngine(model, params, EngineConfig(
-                    role="prefill", **ecfg,
-                ))
-                wf, _ = roundtrip_frame(
-                    warm_frame(helper), feng.migration_signature
-                )
-                h = feng.inject_migration(wf)
-                while feng.has_work():
-                    feng.step()
-                if h.status is not RequestStatus.COMPLETED:
-                    raise RuntimeError(f"warm-up decode: {h.status}")
-                feng.precompile_decode_buckets()
-            if rank == 0:  # the delay-free bit-parity anchor
-                ref = ServeEngine(
-                    model, params, EngineConfig(**ecfg),
-                )
-                res["solo_streams"] = serve(ref, reqs)
-            for phase, share in (
-                ("indep4", reqs[rank::4]),
-                ("indep2", reqs[rank::2] if rank < 2 else []),
-            ):
-                ring.barrier()
-                t0 = time.perf_counter()
-                res[f"{phase}_streams"] = serve(ieng, share)
-                ring.barrier()
-                res[f"{phase}_wall"] = time.perf_counter() - t0
-            assign = _disagg_lpt_assignment(spec)
-            ring.barrier()
-            t0 = time.perf_counter()
-            if rank < 2:
-                dst = rank + 2
-                mine = [r for i, r in enumerate(reqs) if assign[i] == dst]
-                hs = [feng.submit(r) for r in mine]
-                sent = 0
-                while feng.has_work() or feng.outbox:
-                    feng.step()
-                    while feng.outbox:
-                        send_frame(ring, feng.outbox.popleft(), dst)
-                        sent += 1
-                if sent != len(mine) or any(
-                    h.status is not RequestStatus.MIGRATED for h in hs
-                ):
-                    raise RuntimeError(
-                        f"prefill rank {rank}: sent {sent}/{len(mine)}, "
-                        f"statuses {[h.status for h in hs]}"
-                    )
-                res["fleet_streams"] = {}
-            else:
-                mine = [i for i in range(len(reqs)) if assign[i] == rank]
-                per_page = frame_nbytes(feng.pool.cache)
-                migrated_base = feng.migrated_in  # warm frame excluded
-                payload_bytes = pages = 0
-                handles = {}
-                for _ in mine:
-                    fr = recv_frame(
-                        ring, rank - 2, feng.migration_signature
-                    )
-                    if fr.payload.nbytes != fr.n_pages * per_page:
-                        raise RuntimeError(
-                            f"{fr.request_id}: payload {fr.payload.nbytes}"
-                            f" != {fr.n_pages} pages x {per_page}"
-                        )
-                    payload_bytes += fr.payload.nbytes
-                    pages += fr.n_pages
-                    handles[fr.request_id] = feng.inject_migration(fr)
-                    # overlap: a couple of ticks per arrival keeps the
-                    # decode batch advancing while the next frame is
-                    # still being prefilled upstream
-                    for _ in range(2):
-                        feng.step()
-                feng.run_until_drained()
-                out = {}
-                for rid, h in handles.items():
-                    if h.status is not RequestStatus.COMPLETED:
-                        raise RuntimeError(
-                            f"{rid}: {h.status} {h.error!r}"
-                        )
-                    out[rid] = list(h.tokens)
-                res["fleet_streams"] = out
-                res["migration_payload_bytes"] = int(payload_bytes)
-                res["migration_pages"] = int(pages)
-                res["page_nbytes"] = int(per_page)
-                res["page_f32_nbytes"] = int(
-                    frame_f32_nbytes(feng.pool.cache)
-                )
-                res["migrated_in"] = int(feng.migrated_in - migrated_base)
-            ring.barrier()
-            res["fleet_wall"] = time.perf_counter() - t0
-        q.put((rank, res))
-    except Exception:  # pragma: no cover - surfaced by the parent
-        import traceback
-
-        q.put((rank, f"rank {rank}: {traceback.format_exc()}"))
-
-
-def bench_disagg() -> None:
-    """Disaggregated serving fleet vs independent engines (r18).
-
-    Two halves, every claim checked in-phase. (1) MULTI-PROCESS
-    makespan over the pinned heavy-tailed storm: 4 ranks run the
-    identical workload as 4 then 2 independent static-split engines,
-    then as a 2-prefill + 2-decode fleet shipping int8 KV frames over
-    the ring, with the router's length-aware placement. The fleet must
-    beat the BEST independent configuration >= 1.2x. Ceiling
-    arithmetic: the skewed indep-4 rank pays prefill 1.54s + decode
-    6.36s of priced compute vs the fleet decode rank's 5.52s + head,
-    ~1.37x before python overhead; an oracle-balanced static split
-    would TIE the fleet — the claim is against static splits of
-    heavy-tailed arrivals, which cannot know lengths up front. All
-    streams must be bit-identical to the delay-free solo reference —
-    a wrong-math speedup cannot pass. The decode ranks pin int8
-    payload bytes == pages x frame_nbytes EXACTLY, <= 0.55x f32.
-    (2) IN-PROCESS router storm (real Router, no delays): 48 requests
-    sharing a 64-token system prompt over a 2+2 fleet — pins the
-    prefix prefilled once per FLEET (store puts == 8 pages, the peer
-    prefill engine adopts), pooled p99 TTFT under budget, and the
-    ``serve.engine_loss`` drill (kill d1 mid-storm) replaying with
-    streams equal to the loss-free run. One documented timing-only
-    retry on the makespan ratio; parity/accounting never retried."""
-    from pytorch_distributed_tpu.models.gpt2 import GPT2Config, GPT2LMHead
-    from pytorch_distributed_tpu.runtime import faults
-    from pytorch_distributed_tpu.serve import (
-        EngineConfig,
-        InProcPrefixStore,
-        Request,
-        RequestStatus,
-        Router,
-        ServeEngine,
-    )
-
-    world = 4
-    spec = _disagg_workload()
-    total_tokens = sum(n for _, n in spec)
-
-    def merged(results, key):
-        out = {}
-        for _, r in results:
-            out.update(r[key])
-        return out
-
-    for attempt in (1, 2):  # timing-only retry; parity checked every run
-        results = _spawn_ring_workers(
-            world, _disagg_fleet_worker, timeout=420.0,
-        )
-        bad = [r for r in results if not isinstance(r[1], dict)]
-        if bad:
-            raise RuntimeError(f"disagg workers failed: {bad}")
-        results.sort(key=lambda r: r[0])
-        byrank = dict(results)
-        solo = byrank[0]["solo_streams"]
-        # bit-parity three ways BEFORE any timing claim
-        for phase in ("indep4", "indep2", "fleet"):
-            streams = merged(results, f"{phase}_streams")
-            if streams != solo:
-                raise RuntimeError(
-                    f"disagg {phase} streams diverged from the solo "
-                    f"reference ({len(streams)}/{len(solo)} present)"
-                )
-        # exact int8 migration accounting (per-frame pinned in-worker)
-        pages = sum(byrank[r]["migration_pages"] for r in (2, 3))
-        payload = sum(
-            byrank[r]["migration_payload_bytes"] for r in (2, 3)
-        )
-        per_page = byrank[2]["page_nbytes"]
-        per_page_f32 = byrank[2]["page_f32_nbytes"]
-        if payload != pages * per_page:
-            raise RuntimeError(
-                f"migration bytes {payload} != {pages} x {per_page}"
-            )
-        if sum(byrank[r]["migrated_in"] for r in (2, 3)) != len(spec):
-            raise RuntimeError("not every request migrated")
-        byte_ratio = per_page / per_page_f32
-        if byte_ratio > 0.55:
-            raise RuntimeError(
-                f"int8 frame {per_page}B > 0.55x f32 {per_page_f32}B"
-            )
-        indep4 = max(byrank[r]["indep4_wall"] for r in range(world))
-        indep2 = max(byrank[r]["indep2_wall"] for r in range(world))
-        fleet = max(byrank[r]["fleet_wall"] for r in range(world))
-        best_indep = min(indep4, indep2)
-        ratio = best_indep / fleet
-        if ratio >= 1.2 or attempt == 2:
-            break
-        print(
-            f"# disagg: attempt {attempt} ratio {ratio:.2f}x < 1.2x on "
-            f"a contended box — one timing-only retry",
-            file=sys.stderr,
-        )
-    if ratio < 1.2:
-        raise RuntimeError(
-            f"fleet ({fleet:.2f}s) did not beat the best independent "
-            f"split (indep4 {indep4:.2f}s / indep2 {indep2:.2f}s) by "
-            f">= 1.2x: {ratio:.2f}x"
-        )
-
-    # -- in-process router storm: prefix-once, p99 TTFT, loss drill --------
-    cfg = GPT2Config(
-        vocab_size=211, n_positions=96, hidden_size=32, num_layers=2,
-        num_heads=2, dropout_rate=0.0,
-    )
-    model = GPT2LMHead(cfg)
-    params = model.init(
-        jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
-    )["params"]
-    shared = np.arange(1, 65, dtype=np.int32)  # 8 full pages @ ps=8
-    rng = np.random.default_rng(3)
-    storm = [
-        Request(
-            # the unique tail stays SUB-page (7 < 8 tokens), so the
-            # only publishable full pages are the shared prefix's 8 —
-            # puts == 8 is then EXACTLY "prefilled once per fleet"
-            np.concatenate(
-                [shared, rng.integers(1, 211, size=7).astype(np.int32)]
-            ),
-            max_new_tokens=8, request_id=f"storm-{i}",
-            temperature=(0.8 if i % 2 else 0.0),
-            top_k=(20 if i % 2 else None), seed=700 + i,
-        )
-        for i in range(48)
-    ]
-    ecfg = dict(num_slots=4, max_len=96, prefill_chunk=8, page_size=8)
-
-    def run_storm(store):
-        router = Router(
-            prefill=[
-                ServeEngine(model, params, EngineConfig(
-                    role="prefill", engine_id=f"p{i}", **ecfg,
-                ), prefix_store=store)
-                for i in range(2)
-            ],
-            decode=[
-                ServeEngine(model, params, EngineConfig(
-                    role="decode", engine_id=f"d{i}", **ecfg,
-                ), prefix_store=store)
-                for i in range(2)
-            ],
-        )
-        router.warm_up(storm[0].prompt_ids)
-        t0 = time.perf_counter()
-        hs = [router.submit(r) for r in storm]
-        router.run_until_drained()
-        wall = time.perf_counter() - t0
-        out = {}
-        for r, h in zip(storm, hs):
-            if h.status is not RequestStatus.COMPLETED:
-                raise RuntimeError(
-                    f"storm {r.request_id}: {h.status} {h.error!r}"
-                )
-            out[r.request_id] = list(h.tokens)
-        return router, out, wall
-
-    store = InProcPrefixStore()
-    router, clean, storm_wall = run_storm(store)
-    puts = store.stats()["puts"]
-    if puts != 8:  # 64-token prompt / 8-token pages, once per FLEET
-        raise RuntimeError(
-            f"shared prefix published {puts} pages, want exactly 8 "
-            f"(once per fleet): {store.stats()}"
-        )
-    summ = router.summary()
-    p99 = summ.get("ttft_ms_p99")
-    if p99 is None or p99 > _DISAGG_TTFT_BUDGET_MS:
-        raise RuntimeError(
-            f"storm p99 TTFT {p99} ms over the "
-            f"{_DISAGG_TTFT_BUDGET_MS} ms budget"
-        )
-    # loss drill: kill d1 mid-storm; replay must land identical bits
-    with faults.injected("serve.engine_loss:mode=raise,match=d1,after=4"):
-        router2, lossy, _ = run_storm(InProcPrefixStore())
-    if router2.lost_engines != ["d1"] or router2.replays < 1:
-        raise RuntimeError(
-            f"loss drill: lost={router2.lost_engines} "
-            f"replays={router2.replays}"
-        )
-    if lossy != clean:
-        raise RuntimeError(
-            "loss-drill streams diverged from the loss-free storm"
-        )
-
-    _emit({
-        "metric": "disagg_fleet_tokens_per_sec",
-        "value": round(total_tokens / fleet, 2),
-        "unit": (
-            "tokens/s, 4-proc CPU ring, 2 prefill + 2 decode, int8 KV "
-            "frames over real P2P, LPT (router) placement, priced "
-            "per-token compute (prefill "
-            f"{_DISAGG_PREFILL_DELAY_S * 1e3:.0f} ms/tok, decode "
-            f"{_DISAGG_DECODE_DELAY_S * 1e3:.0f} ms/tok); vs_baseline "
-            "= ratio over the BEST static independent split (indep-4 "
-            "and indep-2 both measured, ceiling ~1.37x); all streams "
-            "bit-identical to the delay-free solo reference in-phase"
-        ),
-        "vs_baseline": round(ratio, 4),
-        "indep4_wall_s": round(indep4, 3),
-        "indep2_wall_s": round(indep2, 3),
-        "fleet_wall_s": round(fleet, 3),
-        "migration_payload_bytes": payload,
-        "migration_pages": pages,
-        "page_nbytes": per_page,
-        "page_f32_nbytes": per_page_f32,
-        "bytes_exact": True,
-        "int8_byte_ratio": round(byte_ratio, 4),
-    })
-    _emit({
-        "metric": "disagg_storm_ttft_ms_p99",
-        "value": round(p99, 2),
-        "unit": (
-            "ms, in-process 2+2 router storm, 48 requests sharing a "
-            "64-token system prompt (prefilled once per fleet: store "
-            "puts == 8 pages), pooled across engines; budget "
-            f"{_DISAGG_TTFT_BUDGET_MS} ms; engine-loss drill replays "
-            "bit-identically in-phase"
-        ),
-        "vs_baseline": None,
-        "storm_wall_s": round(storm_wall, 3),
-        "storm_tokens_per_sec": round(
-            sum(len(t) for t in clean.values()) / storm_wall, 2
-        ),
-        "prefix_store_puts": puts,
-        "prefix_store_hits": store.stats()["hits"],
-        "loss_drill_replays": router2.replays,
-    })
-    print(
-        f"# disagg: fleet {fleet:.2f}s vs indep4 {indep4:.2f}s / indep2 "
-        f"{indep2:.2f}s ({ratio:.2f}x), storm p99 {p99:.0f} ms, "
-        f"{payload} payload bytes over {pages} pages", file=sys.stderr,
-    )
 
 
 def bench_planning() -> None:
@@ -3631,11 +3000,6 @@ def main():
         # RELATIVE numbers on the same box too — the r11 serving claims
         run_if_budget("serving_paged", bench_serving_paged, False)
         run_if_budget("serving_spec", bench_serving_spec, False)
-        # paged-attention vs dense-gather is relative on the same box
-        # too, with parity enforced in-phase — the r12 serving claims
-        run_if_budget(
-            "serving_paged_attn", bench_serving_paged_attn, False
-        )
         # so is the tracing-overhead ratio: traced vs untraced on the
         # same loop, same box
         run_if_budget("observability", bench_observability)
@@ -3658,10 +3022,6 @@ def main():
         # hierarchical-vs-flat over a throttled TCP leg: relative ratio
         # plus EXACT slow-link byte accounting, bit-identity in-phase
         run_if_budget("multihost", bench_multihost)
-        # fleet-vs-independent is a relative ratio over the same priced
-        # compute, with solo bit-parity + exact int8 migration-byte
-        # accounting enforced in-phase (r18)
-        run_if_budget("disagg", bench_disagg)
     else:
         bench_resnet50(on_tpu)
         run_if_budget("input_pipeline", bench_input_pipeline, on_tpu)
@@ -3679,9 +3039,6 @@ def main():
         run_if_budget("serving", bench_serving, on_tpu)
         run_if_budget("serving_paged", bench_serving_paged, on_tpu)
         run_if_budget("serving_spec", bench_serving_spec, on_tpu)
-        run_if_budget(
-            "serving_paged_attn", bench_serving_paged_attn, on_tpu
-        )
         run_if_budget("observability", bench_observability)
         run_if_budget("flightrec", bench_flightrec)
         run_if_budget("planning", bench_planning)
@@ -3690,7 +3047,6 @@ def main():
         run_if_budget("pipeline", bench_pipeline)
         run_if_budget("ckpt_shard", bench_ckpt_shard)
         run_if_budget("multihost", bench_multihost)
-        run_if_budget("disagg", bench_disagg)
     # the per-phase wall clocks as DATA (the stderr "# phase ... done"
     # notes were print-only): one record the driver's BENCH tail and
     # test_bench_contract can both parse

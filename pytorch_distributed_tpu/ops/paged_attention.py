@@ -25,24 +25,18 @@ online-softmax machinery (ops/flash_attention.py):
   and ``ops.attention.attention`` dispatches here — so ``models/``
   attention code stays ONE implementation.
 
-Three interchangeable implementations, selected by
-:func:`set_paged_attention_impl` (default ``"auto"``):
+Two implementations, selected by :func:`set_paged_attention_impl`
+(default ``"auto"``), and a reference beside them:
 
 * ``"gather"`` — materialize the (bucket-sliced, NOT max_len-wide)
   pages into a per-row dense slab inside the op and run the UNCHANGED
-  ``dot_product_attention`` math. BIT-IDENTICAL to the pre-paged dense
-  path by the zero-tail argument (masked tail keys contribute exact
-  0.0 to every reduction; live keys occupy the same leading positions
-  — verified empirically per dtype in tests/test_paged_attention.py),
-  so the engine's pinned solo-``generate`` parity survives to the bit.
-* ``"stream"`` — the pure-jnp ``lax.scan``-over-pages reference: one
-  page of K/V gathered per step, an online-softmax carry (m, l, acc)
-  exactly like the flash kernel's VMEM scratch. The documented
-  semantics of the kernel, and the analytic model for the
-  bytes-per-token accounting (each page read ONCE, no dense
-  transient). Online softmax REORDERS the reductions, so parity with
-  the dense path is last-ulp-class, not bitwise — pinned per dtype
-  with explicit tolerances.
+  ``dot_product_attention`` math. BIT-IDENTICAL to attention over a
+  dense cache by the zero-tail argument (masked tail keys contribute
+  exact 0.0 to every reduction; live keys occupy the same leading
+  positions — verified empirically per dtype in
+  tests/test_paged_attention.py), so the engine's pinned
+  solo-``generate`` parity holds to the bit. The only impl that takes
+  int8 pools.
 * ``"kernel"`` — the Pallas TPU kernel. It walks what a row OWNS, many
   pages a step: the grid is over groups of rows, and each row runs as
   many steps as it has BLOCKS of live pages (``row_walk``: the pages
@@ -71,19 +65,22 @@ Three interchangeable implementations, selected by
 
 ``"auto"`` resolves to ``"kernel"`` on TPU and ``"gather"`` elsewhere:
 the gather impl is the provably-exact CPU/CI path, and on the chip the
-kernel is the point of this module. What a v5e showed (jax 0.9.0,
-libtpu 0.0.34, chip_smoke.py): the kernel compiles in a few seconds and
-matches ``"gather"`` — f32 to ~2e-6, bf16 to ~5e-3 of the output scale —
-for 16/16 heads of 64 and 32/8 heads of 128, W = 1 and 5, with and
-without a window, at page sizes 4-32 (f32) and 8-32 (bf16); smaller
-pages were not tried (the single-page body of PR 21; the blocked walk
-of PR 28 was compared with ``"gather"`` at the serving cells' shapes by
+kernel is the point of this module. :func:`paged_attention_reference`
+(one page a ``lax.scan`` step, an online-softmax carry) is selected by
+nothing: it is the plain float form the tests hold both against. What a
+v5e showed (jax 0.9.0, libtpu 0.0.34, chip_smoke.py): the kernel
+compiles in a few seconds and matches ``"gather"`` — f32 to ~2e-6,
+bf16 to ~5e-3 of the output scale — for 16/16 heads of 64 and 32/8
+heads of 128, W = 1 and 5, with and without a window, at page sizes
+4-32 (f32) and 8-32 (bf16); smaller pages were not tried (the
+single-page body of PR 21; the blocked walk of PR 28 was compared with
+``"gather"`` at the serving cells' shapes by
 ``scripts/paged_kernel_bench.py``, bf16 to ~4e-3). Every serving cell
 of the benchmark serves through it; PERF.md §6 has its timings.
 
 int8 KV caches (``kv_cache_quantize="int8"``): payload + per-token
-scale pools ride together (:class:`PagedKVQuant`); gather/stream
-dequantize per page with decode_cache's exact formula. The kernel does
+scale pools ride together (:class:`PagedKVQuant`); ``"gather"``
+dequantizes per page with decode_cache's exact formula. The kernel does
 not take quantized pools and REFUSES them by name
 (:func:`refuse_kernel_for`) — on a TPU an int8 cache needs
 ``set_paged_attention_impl("gather")`` said out loud, never a quiet
@@ -246,7 +243,8 @@ def paged_write(pool, new, page_tables, write_pos, keep, layer=None):
 # implementation dispatch
 # --------------------------------------------------------------------------
 
-_IMPL = "auto"  # auto | gather | stream | kernel
+_IMPLS = ("auto", "gather", "kernel")
+_IMPL = "auto"
 
 
 def set_paged_attention_impl(impl: str) -> None:
@@ -256,8 +254,7 @@ def set_paged_attention_impl(impl: str) -> None:
     on this flag, so switching drops them and already-compiled decode
     programs retrace with the new backend.
     """
-    if impl not in ("auto", "gather", "stream", "kernel"):
-        raise ValueError(f"unknown paged-attention impl {impl!r}")
+    _check_impl(impl)
     global _IMPL
     if impl == _IMPL:
         return
@@ -274,6 +271,14 @@ def set_paged_attention_impl(impl: str) -> None:
         jax.clear_caches()
 
 
+def _check_impl(impl: str) -> None:
+    if impl not in _IMPLS:
+        raise ValueError(
+            f"unknown paged-attention impl {impl!r}: one of "
+            f"{', '.join(map(repr, _IMPLS))}"
+        )
+
+
 def get_paged_attention_impl() -> str:
     return _IMPL
 
@@ -281,8 +286,9 @@ def get_paged_attention_impl() -> str:
 def resolve_paged_attention_impl(impl: Optional[str] = None) -> str:
     """The concrete backend an ``impl`` (default: the global flag)
     resolves to on this backend — the engine consults it once at
-    construction to pick the matching analytic bytes model."""
+    construction and refuses to tick under another."""
     impl = impl or _IMPL
+    _check_impl(impl)
     if impl != "auto":
         return impl
     return "kernel" if jax.default_backend() == "tpu" else "gather"
@@ -296,8 +302,8 @@ def refuse_kernel_for(*, quantized: bool, page_size: int = 8) -> None:
         raise ValueError(
             "the paged-attention kernel takes floating-point pools "
             "only; an int8 KV cache (kv_cache_quantize='int8') needs "
-            "set_paged_attention_impl('gather') (or 'stream') — on a "
-            "TPU 'auto' resolves to 'kernel'"
+            "set_paged_attention_impl('gather') — on a TPU 'auto' "
+            "resolves to 'kernel'"
         )
     if page_size % 8 and not _interpret():
         raise ValueError(
@@ -350,8 +356,8 @@ def paged_attention(
     With ``layer`` the pools are the STACKED leaves of a scanned model
     and every impl reads plane ``layer`` of them in place. ``keep``
     (the engine's write gate) names the rows that decode: the kernel
-    walks no page of a row it marks False and returns zeros for it; the
-    other impls ignore it, and the caller discards such rows anyway.
+    walks no page of a row it marks False and returns zeros for it;
+    ``"gather"`` ignores it, and the caller discards such rows anyway.
 
     The case is told from the shapes handed in: the kv heads are the
     key frame's width over ``q``'s head size, the value's head size the
@@ -416,12 +422,6 @@ def paged_attention(
             q, k_pages, v_pages, page_tables, lengths, scale, window,
             k_scale, v_scale, kdt, layer, dv,
         )
-    if impl == "stream":
-        return paged_attention_reference(
-            q, k_pages, v_pages, page_tables=page_tables, lengths=lengths,
-            layer=layer, scale=scale, window=window, k_scale=k_scale,
-            v_scale=v_scale, out_dtype=kdt, value_dim=dv,
-        )
     return _paged_kernel_call(
         q, k_pages, v_pages, page_tables, lengths, keep, layer, scale,
         window, dv,
@@ -470,25 +470,27 @@ def _paged_gather(q, k_pages, v_pages, tables, lengths, scale, window,
 
 
 # --------------------------------------------------------------------------
-# "stream": the pure-jnp scan-over-pages online-softmax reference
+# the reference: a pure-jnp scan over pages, online softmax
 # --------------------------------------------------------------------------
 
 
 def paged_attention_reference(
     q, k_pages, v_pages, *, page_tables, lengths, layer=None,
     scale: Optional[float] = None, window: Optional[int] = None,
-    k_scale=None, v_scale=None, out_dtype=None, value_dim=None,
+    value_dim=None,
 ):
-    """One page of K/V per ``lax.scan`` step, online-softmax carry.
+    """The float reference the tests compare the impls with: one page
+    of K/V per ``lax.scan`` step, online-softmax carry.
 
-    The documented semantics of the Pallas kernel and the analytic
-    model behind the bytes-per-token counters: per step it touches ONE
-    page frame per row (a ``[B, ps, Hkv, D]`` transient), never a
-    ``[B, n*ps]`` dense slab. Reductions are reassociated page-by-page
-    (rescale by ``exp(m_prev - m_new)``), so outputs match the dense
-    path to last-ulp tolerance per dtype, not bitwise — the gather impl
-    is the bit-exact one. ``v_pages`` None reads the values off the key
-    frame's first ``value_dim`` lanes (a latent cache).
+    Per step it touches ONE page frame per row (a ``[B, ps, Hkv, D]``
+    transient), never a ``[B, n*ps]`` dense slab. Reductions are
+    reassociated page-by-page (rescale by ``exp(m_prev - m_new)``), so
+    outputs match the dense path to last-ulp tolerance per dtype, not
+    bitwise — the gather impl is the bit-exact one. Floating-point
+    pools only, stored as ``paged_attention`` takes them (``layer``
+    names the plane of a stacked leaf). ``v_pages`` None reads the
+    values off the key frame's first ``value_dim`` lanes (a latent
+    cache).
     """
     B, W, Hq, D = q.shape
     ps, Hkv = k_pages.shape[-2], k_pages.shape[-1] // D
@@ -497,19 +499,17 @@ def paged_attention_reference(
     n = page_tables.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(D)
-    dtype = out_dtype or q.dtype
     qg = q.reshape(B, W, Hkv, G, D)
     qpos = lengths[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
 
-    def page(pages, scales, i, d):            # -> [B, ps, Hkv, d]
-        return _take_frames(
-            pages, scales, page_tables[:, i], layer, d, dtype
-        )
+    def page(pages, i, d):                    # -> [B, ps, Hkv, d]
+        out = pages[_plane(layer, page_tables[:, i])]
+        return out.reshape(out.shape[:2] + (-1, d))
 
     def body(carry, i):
         m, l, acc = carry
-        k = page(k_pages, k_scale, i, D)
-        v = k[..., :dv] if v_pages is None else page(v_pages, v_scale, i, dv)
+        k = page(k_pages, i, D)
+        v = k[..., :dv] if v_pages is None else page(v_pages, i, dv)
         s = jnp.einsum(
             "bwkgd,bpkd->bwkgp", qg, k,
             preferred_element_type=jnp.float32,

@@ -422,9 +422,9 @@ class HostPipelineStep:
     Cross-stage reductions inside the optimizer (global-norm clipping) are
     out of scope: ``tx`` must be elementwise per stage (DESIGN.md §25).
 
-    ``delay_s`` sleeps that long before each compute op, OUTSIDE the math
-    (the r18 ``prefill_delay_s`` idiom): a 1-core box then behaves like an
-    S-deep pipeline because sleeps overlap across processes — the bench's
+    ``delay_s`` sleeps that long before each compute op, OUTSIDE the
+    math: a 1-core box then behaves like an S-deep pipeline because
+    sleeps overlap across processes — the bench's
     bubble-measurement shaping, with bit-identity to the delay-free run
     enforced by CRC.
     """

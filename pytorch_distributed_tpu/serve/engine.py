@@ -17,11 +17,8 @@ by tests:
   engine installs a ``PagedView`` around the traced model apply, new
   K/V lands via per-page scatters of only the deliberately-written
   positions, and attention streams the pages — no transient
-  ``[S, max_len]`` dense view, the round-11 gather tax this round
-  removed). ``decode_mode="dense"`` keeps the round-11 dense-gather
-  program as the A/B baseline the bench's ``serving_paged_attn`` phase
-  measures against. Free / mid-prefill rows still never write — the
-  per-page write drops their rows exactly as the dense scatter did.
+  ``[S, max_len]`` dense view). Free / mid-prefill rows never write —
+  the per-page write drops their rows.
 * **length buckets** bound what the remaining dense spans (chunked
   prefill's per-slot row, the speculative draft's short context) and
   the paged streams actually touch: widths round up to the live
@@ -95,7 +92,6 @@ from pytorch_distributed_tpu.runtime import tracing
 from pytorch_distributed_tpu.serve.kv_slots import (
     PagedKVPool,
     extract_frames,
-    frame_nbytes,
     frame_signature,
     gather_pages,
     scatter_kv,
@@ -180,11 +176,6 @@ class EngineConfig:
     page_size: Optional[int] = None
     num_pages: Optional[int] = None
     prefix_cache: bool = True
-    # "paged" (default): the decode tick attends in place over the page
-    # pool (ops/paged_attention) with length-bucketed widths; "dense"
-    # keeps the round-11 full-width gather programs — the A/B baseline
-    # bench.py's serving_paged_attn phase measures the paged path against
-    decode_mode: str = "paged"
     # r18 tiers: "solo" (the default — the bit-identical A/B baseline,
     # every pre-r18 code path byte-for-byte unchanged) serves requests
     # end to end; "prefill" fills pages and ships MigrationFrames via
@@ -195,22 +186,8 @@ class EngineConfig:
     # fleet label: stamps telemetry records (engine_id gauge label) and
     # migration frames; None keeps the single-engine-implicit schema
     engine_id: Optional[str] = None
-    # synthetic per-token compute (the r15 ``shard_delay_s`` idiom for
-    # serving): what a disaggregated tier can actually overlap. A
-    # prefill chunk sleeps prefill_delay_s * chunk_len; a decode tick
-    # sleeps decode_delay_s * active_slots. Bench/chaos only — sleeps
-    # never touch the math, so CRCs are invariant to either knob, and a
-    # 1-core host running N sleeping processes behaves like an N-way
-    # fleet (compute overlaps; the python between sleeps serializes).
-    prefill_delay_s: float = 0.0
-    decode_delay_s: float = 0.0
 
     def __post_init__(self):
-        if self.decode_mode not in ("paged", "dense"):
-            raise ValueError(
-                f"decode_mode must be 'paged' or 'dense', got "
-                f"{self.decode_mode!r}"
-            )
         if self.role not in ("solo", "prefill", "decode"):
             raise ValueError(
                 f"role must be 'solo', 'prefill' or 'decode', got "
@@ -232,11 +209,6 @@ class EngineConfig:
             raise ValueError(
                 f"prefill_chunk {self.prefill_chunk} > max_len "
                 f"{self.max_len}: no request could ever be admitted"
-            )
-        if self.prefill_delay_s < 0 or self.decode_delay_s < 0:
-            raise ValueError(
-                "prefill_delay_s / decode_delay_s must be >= 0, got "
-                f"{self.prefill_delay_s} / {self.decode_delay_s}"
             )
         if self.page_size is not None and (
             self.page_size < 1 or self.max_len % self.page_size
@@ -406,45 +378,24 @@ class ServeEngine:
         self.decode_compiles = 0
         # length buckets: the static widths the prefill/decode programs
         # compile at — powers of two in pages, capped at max_pages
-        # (dense mode has exactly one width, the full table)
-        if config.decode_mode == "paged":
-            self._buckets = self._bucket_list(mp)
-        else:
-            self._buckets = [mp]
+        self._buckets = self._bucket_list(mp)
         # per-bucket compile counts (the traced program bodies bump
         # them): the bounded-compile invariant is now "each occupied
         # bucket compiled EXACTLY once" — decode_compiles stays the
         # cumulative total across buckets
         self._decode_bucket_compiles: dict = {}
         self._prefill_bucket_compiles: dict = {}
-        # analytic HBM accounting for the decode hot path: bytes one
-        # tick moves under this mode/impl's traffic model (DESIGN.md
-        # §17), accumulated host-side as plain ints so the disarmed
-        # tracing cost stays one is-None test. The impl resolves
-        # ONCE — the accounting follows the backend the programs trace
-        self._resolved_impl = (
-            resolve_paged_attention_impl()
-            if config.decode_mode == "paged" else "dense"
-        )
+        # the impl resolves ONCE: it is what the programs trace, and
+        # what the tick spans' ``fetched_pages`` count
+        self._resolved_impl = resolve_paged_attention_impl()
         if self._resolved_impl == "kernel":
             # fail at construction, not at the first decode compile
             refuse_kernel_for(quantized=getattr(
                 getattr(model, "config", None), "kv_cache_quantize", None
             ) is not None, page_size=self.pool.page_size)
-        # bytes of ONE page frame across every KV-payload leaf (layer
-        # stacking included) — the unit of the analytic HBM accounting
-        self._frame_bytes_target = frame_nbytes(self.pool.cache)
-        # and of one token in one layer's widest leaf: the kernel's
+        # bytes of one token in one layer's widest leaf: the kernel's
         # block size follows it (the tick spans' ``fetched_pages``)
         self._token_bytes = token_nbytes(self.pool.cache)
-        self._frame_bytes_draft = (
-            frame_nbytes(self.draft_pool.cache)
-            if self.draft_pool is not None else 0
-        )
-        self._tick_cost_cache: dict = {}
-        self.decode_gather_bytes = 0   # dense-intermediate traffic only
-        self.decode_hbm_bytes = 0      # gather + attention-stream reads
-        self._decode_tokens = 0        # tokens emitted by decode ticks
         # speculative bookkeeping (raw per-verify acceptance; host ints)
         self.spec_verifies = 0
         self.spec_drafted = 0
@@ -657,40 +608,25 @@ class ServeEngine:
         self._decode_bucket_compiles[n_pages] = (
             self._decode_bucket_compiles.get(n_pages, 0) + 1
         )
-        if self.config.decode_mode == "paged":
-            # attend in place over the pool: decode_cache writes the
-            # new token through per-page scatters (inactive rows drop
-            # theirs) and attention streams the bucket-sliced tables —
-            # no dense intermediate, no scatter-back. The pool leaves
-            # ride the layer loop as its carry (models/scan.py) and
-            # come back as the same buffers, donated in and aliased out
-            # (scripts/pool_hlo_check.py reads that off the compiled
-            # program, where it has to hold: the jaxpr alone can say
-            # "the returned cache IS the pool" over a program that
-            # copies every leaf)
-            ptb = jax.lax.slice_in_dim(pt, 0, n_pages, axis=1)
-            with paged_view(PagedView(
-                page_tables=ptb, keep=active,
-                page_size=self.pool.page_size,
-            )):
-                last, cache, sown = decode_step_body(
-                    self.model, params, cache, toks,
-                    cache_len=self.config.max_len,
-                    positions=lengths[:, None],
-                    write_pos=lengths, with_intermediates=True,
-                )
-        else:
-            dense = gather_pages(cache, pt, self.pool.tails)
-            last, dense, sown = decode_step_body(
-                self.model, params, dense, toks,
+        # attend in place over the pool: decode_cache writes the new
+        # token through per-page scatters (inactive rows drop theirs)
+        # and attention streams the bucket-sliced tables — no dense
+        # intermediate, no scatter-back. The pool leaves ride the layer
+        # loop as its carry (models/scan.py) and come back as the same
+        # buffers, donated in and aliased out (scripts/pool_hlo_check.py
+        # reads that off the compiled program, where it has to hold: the
+        # jaxpr alone can say "the returned cache IS the pool" over a
+        # program that copies every leaf)
+        ptb = jax.lax.slice_in_dim(pt, 0, n_pages, axis=1)
+        with paged_view(PagedView(
+            page_tables=ptb, keep=active,
+            page_size=self.pool.page_size,
+        )):
+            last, cache, sown = decode_step_body(
+                self.model, params, cache, toks,
                 cache_len=self.config.max_len,
                 positions=lengths[:, None],
                 write_pos=lengths, with_intermediates=True,
-            )
-            # persist ONLY the decoding rows' written token; free and
-            # mid-prefill rows drop their write on the floor
-            cache = scatter_kv(
-                cache, dense, pt, lengths[:, None], active[:, None]
             )
         pair = jax.vmap(jax.random.split)(keys)  # [S, 2, 2]
         nxt = sample_logits_rows(last, pair[:, 1], temps, top_ks, top_ps)
@@ -719,10 +655,10 @@ class ServeEngine:
         retires the request, so device/host state never diverges for a
         row that keeps decoding).
 
-        In paged mode the DRAFT keeps a dense view — its k sequential
-        single-token steps re-read the whole live context every step,
-        the one shape a dense span still wins — but bucket-sliced to
-        ``n_pages`` instead of ``max_len``-wide; the target verify
+        The DRAFT keeps a dense view — its k sequential single-token
+        steps re-read the whole live context every step, the one shape
+        a dense span still wins — bucket-sliced to ``n_pages`` instead
+        of ``max_len``-wide; the target verify
         attends in place over the pool like the plain tick, with the
         ``[S, k+1]`` query block riding the same paged primitive.
         """
@@ -733,7 +669,6 @@ class ServeEngine:
         k = self.spec.num_draft_tokens
         S = self.config.num_slots
         max_len = self.config.max_len
-        paged = self.config.decode_mode == "paged"
         width = n_pages * self.pool.page_size
         dpt = jax.lax.slice_in_dim(dpt, 0, n_pages, axis=1)
         idx = jnp.arange(k + 1)[None, :]
@@ -807,37 +742,22 @@ class ServeEngine:
 
         # ---- verify: one chunked target pass scores the proposal ----
         chunk = jnp.concatenate([toks[:, None], drafts], axis=1)
-        if paged:
-            # the [S, k+1] verify attends in place over the pool: the
-            # k+1 K/V entries land via per-page scatters (inactive rows
-            # dropped) and the paged primitive streams the bucket
-            ptb = jax.lax.slice_in_dim(pt, 0, n_pages, axis=1)
-            with paged_view(PagedView(
-                page_tables=ptb, keep=active,
-                page_size=self.pool.page_size,
-            )):
-                logits, st = self.model.apply(
-                    {"params": params, "cache": cache},
-                    chunk, decode=True, cache_len=max_len,
-                    mutable=["cache"],
-                    positions=lengths[:, None] + idx,
-                    write_pos=lengths,
-                )
-            cache = st["cache"]
-        else:
-            dense_t = gather_pages(cache, pt, self.pool.tails)
+        # the [S, k+1] verify attends in place over the pool: the k+1
+        # K/V entries land via per-page scatters (inactive rows dropped)
+        # and the paged primitive streams the bucket
+        ptb = jax.lax.slice_in_dim(pt, 0, n_pages, axis=1)
+        with paged_view(PagedView(
+            page_tables=ptb, keep=active,
+            page_size=self.pool.page_size,
+        )):
             logits, st = self.model.apply(
-                {"params": params, "cache": dense_t},
+                {"params": params, "cache": cache},
                 chunk, decode=True, cache_len=max_len,
                 mutable=["cache"],
                 positions=lengths[:, None] + idx,
                 write_pos=lengths,
             )
-            vpos = lengths[:, None] + idx
-            cache = scatter_kv(
-                cache, st["cache"], pt, vpos,
-                active[:, None] & jnp.ones((1, k + 1), bool),
-            )
+        cache = st["cache"]
 
         # ---- acceptance ----
         # greedy: the longest draft prefix matching the target's own
@@ -1131,11 +1051,11 @@ class ServeEngine:
         if self._inject_backlog:
             self._drain_inject_backlog()
 
-    # -- length buckets + analytic HBM accounting --------------------------
+    # -- length buckets ----------------------------------------------------
     def _compile_note(self, kind: str, n_pages: int) -> str:
         """Recompile-sentinel key: per bucket when buckets exist (each
         bucket is its own program with its own once-contract); the
-        round-11 plain name when exactly one width exists."""
+        plain name when exactly one width exists (a one-page table)."""
         if len(self._buckets) == 1:
             return f"serve.{kind}"
         return f"serve.{kind}[b{n_pages}]"
@@ -1146,14 +1066,11 @@ class ServeEngine:
         (max live length + the tick's write span). Inactive rows may
         point beyond it — their reads are discarded and their writes
         dropped, so the clamp is harmless by construction."""
-        if self.config.decode_mode == "dense":
-            return self.pool.max_pages
         if resolve_paged_attention_impl() != self._resolved_impl:
             # set_paged_attention_impl() cleared the jit caches: the
             # next dispatch would retrace (breaking the compiled-once-
-            # per-bucket contract) while the analytic byte model kept
-            # pricing the OLD backend — refuse loudly instead of
-            # silently desynchronizing both
+            # per-bucket contract) under a backend the engine was not
+            # checked against at construction — refuse loudly
             raise RuntimeError(
                 f"paged-attention impl changed under a live engine "
                 f"(engine resolved {self._resolved_impl!r}, flag now "
@@ -1167,31 +1084,6 @@ class ServeEngine:
         ) + W
         return self._bucket_for(-(-need // self.pool.page_size))
 
-    def _tick_cost(self, n_pages: int):
-        """(gather_bytes, total_hbm_bytes) one decode tick moves under
-        the active mode/impl's analytic traffic model (DESIGN.md §17) —
-        cached per bucket so the per-tick cost is two integer adds."""
-        cost = self._tick_cost_cache.get(n_pages)
-        if cost is None:
-            S = self.config.num_slots
-            fb = self._frame_bytes_target
-            # gather traffic = the dense intermediate (pool read +
-            # dense write); the attention stream reads each page once
-            gather = attn = 0
-            if self._resolved_impl in ("dense", "gather"):
-                gather += 2 * S * n_pages * fb
-            attn += S * n_pages * fb
-            if self.spec is not None:
-                # the draft keeps a (bucketed) dense view: one gather,
-                # k+1 proposal steps + the fill feed each re-read it
-                k = self.spec.num_draft_tokens
-                dfb = self._frame_bytes_draft
-                gather += 2 * S * n_pages * dfb
-                attn += (k + 2) * S * n_pages * dfb
-            cost = (gather, gather + attn)
-            self._tick_cost_cache[n_pages] = cost
-        return cost
-
     @property
     def decode_buckets(self):
         """Bucket widths (pages) the decode tick has compiled at."""
@@ -1201,22 +1093,13 @@ class ServeEngine:
     def prefill_buckets(self):
         return set(self._prefill_bucket_compiles)
 
-    @property
-    def decode_hbm_bytes_per_token(self) -> float:
-        """Analytic decode-path HBM bytes per emitted token — the
-        number the dense-gather path roughly doubled and this round's
-        paged attention removes (serve.decode_hbm_bytes_per_token
-        tracing counter / bench serving_paged_attn phase)."""
-        return self.decode_hbm_bytes / max(self._decode_tokens, 1)
-
     def precompile_decode_buckets(self) -> None:
         """Compile every decode-tick bucket with a no-op dispatch so
         serving never pays a compile mid-measurement.
 
         All rows ride as INACTIVE: pool writes are dropped by the keep
         gate, and toks/lengths/keys pass through their ``where(active,
-        ...)`` untouched — device state is semantically unchanged. The
-        analytic byte counters are left alone (nothing was served).
+        ...)`` untouched — device state is semantically unchanged.
         ``serve.loadgen.warm_up`` calls this after its warm request; a
         test driving the engine directly still sees one compile per
         OCCUPIED bucket.
@@ -1274,10 +1157,6 @@ class ServeEngine:
                 else 0.0
             ),
             prefix_hit_rate=pool.prefix_hit_rate,
-            decode_gather_bytes=self.decode_gather_bytes,
-            decode_hbm_bytes_per_token=round(
-                self.decode_hbm_bytes_per_token, 1
-            ),
         )
         if self.spec is not None:
             gauges.update(
@@ -1292,16 +1171,6 @@ class ServeEngine:
             decode_ticks=self._decode_ticks,
             **gauges,
         )
-        if tracing._tracer is not None:
-            # the decode-path gather tax (and its removal) as recorded
-            # facts — plain precomputed ints, armed-only emission
-            tracing.counter(
-                "serve.decode_gather_bytes", self.decode_gather_bytes
-            )
-            tracing.counter(
-                "serve.decode_hbm_bytes_per_token",
-                gauges["decode_hbm_bytes_per_token"],
-            )
 
     def run_until_drained(self, max_steps: int = 1_000_000) -> None:
         """Step until every submitted request reaches a terminal state."""
@@ -1463,8 +1332,6 @@ class ServeEngine:
                     self._prefill_bucket_compiles.get(n_pages),
                 )
             self.pool.lengths[slot] = plan.start + plan.chunk_len
-            if cfg.prefill_delay_s:
-                time.sleep(cfg.prefill_delay_s * plan.chunk_len)
             chunks += 1
             if plan.final:
                 # the slot's full prompt pages now hold canonical KV —
@@ -1531,8 +1398,8 @@ class ServeEngine:
         ``fetched_pages``: the pages the tick's attention goes over for
         them — the kernel's blocks of the live pages, whole (it copies
         a block's live pages only; its products span the block), by the
-        kernel's own arithmetic; any other impl gathers every slot's
-        bucket."""
+        kernel's own arithmetic; the ``gather`` impl gathers every
+        slot's bucket."""
         W = 1 if self.spec is None else self.spec.num_draft_tokens + 1
         ps = self.pool.page_size
         lengths = np.asarray(
@@ -1589,12 +1456,6 @@ class ServeEngine:
                 self._compile_note("decode", n_pages),
                 self._decode_bucket_compiles.get(n_pages),
             )
-        gb, hb = self._tick_cost(n_pages)
-        self.decode_gather_bytes += gb
-        self.decode_hbm_bytes += hb
-        self._decode_tokens += len(decoding)
-        if self.config.decode_delay_s:
-            time.sleep(self.config.decode_delay_s * len(decoding))
         with tracing.span("serve.token_fetch"):
             # the one per-tick device sync: every sampled token comes down
             nxt = np.asarray(nxt)
@@ -1676,9 +1537,6 @@ class ServeEngine:
                 self._compile_note("decode", n_pages),
                 self._decode_bucket_compiles.get(n_pages),
             )
-        gb, hb = self._tick_cost(n_pages)
-        self.decode_gather_bytes += gb
-        self.decode_hbm_bytes += hb
         with tracing.span("serve.token_fetch"):
             # ONE per-tick device sync: k+1 emit columns + the
             # accepted count packed into a single [S, k+2] fetch
@@ -1690,7 +1548,6 @@ class ServeEngine:
         with tracing.span("serve.emit"):
             for slot, h in decoding:
                 n = int(acc[slot]) + 1
-                self._decode_tokens += n
                 # mirror the in-program advances: the verify wrote k+1
                 # entries but only a+1 became sequence; the rejected
                 # tail sits beyond the accepted length where the next

@@ -46,20 +46,15 @@ tokens, same absolute positions, same deterministic program — so the
 gathered dense view is bitwise what the unshared engine computed, and
 the solo-``generate`` parity suite holds with sharing on.
 
-The static-shape tax, and its round-12 removal: through round 11 every
-decode tick gathered the live slots' pages into a transient dense
-``[S, max_len]`` view — per-tick read traffic roughly doubled (gather +
-attention read) and the transient peak carried a full dense copy. The
-default engine (``decode_mode="paged"``) now attends IN PLACE over the
-pool (``ops/paged_attention``): new-token K/V lands via per-page
-scatters and attention streams the pages, so the remaining dense spans
-(chunked prefill's one row, the speculative draft's short context) are
-bucket-sliced to the live maximum's power-of-two page width, never
-``max_len``. The gather helpers below stay the ``decode_mode="dense"``
-baseline path — bench.py's ``serving_paged_attn`` phase measures the
-paged tick against it (tokens/s and analytic HBM bytes/token, parity
-enforced in-phase). Resident KV is ``pages_in_use × page_size`` either
-way (``serving_kv_bytes_ratio`` >= 2x pinned by test_bench_contract).
+Where a dense view is still made: the decode tick and the speculative
+verify attend IN PLACE over the pool (``ops/paged_attention``) —
+new-token K/V lands via per-page scatters and attention reads the pages.
+The gather helpers below (``gather_pages`` / ``scatter_kv``) serve the
+two dense spans that remain, chunked prefill's one row and the
+speculative draft's short context, both bucket-sliced to the live
+maximum's power-of-two page width, never ``max_len``. Resident KV is
+``pages_in_use × page_size`` (``serving_kv_bytes_ratio`` >= 2x pinned by
+test_bench_contract).
 """
 
 from __future__ import annotations

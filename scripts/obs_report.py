@@ -935,17 +935,6 @@ def report(trace_path, metric_paths, top_n=10, out=None,
                 f"(fraction of prompt tokens served from shared pages)",
                 file=out,
             )
-        if "decode_hbm_bytes_per_token" in last:
-            bpt = last["decode_hbm_bytes_per_token"]
-            serve["decode_hbm_bytes_per_token"] = bpt
-            gather = last.get("decode_gather_bytes", 0)
-            print(
-                f"  decode HBM: {bpt:,.0f} analytic bytes/token, "
-                f"{gather / 1e6:,.1f} MB total gather traffic "
-                f"(the dense-intermediate tax — 0 under the paged "
-                f"kernel, bucket-wide under the gather fallback, "
-                f"max_len-wide in dense mode)", file=out,
-            )
         if last.get("spec_verifies"):
             apv = last.get("spec_accepted", 0) / last["spec_verifies"]
             serve["spec_accepted_per_verify"] = apv

@@ -86,7 +86,6 @@ def run_storm(args, model, params, max_len, reqs, arrivals, writer,
                          prefill_chunk=args.prefill_chunk,
                          page_size=args.page_size,
                          num_pages=args.num_pages,
-                         decode_mode=args.decode_mode,
                          role=role, engine_id=eid),
             spec=spec if role == "solo" else None,
             prefix_store=store if role != "decode" else None,
@@ -181,13 +180,9 @@ def main():
                     help="preset: size max_len WELL past the live "
                     "lengths (4x the workload fit, >= 256, capped at "
                     "the model's position table) — the regime paged "
-                    "attention exists for; the summary's bytes/token "
-                    "shows the decode path streaming the live bucket "
-                    "instead of the max_len-wide gather")
-    ap.add_argument("--decode-mode", choices=("paged", "dense"),
-                    default="paged",
-                    help="'dense' runs the round-11 full-width gather "
-                    "tick (the A/B baseline) instead of paged attention")
+                    "attention exists for; the summary's decode "
+                    "buckets show the tick running at the live lengths' "
+                    "page width, not max_len's")
     ap.add_argument("--spec-k", type=int, default=0,
                     help="enable speculative decoding with k draft "
                     "tokens per tick (draft = a randomly initialized "
@@ -278,7 +273,7 @@ def main():
     if args.long_context and not args.max_len:
         # the long-context mix: a pool sized far past the live lengths
         # (capped at the model's position table) so the decode tick's
-        # bucketed stream, not max_len, sets the bytes/token
+        # bucket, not max_len, sets what a tick reads
         from pytorch_distributed_tpu.generation import model_max_len
 
         limit = model_max_len(model) or 1 << 30
@@ -330,8 +325,7 @@ def main():
         EngineConfig(num_slots=args.slots, max_len=max_len,
                      prefill_chunk=args.prefill_chunk,
                      page_size=args.page_size,
-                     num_pages=args.num_pages,
-                     decode_mode=args.decode_mode),
+                     num_pages=args.num_pages),
         spec=spec,
     )
     # serve.loadgen's shared warm-up/pacing: both programs compile
@@ -360,11 +354,6 @@ def main():
     print(f"  prefix hit rate    = {pool.prefix_hit_rate:.3f} "
           f"({pool.prefix_hits}/{pool.prefix_lookups} admissions, "
           f"{pool.shared_tokens} prompt tokens served copy-free)")
-    print(f"  decode bytes/token = "
-          f"{engine.decode_hbm_bytes_per_token:,.0f} analytic HBM "
-          f"(mode={args.decode_mode}, gather "
-          f"{engine.decode_gather_bytes:,d} B total — the dense-"
-          f"intermediate tax paged attention removes)")
     if engine.spec is not None and engine.spec_verifies:
         print(f"  spec accept/verify = "
               f"{engine.spec_accepted / engine.spec_verifies:.2f} "

@@ -135,22 +135,6 @@ def test_bench_on_requested_cpu_is_host_meaningful():
         f"speculative decode lost to plain decode: {spec}"
     )
     assert spec["accepted_per_verify"] > 0, spec  # drafts actually land
-    # paged-attention decode (round 12): the decode tick must at least
-    # MATCH the dense-gather tick on tokens/sec (output parity enforced
-    # in-phase — the phase raises on divergence, and on any bucket
-    # compiled more than once) while the analytic decode HBM
-    # bytes/token shrinks >= 1.5x at the long-context mix (in practice
-    # ~16x: a 1-2 page live bucket vs the 16-page max_len gather)
-    pa = one_metric("serving_paged_attn_tokens_per_sec")
-    assert pa["value"] > 0
-    assert pa["vs_baseline"] is not None and pa["vs_baseline"] >= 1.0, (
-        f"paged attention lost to the dense-gather tick: {pa}"
-    )
-    pr = one_metric("serving_paged_attn_bytes_per_token_ratio")
-    assert pr["value"] >= 1.5, pr
-    assert pr["paged_bytes_per_token"] > 0, pr
-    assert pr["dense_bytes_per_token"] > pr["paged_bytes_per_token"], pr
-    assert pr["decode_buckets"], pr
 
     # the input_pipeline phases must stay inside their time budget (the
     # r3 starvation incident: the feed phase alone ran >25 min and ate
@@ -168,7 +152,6 @@ def test_bench_on_requested_cpu_is_host_meaningful():
     assert durations["serving"] < 300, durations
     assert durations.get("serving_paged", 999) < 300, durations
     assert durations.get("serving_spec", 999) < 300, durations
-    assert durations.get("serving_paged_attn", 999) < 300, durations
     assert durations.get("elastic", 999) < 300, durations
 
     # ...and the same numbers must land as DATA: one phase_durations_s
@@ -181,8 +164,8 @@ def test_bench_on_requested_cpu_is_host_meaningful():
     ]
     assert len(pd) == 1, proc.stderr[-2000:]
     for phase in ("input_pipeline_feed", "serving", "serving_paged",
-                  "serving_spec", "serving_paged_attn",
-                  "observability", "flightrec", "planning", "elastic"):
+                  "serving_spec", "observability", "flightrec",
+                  "planning", "elastic"):
         assert phase in pd[0]["value"], pd[0]
     assert pd[0]["value"] == pytest.approx(durations, abs=0.2)
 
@@ -305,43 +288,6 @@ def test_bench_on_requested_cpu_is_host_meaningful():
     assert mhb["bytes_exact"] is True, mhb
     assert "multihost" in pd[0]["value"], pd[0]
     assert durations.get("multihost", 999) < 120, durations
-
-    # the disagg phase (r18): 2 prefill + 2 decode shipping int8 KV
-    # frames over the real P2P ring, placement by the router's LPT —
-    # must beat the BEST static independent split (indep-4 AND indep-2
-    # both measured) >= 1.2x on the pinned heavy-tailed storm (priced
-    # ceiling ~1.37x), with every stream verified bit-identical to the
-    # delay-free solo reference INSIDE the phase (it raises, so the
-    # ratio can never come from wrong tokens)
-    dg = one_metric("disagg_fleet_tokens_per_sec")
-    assert dg["value"] > 0, dg
-    assert dg["vs_baseline"] is not None and dg["vs_baseline"] >= 1.2, (
-        f"fleet lost its edge over the best independent split: {dg}"
-    )
-    assert 0 < dg["fleet_wall_s"] < min(
-        dg["indep4_wall_s"], dg["indep2_wall_s"]
-    ), dg
-    # EXACT migration accounting: 32 requests x 3 pages each (24-token
-    # prompts, 8-token pages), payload == pages x per-page bytes, and
-    # the int8 (+ f32 scale sidecar) page <= 0.55x its f32 cost
-    assert dg["migration_pages"] == 96, dg
-    assert dg["migration_payload_bytes"] == (
-        dg["migration_pages"] * dg["page_nbytes"]
-    ), dg
-    assert dg["bytes_exact"] is True, dg
-    assert dg["int8_byte_ratio"] <= 0.55, dg
-    # the in-process router storm: p99 TTFT under its pinned budget,
-    # the shared system prompt prefilled once per FLEET (8 pages, the
-    # peer prefill engine adopts from the store), and the engine-loss
-    # drill replaying bit-identically (checked inside the phase)
-    ttft = one_metric("disagg_storm_ttft_ms_p99")
-    assert 0 < ttft["value"] <= 2500.0, ttft
-    assert ttft["prefix_store_puts"] == 8, ttft
-    assert ttft["prefix_store_hits"] >= 8, ttft
-    assert ttft["loss_drill_replays"] >= 1, ttft
-    assert ttft["storm_tokens_per_sec"] > 0, ttft
-    assert "disagg" in pd[0]["value"], pd[0]
-    assert durations.get("disagg", 999) < 300, durations
 
     # the ckpt_shard phase (r17): at replication=1 every rank of the
     # sharded save must write <= 1.2x its fair share of the full

@@ -5,10 +5,12 @@ Contracts under test, on top of test_serve_paged.py's parity suite:
 * the op: the ``gather`` impl is BIT-IDENTICAL per dtype to
   ``gather_pages``-style dense materialization + the unchanged
   ``dot_product_attention`` (the zero-tail argument made executable);
-  the ``stream`` (lax.scan online-softmax) reference and the Pallas
-  ``kernel`` (interpret off-TPU) match the dense path to explicit
-  per-dtype tolerances — online softmax reorders reductions, so their
-  parity is last-ulp-class, pinned, not assumed;
+  ``paged_attention_reference`` (lax.scan online softmax, selected by
+  no impl value) and the Pallas ``kernel`` (interpret off-TPU) match
+  the dense path to explicit per-dtype tolerances — online softmax
+  reorders reductions, so their parity is last-ulp-class, pinned, not
+  assumed; the setter and the op take ``auto|gather|kernel`` and name
+  them when they refuse another;
 * null-page frame 0 is unobservable (garbage in frame 0 changes no
   output), ragged lengths (including 0) and the ``[W > 1]`` verify
   block's internal causal order mask inside the op, GQA maps kv heads
@@ -16,16 +18,17 @@ Contracts under test, on top of test_serve_paged.py's parity suite:
 * per-page writes land exactly where the page table says, and dropped
   rows (keep=False) never touch the pool — the scatter_kv invariant
   carried to the new write path;
-* the engine: dense-mode vs paged-mode A/B runs emit identical
-  streams while the paged run's analytic HBM bytes shrink; slot reuse
-  across length buckets recompiles AT MOST once per bucket (a second
-  wave of the same shape compiles nothing); CoW-shared pages attend
+* the engine: a long-context workload and an int8 cache emit solo
+  ``generate``'s streams; ``EngineConfig`` has the ten fields it has;
+  slot reuse across length buckets recompiles AT MOST once per bucket
+  (a second wave of the same shape compiles nothing); CoW-shared pages attend
   correctly while BOTH sharers are live mid-decode; the ``[k+1]``
   paged verify stays bit-identical to solo generate; precompiling
   buckets is bitwise state-neutral; ``auto_page_size`` warns once on
   the odd-max_len 1-token-page degeneration.
 """
 
+import dataclasses
 import logging
 import sys
 
@@ -40,10 +43,10 @@ from pytorch_distributed_tpu.ops.attention import dot_product_attention
 from pytorch_distributed_tpu.ops.paged_attention import (
     PagedKVQuant,
     paged_attention,
+    paged_attention_reference,
     paged_write,
     set_paged_attention_impl,
 )
-from pytorch_distributed_tpu.runtime import tracing
 from pytorch_distributed_tpu.serve import (
     EngineConfig,
     Request,
@@ -58,7 +61,9 @@ from pytorch_distributed_tpu.serve.kv_slots import (
 
 pytestmark = pytest.mark.serve
 
-IMPLS = ("gather", "stream", "kernel")
+# the two impls the setter selects, and the float reference beside
+# them (``paged_attention_reference``, called directly: no impl value)
+IMPLS = ("gather", "reference", "kernel")
 # how a pool leaf is stored: per layer ([P1, ps, Hkv * D], an unrolled
 # stack), or stacked [L, P1, ps, Hkv * D] with the op reading ONE plane
 # of it in place (a scanned stack; the other planes hold noise, so a
@@ -100,11 +105,14 @@ def _leaf(pool, L, fold=2):
     return leaf, jnp.asarray(layer, jnp.int32)
 
 
-def _paged(q, kp, vp, L, **kw):
-    """``paged_attention`` over the stored form of logical pools."""
+def _paged(q, kp, vp, L, impl=None, **kw):
+    """``paged_attention`` (or the reference) over the stored form of
+    logical pools."""
     kl, layer = _leaf(kp, L)
     vl, _ = _leaf(vp, L)
-    return paged_attention(q, kl, vl, layer=layer, **kw)
+    if impl == "reference":
+        return paged_attention_reference(q, kl, vl, layer=layer, **kw)
+    return paged_attention(q, kl, vl, layer=layer, impl=impl, **kw)
 
 
 def _dense_ref(q, kp, vp, tables, lengths, **kw):
@@ -143,7 +151,7 @@ class TestPagedAttentionOp:
                 np.asarray(out, np.float32), np.asarray(ref, np.float32)
             ), str(dtype)
 
-    @pytest.mark.parametrize("impl", ["stream", "kernel"])
+    @pytest.mark.parametrize("impl", ["reference", "kernel"])
     def test_streaming_impls_match_dense_per_dtype(self, impl, L):
         """Online softmax reassociates the reductions: parity with the
         dense path is pinned per dtype at explicit tolerances (f32
@@ -271,6 +279,26 @@ class TestPagedAttentionOp:
             set_paged_attention_impl("mosaic")
 
 
+# the two values that select something and ``auto`` are all there is
+_NAMED = "'auto', 'gather', 'kernel'"
+
+
+def test_stream_is_no_value_of_the_setter():
+    with pytest.raises(ValueError, match=_NAMED):
+        set_paged_attention_impl("stream")
+
+
+def test_the_op_refuses_an_impl_it_does_not_know():
+    """It names the values it takes; it does not fall through to the
+    kernel."""
+    q, kp, vp, tables, lengths = _pool_case(np.random.default_rng(9))
+    with pytest.raises(ValueError, match=_NAMED):
+        _paged(
+            q, kp, vp, None, page_tables=tables, lengths=lengths,
+            impl="stream",
+        )
+
+
 # the heads of the two serving cells: GPT-2-medium's 16/16 of 64 and
 # Mistral's GQA at 128 (its 32/8 cut to 8/2: the group of 4 is kept)
 @pytest.mark.parametrize("window", [None, 6])
@@ -291,13 +319,8 @@ def test_kernel_reads_its_plane_in_place(L, Hq, Hkv, D, W, window):
     assert np.max(np.abs(out - ref)) <= 3e-6
 
 
-# the stream case is red on the parent tree (its 3e-6 is an absolute
-# bound and these values reach ~12); it stays ONE case, unstacked, so
-# the known failure neither multiplies nor goes quiet here
-@pytest.mark.parametrize("impl,L", [
-    (impl, L) for impl in IMPLS for L in STACKS
-    if impl != "stream" or L is None
-])
+@pytest.mark.parametrize("L", STACKS)
+@pytest.mark.parametrize("impl", ["gather", "kernel"])
 def test_int8_scale_pools(impl, L):
     """Quantized pools ride as payload+scale pairs; the dequant is
     decode_cache's exact formula, so the gather impl is bitwise the
@@ -340,8 +363,7 @@ def test_int8_scale_pools(impl, L):
         q, *pools, page_tables=tables, lengths=lengths, layer=layer,
         impl=impl,
     ), np.float32)
-    tol = 0.0 if impl == "gather" else 3e-6
-    assert np.max(np.abs(out - ref)) <= tol
+    assert np.array_equal(out, ref)
 
 
 # -- the kernel's walk: a row's live pages, a block of them a step ---------
@@ -506,47 +528,38 @@ def _workload(rng, n, p_rng=(3, 9), n_rng=(4, 12)):
     ]
 
 
-class TestPagedEngine:
-    def test_dense_vs_paged_ab_parity_and_bytes(self, long_ctx):
-        """Same seeded workload through decode_mode='dense' (the round
-        11 gather programs) and 'paged': identical token streams, and
-        the paged run's analytic decode HBM bytes/token shrink — the
-        gather tax is a recorded fact, removed."""
-        model, params = long_ctx
-        streams, engines = [], []
-        for mode in ("dense", "paged"):
-            rng = np.random.default_rng(11)
-            engine = ServeEngine(model, params, EngineConfig(
-                num_slots=4, max_len=128, prefill_chunk=4, page_size=8,
-                decode_mode=mode,
-            ))
-            hs = [engine.submit(r) for r in _workload(rng, 8)]
-            engine.run_until_drained()
-            assert all(
-                h.status is RequestStatus.COMPLETED for h in hs
-            )
-            streams.append([h.tokens for h in hs])
-            engines.append(engine)
-        assert streams[0] == streams[1]
-        dense_e, paged_e = engines
-        assert dense_e._decode_tokens == paged_e._decode_tokens > 0
-        # dense gathers [S, max_len] every tick; paged streams at most
-        # the live bucket — live lengths (< 24) sit in 2-4 of 16 pages
-        assert paged_e.decode_hbm_bytes < dense_e.decode_hbm_bytes / 3
-        assert paged_e.decode_gather_bytes < dense_e.decode_gather_bytes
-        assert (
-            paged_e.decode_hbm_bytes_per_token
-            < dense_e.decode_hbm_bytes_per_token / 3
-        )
-        # dense mode is exactly one program per kind
-        assert dense_e.decode_buckets == {dense_e.pool.max_pages}
-        assert dense_e.decode_compiles == 1
+def _serve_like_solo(engine, model, params, reqs):
+    """Every request completes with solo ``generate``'s stream."""
+    hs = [engine.submit(r) for r in reqs]
+    engine.run_until_drained()
+    for r, h in zip(reqs, hs):
+        assert h.status is RequestStatus.COMPLETED
+        assert h.tokens == _solo(model, params, r)
 
-    def test_int8_kv_cache_dense_vs_paged_ab_parity(self, monkeypatch):
+
+class TestPagedEngine:
+    def test_long_context_workload_matches_solo_generate(self, long_ctx):
+        """A seeded greedy-and-sampled workload at a ``max_len`` far
+        past the live lengths: every stream is solo ``generate``'s, and
+        each occupied bucket (2-4 of 16 pages) compiled once."""
+        model, params = long_ctx
+        rng = np.random.default_rng(11)
+        engine = ServeEngine(model, params, EngineConfig(
+            num_slots=4, max_len=128, prefill_chunk=4, page_size=8,
+        ))
+        _serve_like_solo(engine, model, params, _workload(rng, 8))
+        assert engine.decode_buckets < set(engine._buckets)
+        assert max(engine.decode_buckets) < engine.pool.max_pages
+        assert engine.decode_compiles == len(engine.decode_buckets)
+        assert all(
+            v == 1 for v in engine._decode_bucket_compiles.values()
+        )
+
+    def test_int8_kv_cache_matches_solo_generate(self, monkeypatch):
         """kv_cache_quantize='int8' rides the paged path as payload +
         scale pools (PagedKVQuant): the per-page dequant is
-        decode_cache's exact formula, so dense-mode and paged-mode
-        engines emit identical streams on the same int8 cache."""
+        decode_cache's exact formula, so the engine emits the streams
+        solo ``generate`` emits on the same int8 cache."""
         cfg = GPT2Config(
             vocab_size=97, n_positions=96, hidden_size=32,
             num_layers=2, num_heads=2, dropout_rate=0.0,
@@ -556,33 +569,28 @@ class TestPagedEngine:
         params = model.init(
             jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
         )["params"]
-        streams = []
-        for mode in ("dense", "paged"):
-            rng = np.random.default_rng(17)
-            engine = ServeEngine(model, params, EngineConfig(
-                num_slots=2, max_len=64, prefill_chunk=4, page_size=8,
-                decode_mode=mode,
-            ))
-            hs = [engine.submit(r) for r in _workload(rng, 4)]
-            engine.run_until_drained()
-            assert all(
-                h.status is RequestStatus.COMPLETED for h in hs
-            )
-            streams.append([h.tokens for h in hs])
-        assert streams[0] == streams[1]
+        rng = np.random.default_rng(17)
+        engine = ServeEngine(model, params, EngineConfig(
+            num_slots=2, max_len=64, prefill_chunk=4, page_size=8,
+        ))
+        _serve_like_solo(engine, model, params, _workload(rng, 4))
         # where the kernel is what "auto" means (a TPU), the same engine
         # is refused at construction, by name — the flag is patched
         # directly: the setter would drop every jit cache in the process
-        import sys
-
-        monkeypatch.setattr(
-            sys.modules["pytorch_distributed_tpu.ops.paged_attention"],
-            "_IMPL", "kernel",
-        )  # (the package re-exports a function under the module's name)
+        monkeypatch.setattr(_PAGED, "_IMPL", "kernel")
         with pytest.raises(ValueError, match="int8 KV cache"):
             ServeEngine(model, params, EngineConfig(
                 num_slots=2, max_len=64, prefill_chunk=4, page_size=8,
             ))
+
+    def test_engine_config_fields_are_the_ten(self):
+        """A knob taken out (a second decode path, a sleep in the loop)
+        cannot come back unnoticed."""
+        assert [f.name for f in dataclasses.fields(EngineConfig)] == [
+            "num_slots", "max_len", "prefill_chunk",
+            "prefill_chunks_per_step", "telemetry_every", "page_size",
+            "num_pages", "prefix_cache", "role", "engine_id",
+        ]
 
     def test_slot_reuse_recompiles_at_most_once_per_bucket(
         self, long_ctx
@@ -758,29 +766,6 @@ class TestPagedEngine:
         engine.run_until_drained()
         assert h2.tokens == _solo(model, params, r2)
         assert h.status is RequestStatus.COMPLETED
-
-    def test_counters_ride_armed_tracing_only(self, long_ctx):
-        """serve.decode_gather_bytes / decode_hbm_bytes_per_token land
-        on an armed tracer's counter track and in snapshot gauges."""
-        model, params = long_ctx
-        rng = np.random.default_rng(16)
-        with tracing.enabled() as t:
-            engine = ServeEngine(model, params, EngineConfig(
-                num_slots=2, max_len=64, prefill_chunk=4, page_size=8,
-                telemetry_every=2,
-            ))
-            hs = [engine.submit(r) for r in _workload(rng, 3)]
-            engine.run_until_drained()
-        assert all(h.status is RequestStatus.COMPLETED for h in hs)
-        names = {
-            e["name"] for e in t._events if e.get("ph") == "C"
-        }
-        assert "serve.decode_gather_bytes" in names
-        assert "serve.decode_hbm_bytes_per_token" in names
-        assert engine.decode_hbm_bytes_per_token > 0
-        # the default CPU impl ("gather") still pays a bucketed dense
-        # slab; the counter records it honestly
-        assert engine.decode_gather_bytes > 0
 
     def test_auto_page_size_warns_once_on_odd_max_len(self, caplog):
         reset_page_size_warnings()

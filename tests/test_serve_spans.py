@@ -284,15 +284,21 @@ def test_prefill_tier_reads_its_first_token_inside_a_span():
 
 
 @pytest.mark.parametrize("mode", ["plain", "spec"])
-def test_snapshot_keeps_the_two_read_counters_only(mode):
+def test_an_armed_run_records_no_counter_event(mode):
+    """Snapshots at every step: what a tick reads is on its span
+    (``live_pages``, ``fetched_pages``), and no ``C`` event is left."""
     eng = _engine(mode, telemetry_every=1)
     with tracing.enabled() as t:
         eng.submit(Request(np.arange(1, 7, dtype=np.int32),
                            max_new_tokens=3))
         eng.run_until_drained()
-    counters = {e["name"] for e in t._events if e["ph"] == "C"}
-    assert counters == {"serve.decode_gather_bytes",
-                        "serve.decode_hbm_bytes_per_token"}
+    assert not [e["name"] for e in t._events if e["ph"] == "C"]
+    ticks = [e for e in t._events
+             if e["name"] in ("serve.decode_tick", "serve.spec_tick")]
+    assert ticks and all(
+        0 < e["args"]["live_pages"] <= e["args"]["fetched_pages"]
+        for e in ticks
+    )
 
 
 def test_disarmed_step_records_nothing():
