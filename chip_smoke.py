@@ -168,7 +168,9 @@ def check_kernels(sizes: Sizes, on_chip: bool) -> None:
             def rand(*shape):
                 return jnp.asarray(rng.standard_normal(shape), dtype)
 
-            # -- paged decode (W=1) and speculative verify (W=5) ----------
+            # -- paged decode (W=1), speculative verify (W=5) and a prompt
+            # chunk (the kernel's query-tiled body, PR 30: W=128, and 96,
+            # no whole tile of two-heads-a-group positions) ---------------
             # the pool as it is stored: frames lane-dense, [ps, Hkv * D]
             k_pages = rand(B * n + 1, ps, Hkv * D).at[0].set(0)
             v_pages = rand(B * n + 1, ps, Hkv * D).at[0].set(0)
@@ -176,7 +178,7 @@ def check_kernels(sizes: Sizes, on_chip: bool) -> None:
                 rng.permutation(np.arange(1, B * n + 1)).reshape(B, n),
                 jnp.int32,
             )
-            for W in (1, 5):
+            for W in (1, 5, min(96, ctx // 4), min(128, ctx // 2)):
                 q = rand(B, W, Hq, D)
                 lengths = jnp.asarray(
                     rng.integers(0, ctx - W + 1, size=B), jnp.int32

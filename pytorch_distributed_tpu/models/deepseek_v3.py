@@ -27,7 +27,9 @@ the latent's width), scores ``[q~; q_r] . [c; k_r]``, values the frame's
 first ``kv_lora_rank`` lanes, ``o = (sum p c) W_uv`` — every head reads
 the one latent head, through the paged kernel in place. A prefill chunk
 DECODES the row's cached latents to keys and values and takes the dense
-path (fewer operations there).
+path (fewer operations there); a served chunk gathers them from the
+pool first, the bucket's frames (PERF.md §6, PR 30, has the timing of
+both forms over the pool).
 
 **FFN.** Dense SwiGLU in the first ``first_k_dense`` layers, which are
 unrolled AHEAD of the scanned expert stack (``models/scan.py`` scans
@@ -211,6 +213,8 @@ class MLAttention(nn.Module):
         else:
             from pytorch_distributed_tpu.ops.paged_attention import (
                 active_view,
+                gathered_rows,
+                is_chunk,
             )
 
             F = cfg.latent_frame
@@ -222,7 +226,16 @@ class MLAttention(nn.Module):
                 self, frame, cache_len or cfg.max_seq_len, r,
                 write_pos=write_pos,
             )
-            if S == 1 or active_view() is not None:
+            paged = active_view() is not None
+            if paged and is_chunk(S):
+                # a served chunk: the row's bucket of frames out of the
+                # pool, then the dense path's own arithmetic
+                with gathered_rows(k_all) as rows:          # [B, T, F]
+                    out = decoded(
+                        rows[..., :r], rows[:, :, None, r:r + dr],
+                        q_offset=offset,
+                    )
+            elif S == 1 or paged:
                 # absorbed: every head reads the one latent head
                 q_abs = jnp.einsum("bshd,rhd->bshr", q_n, w_uk)
                 q_cat = jnp.concatenate([

@@ -1,5 +1,6 @@
-"""Paged-attention decode: attention streams K/V straight from the page
-pool — the compute-side completion of the paged KV pool (serve/kv_slots).
+"""Paged attention: a tick, a speculative verify and a prompt chunk
+attend over K/V where the page pool holds it — the compute-side
+completion of the paged KV pool (serve/kv_slots).
 
 PR 11 made the PAGE the allocation unit but left the compute contract
 dense: every decode tick gathered the live slots' pages into a transient
@@ -16,10 +17,11 @@ online-softmax machinery (ops/flash_attention.py):
   are scanned), per-request page tables ``[B, n_pages]`` and per-row
   lengths, and computes ``[B, W, Hq, D]`` attention for W queries per
   row (W = 1 for the decode tick, W = k+1 for the fused speculative
-  verify) with ragged lengths masked INSIDE the op — no caller-side
-  dense view;
+  verify, W = the chunk for a prompt chunk's one row) with ragged
+  lengths masked INSIDE the op — no caller-side dense view;
 * the engine installs a :class:`PagedView` (the adapter object) around
-  its jitted decode programs; ``ops.attention.decode_cache`` writes new
+  its jitted decode and prefill programs;
+  ``ops.attention.decode_cache`` writes new
   K/V through :func:`paged_write` (a per-page scatter of only the W
   deliberately-written positions — dropped entirely for inactive rows)
   and ``ops.attention.attention`` dispatches here — so ``models/``
@@ -62,6 +64,22 @@ Two implementations, selected by :func:`set_paged_attention_impl`
   masked scores weigh by an exact 0.0, so no page past a row's length
   is ever read. The online-softmax carry lives in VMEM scratch.
   ``interpret=True`` off-TPU, like every Pallas kernel in this repo.
+  That body is a tick's and a verify's: a handful of queries a row. A
+  call of ``_CHUNK_QUERIES`` queries a row or more over a K and a V
+  pool (a prompt chunk) takes the QUERY-TILED body instead, told from
+  the call's shapes alone (``_paged_kernel_call``): the same walk
+  — the row's own blocks of live pages through the prefetched table,
+  copied by hand from the pool where it lies, double-buffered — with
+  the grid over tiles of ``query_tiles`` positions; a tile walks the
+  blocks up to its own last query, multiplies each fetched block one
+  128-lane group of heads at a time (one head of 128, two of 64 as a
+  block-diagonal pair) against that group's tile of query rows, and
+  compares positions only in the blocks its own queries fall in (or a
+  window's edge crosses). No bucket-wide array and no score matrix
+  reaches HBM. Its op is named ``paged_prefill``:
+  the ticks' roofline readers sum the device time of every op named
+  ``paged_attention``. (A latent frame's chunk does not come here:
+  ``MLAttention`` decodes the row's frames, :func:`gathered_rows`.)
 
 ``"auto"`` resolves to ``"kernel"`` on TPU and ``"gather"`` elsewhere:
 the gather impl is the provably-exact CPU/CI path, and on the chip the
@@ -97,6 +115,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -166,6 +185,39 @@ def paged_layer(layer):
     """Inside a layer loop's body (models/scan.py): the active view
     with ``layer`` naming this iteration's plane of the stacked leaves."""
     return paged_view(dataclasses.replace(_VIEW, layer=layer))
+
+
+# a call of this many queries a row or more is a prompt chunk; under it
+# a tick's one and a speculative verify's k + 1. 16: the least tile of
+# positions the many-query body cuts for every group size
+_CHUNK_QUERIES = 16
+
+
+def is_chunk(w: int) -> bool:
+    """Whether a call of ``w`` queries a row is a prompt chunk (the
+    kernel's query-tiled body) and not a tick or a speculative verify
+    (its block-diagonal one)."""
+    return w >= _CHUNK_QUERIES
+
+
+@contextlib.contextmanager
+def gathered_rows(pages):
+    """The active view's rows of a pool leaf, dense — ``[B, n_pages *
+    page_size, F]``, one gather through the bucket-sliced tables (plane
+    ``layer`` of a stacked leaf), what the ``"gather"`` impl reads —
+    with the view lifted while the caller attends over them. For a
+    caller whose attention over the cached positions is not the pool's
+    own form: a latent chunk decodes its latents
+    (``models/deepseek_v3.py``)."""
+    global _VIEW
+    view = _VIEW
+    B, n = view.page_tables.shape
+    out = pages[_plane(view.layer, view.page_tables.reshape(-1))]
+    _VIEW = None
+    try:
+        yield out.reshape(B, n * out.shape[1], out.shape[2])
+    finally:
+        _VIEW = view
 
 
 class PagedKVQuant(NamedTuple):
@@ -731,6 +783,339 @@ def _kernel_body(lengths_ref, pages_ref, next_ref, tables_ref, *refs,
     jax.lax.fori_loop(0, group, row, 0)
 
 
+# --------------------------------------------------------------------------
+# "kernel", many queries a row: the same walk, tiles of query rows
+# --------------------------------------------------------------------------
+
+# query rows a tile multiplies a fetched block by, a lane group: the
+# table of scripts/paged_kernel_bench.py --chunks (PERF.md §6, PR 30)
+# chose it
+_TILE_ROWS = 1024
+
+
+def query_tiles(w: int, g: int, hkv: int, d: int):
+    """How the many-query body cuts a call, from its shapes: ``(hpg,
+    tq, nt)``. ``hpg`` kv heads share a lane group — whole 128-lane
+    tiles of a frame: two heads of 64, one of 128 — ``tq`` query
+    positions make a tile (about ``_TILE_ROWS`` rows of a lane group,
+    whole sublane tiles, never more than the call has) and ``nt`` tiles
+    cover the ``w`` queries."""
+    hpg = max(
+        (h for h in range(1, hkv + 1) if hkv % h == 0 and h * d <= 128),
+        default=1,
+    )
+    whole = 16 // math.gcd(g, 16)                # tq * g: whole tiles
+    tq = max(min(_TILE_ROWS // (hpg * g), w), 1)
+    tq = -(-tq // whole) * whole
+    return hpg, tq, -(-w // tq)
+
+
+def tile_walk(lengths, w: int, tq: int, page_size: int, n_pages: int,
+              k: int):
+    """``row_walk`` for each query tile of rows of ``lengths`` cached
+    tokens and ``w`` queries cut ``tq`` a tile: ``(pages, blocks)``,
+    each ``[B, tiles]`` — the pages tile ``t``'s last query reaches (a
+    tile walks no block past its own causal reach, and none past the
+    row's) and its blocks of them. A row's prefix is fetched once a
+    tile."""
+    seen = np.minimum((np.arange(-(-w // tq), dtype=np.int32) + 1) * tq, w)
+    return row_walk(lengths[:, None] + seen[None, :], 0, page_size, n_pages, k)
+
+
+def _lanes(x, n: int):
+    """``x`` (``[rows, 128]``, a row's lanes all alike) at ``n`` lanes:
+    whole tiles side by side, no move across lanes, where ``n`` is a
+    multiple of 128."""
+    if n % 128:
+        return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+    return x if n == 128 else jnp.concatenate([x] * (n // 128), axis=1)
+
+
+def _tiled_body(lengths_ref, pages_ref, next_ref, tables_ref, *refs,
+                sm_scale, page_size, k, hkv, hpg, g, tq, d, dv, window,
+                stacked):
+    # the operands of ``_kernel_body`` over a K and a V pool; one grid
+    # step is one TILE of one row's queries, ``pages_ref`` the pages
+    # that tile's last query sees
+    refs = list(refs)
+    layer_ref = refs.pop(0) if stacked else None
+    q_ref, *pools, o_ref, k_buf, v_buf = refs[:6]
+    sems, slot_ref, *packed, acc_ref, m_ref, l_ref = refs[6:]
+    bufs = (k_buf, v_buf)
+    rows = q_ref.shape[2]                          # tq * g, a kv head
+    n_lg = hkv // hpg
+    b, t = pl.program_id(0), pl.program_id(1)
+    n_rows, nt = pl.num_programs(0), pl.num_programs(1)
+    kps = k * page_size
+
+    def copies(row, tile, blk, slot, wait):
+        """``_kernel_body``'s: the live page frames of block ``blk`` of
+        what tile ``tile`` of ``row`` sees, to buffer ``slot``."""
+        first = blk * k
+
+        def page(j, carry):
+            frame = tables_ref[row, first + j]
+            at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for p, (pool, buf) in enumerate(zip(pools, bufs)):
+                src = (
+                    pool.at[layer_ref[0], frame] if stacked
+                    else pool.at[frame]
+                )
+                copy = pltpu.make_async_copy(
+                    src, buf.at[slot, at], sems.at[p, slot]
+                )
+                copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(k, pages_ref[row * nt + tile] - first), page, 0
+        )
+
+    @pl.when(jnp.logical_and(b == 0, t == 0))
+    def _prologue():
+        # finite for good, as in ``_kernel_body``: a block's unfetched
+        # tail is weighed by an exact 0.0
+        def fill(j, carry):
+            at = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for buf in bufs:
+                for slot in range(2):
+                    buf[slot, at, :] = jnp.zeros(
+                        (page_size, buf.shape[2]), buf.dtype
+                    )
+            return carry
+
+        jax.lax.fori_loop(0, k, fill, 0)
+        for ref in packed:  # the zeros between a lane group's heads
+            ref[:] = jnp.zeros_like(ref)
+        slot_ref[0] = 0
+
+        @pl.when(next_ref[0] < n_rows)
+        def _first():
+            copies(next_ref[0], 0, 0, 0, wait=False)
+
+    n_blocks = -(-pages_ref[b * nt + t] // k)
+
+    @pl.when(n_blocks == 0)
+    def _not_decoding():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when(n_blocks > 0)
+    def _walk():
+        if packed:
+            # a lane group's heads as one block-diagonal operand: head
+            # a's rows hold its queries in the lanes of its keys
+            for h in range(hkv):
+                a = h % hpg
+                packed[0][h // hpg, a * rows:(a + 1) * rows,
+                          a * d:(a + 1) * d] = q_ref[0, h]
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        q0 = lengths_ref[b] + t * tq               # the tile's first query
+        # what runs after this tile: the row's next, or the first tile
+        # of the next row that has any page
+        last_tile = t + 1 == nt
+        nrow = jnp.where(last_tile, next_ref[b + 1], b)
+        ntile = jnp.where(last_tile, 0, t + 1)
+
+        def product(slot, keep):
+            """One fetched block against every lane group's tile of
+            queries; ``keep`` None where no score of it is masked."""
+            for lg in range(n_lg):
+                qg = packed[0][lg] if packed else q_ref[0, lg]
+                s = _mxu_dot(
+                    qg, k_buf[slot, :, lg * hpg * d:(lg + 1) * hpg * d], 1, 1
+                ) * sm_scale                        # [hpg * rows, kps]
+                if keep is not None:
+                    s = jnp.where(keep, s, _NEG_INF)
+                # the running max and sum stay replicated over their
+                # 128 lanes from block to block: a row's one value a
+                # vreg is what a tile of this many rows cannot afford
+                # (512 rows against a block of 512 keys read 4.1 us
+                # that way and 2.6 this, PERF.md §6, PR 30)
+                m_prev = m_ref[lg]
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(s, axis=-1, keepdims=True)
+                )
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(s - _lanes(m_new, kps))
+                l_ref[lg] = l_ref[lg] * alpha + jnp.sum(
+                    p, axis=-1, keepdims=True
+                )
+                v = v_buf[slot, :, lg * hpg * dv:(lg + 1) * hpg * dv]
+                acc_ref[lg] = acc_ref[lg] * _lanes(
+                    alpha, hpg * dv
+                ) + _mxu_dot(p.astype(v.dtype), v, 1, 0)
+                m_ref[lg] = m_new
+
+        def block(i, slot):
+            last = i + 1 == n_blocks
+
+            @pl.when(jnp.logical_not(last))
+            def _next_block():
+                copies(b, t, i + 1, 1 - slot, wait=False)
+
+            @pl.when(jnp.logical_and(last, nrow < n_rows))
+            def _next_tile():
+                copies(nrow, ntile, 0, 1 - slot, wait=False)
+
+            copies(b, t, i, slot, wait=True)
+            # key ``col`` of the block against query ``j`` of the tile:
+            # seen iff col - j <= off, and inside the window iff
+            # col - j > off - window
+            off = q0 - i * kps
+            plain = off >= kps - 1      # every key at or before query 0
+            if window is not None:      # and the last query's band holds
+                plain = jnp.logical_and(plain, off + tq - 1 < window)
+
+            @pl.when(plain)
+            def _unmasked():
+                product(slot, None)
+
+            @pl.when(jnp.logical_not(plain))
+            def _masked():
+                # rows are ordered (head of the group, query j, group
+                # member): row r of a head is query r // g
+                j = jax.lax.broadcasted_iota(jnp.int32, (rows, kps), 0)
+                if g > 1 and (g & (g - 1)) == 0:
+                    j = jnp.right_shift(j, g.bit_length() - 1)
+                elif g > 1:  # Mosaic has no vector integer divide
+                    j = ((j.astype(jnp.float32) + 0.5) * (1.0 / g)).astype(
+                        jnp.int32
+                    )
+                if hpg > 1:
+                    j = jnp.concatenate([j] * hpg, axis=0)
+                gap = jax.lax.broadcasted_iota(jnp.int32, j.shape, 1) - j
+                keep = gap <= off
+                if window is not None:
+                    keep = jnp.logical_and(keep, gap > off - window)
+                product(slot, keep)
+
+            return 1 - slot
+
+        slot_ref[0] = jax.lax.fori_loop(0, n_blocks, block, slot_ref[0])
+        for lg in range(n_lg):
+            l = l_ref[lg]
+            safe = jnp.where(l > 0, l, 1.0)
+            for a in range(hpg):  # head a's own lanes of its own rows
+                at = slice(a * rows, (a + 1) * rows)
+                o_ref[0, lg * hpg + a] = (
+                    acc_ref[lg, at, a * dv:(a + 1) * dv]
+                    / _lanes(safe[at], dv)
+                ).astype(o_ref.dtype)
+
+
+def _paged_tiled_call(q, k_pages, v_pages, tables, lengths, keep, layer,
+                      scale, window, dv):
+    B, W, Hq, D = q.shape
+    ps, F = k_pages.shape[-2:]
+    Hkv = F // D
+    G = Hq // Hkv
+    n = tables.shape[1]
+    pools = (k_pages, v_pages)
+    k = block_pages(ps, F * k_pages.dtype.itemsize, n)
+    hpg, tq, nt = query_tiles(W, G, Hkv, D)
+    rows = tq * G
+    pages = tile_walk(lengths.astype(jnp.int32), W, tq, ps, n, k)[0]
+    if keep is not None:
+        pages = jnp.where(keep[:, None], pages, 0)
+    scalars, qf = _kernel_operands(
+        q, Hkv, nt * rows, tables, lengths, pages, layer
+    )
+
+    def tile_spec(width):
+        return pl.BlockSpec(
+            (1, Hkv, rows, width), lambda b, t, *_: (b, 0, t, 0)
+        )
+
+    n_lg = Hkv // hpg
+    scratch = [
+        pltpu.VMEM((2, k * ps, p.shape[-1]), p.dtype) for p in pools
+    ] + [
+        pltpu.VMEM((n_lg, hpg * rows, hpg * D), q.dtype)
+    ] * (hpg > 1) + [                                   # heads side by side
+        pltpu.VMEM((n_lg, hpg * rows, hpg * dv), jnp.float32),  # acc
+        pltpu.VMEM((n_lg, hpg * rows, 128), jnp.float32),  # running max
+        pltpu.VMEM((n_lg, hpg * rows, 128), jnp.float32),  # running sum
+    ]
+    # what the kernel holds in VMEM: its scratch, the query and output
+    # tiles (each double-buffered), and a lane group's scores and
+    # weights in f32; as much again for what the compiler keeps besides
+    # (31 MB of 63 for a Mistral chunk), and never under the
+    # compiler's own default of 16 MiB
+    held = sum(
+        math.prod(ref.shape) * jnp.dtype(ref.dtype).itemsize
+        for ref in scratch
+    ) + 2 * Hkv * rows * (D + dv) * q.dtype.itemsize + (
+        3 * hpg * rows * k * ps * 4
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(B, nt),
+        in_specs=[tile_spec(D)] + [
+            pl.BlockSpec(memory_space=pl.ANY) for _ in pools
+        ],
+        out_specs=tile_spec(dv),
+        scratch_shapes=scratch[:2] + [
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),                # the buffer in turn
+        ] + scratch[2:],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _tiled_body, sm_scale=scale, page_size=ps, k=k, hkv=Hkv,
+            hpg=hpg, g=G, tq=tq, d=D, dv=dv, window=window,
+            stacked=layer is not None,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, nt * rows, dv), q.dtype),
+        # sequential: a tile's last block starts the next tile's first
+        # fetch, and the buffer in turn passes from tile to tile
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(2 * held, 16 << 20),
+        ),
+        interpret=_interpret(),
+        # its own name: the ticks' roofline readers sum the device time
+        # of every op named for the tick's kernel
+        name="paged_prefill",
+    )(*scalars, qf, *pools)
+    return _by_query(out, W, Hq)
+
+
+def _kernel_operands(q, hkv, rows, tables, lengths, pages, layer):
+    """What either body is handed ahead of the pools: the prefetched
+    scalars — lengths, the pages each walk reaches (``pages``: ``[B]``,
+    a row's, or ``[B, tiles]``, each tile's), ``next_row[b]`` the first
+    row at or after ``b`` with a page (``B``: none), the tables, and a
+    stacked pool's plane — and the queries as ``[B, Hkv, rows, D]``, a
+    kv head's ``W * G`` rows (query j, group member) zero-padded."""
+    B, W, Hq, D = q.shape
+    first = pages.reshape(B, -1)[:, 0]
+    rows_with = jnp.where(first > 0, jnp.arange(B, dtype=jnp.int32), B)
+    next_row = jnp.concatenate([
+        jax.lax.cummin(rows_with, reverse=True),
+        jnp.full((1,), B, jnp.int32),
+    ])
+    qf = q.reshape(B, W, hkv, Hq // hkv, D).transpose(0, 2, 1, 3, 4)
+    qf = qf.reshape(B, hkv, W * Hq // hkv, D)
+    qf = jnp.pad(qf, ((0, 0), (0, 0), (0, rows - qf.shape[2]), (0, 0)))
+    scalars = (
+        lengths.astype(jnp.int32), pages.reshape(-1), next_row,
+        tables.astype(jnp.int32),
+    )
+    if layer is not None:
+        scalars += (jnp.asarray(layer, jnp.int32).reshape(1),)
+    return scalars, qf
+
+
+def _by_query(out, w, hq):
+    """A body's ``[B, Hkv, rows, dv]`` back as ``[B, W, Hq, dv]``."""
+    B, hkv, _, dv = out.shape
+    out = out[:, :, :w * hq // hkv].reshape(B, hkv, w, hq // hkv, dv)
+    return out.transpose(0, 2, 1, 3, 4).reshape(B, w, hq, dv)
+
+
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
@@ -742,6 +1127,12 @@ def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, keep, layer,
     Hkv = F // D
     G = Hq // Hkv
     n = tables.shape[1]
+    if is_chunk(W) and v_pages is not None:
+        # a prompt chunk over a K and a V pool: tiles of its queries
+        return _paged_tiled_call(
+            q, k_pages, v_pages, tables, lengths, keep, layer, scale,
+            window, dv,
+        )
     pools = (k_pages,) if v_pages is None else (k_pages, v_pages)
     k = block_pages(ps, F * k_pages.dtype.itemsize, n)
     # the grid is the rows; a row's steps are its own blocks, counted
@@ -750,23 +1141,11 @@ def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, keep, layer,
     pages, _ = row_walk(lengths.astype(jnp.int32), W, ps, n, k)
     if keep is not None:
         pages = jnp.where(keep, pages, 0)
-    # next_row[b]: the first row at or after b with a page (B: none)
-    rows_with = jnp.where(pages > 0, jnp.arange(B, dtype=jnp.int32), B)
-    next_row = jnp.concatenate([
-        jax.lax.cummin(rows_with, reverse=True),
-        jnp.full((1,), B, jnp.int32),
-    ])
-    # Queries go [B, Hkv, W * G, D], rows zero-padded to the sublane tile
+    # the rows of a kv head's queries, zero-padded to the sublane tile
     rows = -(-W * G // 8) * 8
-    qf = q.reshape(B, W, Hkv, G, D).transpose(0, 2, 1, 3, 4)
-    qf = qf.reshape(B, Hkv, W * G, D)
-    qf = jnp.pad(qf, ((0, 0), (0, 0), (0, rows - W * G), (0, 0)))
-
-    scalars = (
-        lengths.astype(jnp.int32), pages, next_row, tables.astype(jnp.int32)
+    scalars, qf = _kernel_operands(
+        q, Hkv, rows, tables, lengths, pages, layer
     )
-    if layer is not None:
-        scalars += (jnp.asarray(layer, jnp.int32).reshape(1),)
 
     # a grid step serves a group of rows (a row alone costs a step's
     # fixed price, which a slot that is not decoding would pay too)
@@ -813,5 +1192,4 @@ def _paged_kernel_call(q, k_pages, v_pages, tables, lengths, keep, layer,
         interpret=_interpret(),
         name="paged_attention",
     )(*scalars, qf, *pools)
-    out = out[:, :, :W * G].reshape(B, Hkv, W, G, dv)
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, W, Hq, dv)
+    return _by_query(out, W, Hq)

@@ -7,10 +7,13 @@ for the engine's lifetime — the bounded-compile-count invariant, pinned
 by tests:
 
 * ``prefill``: one ``[1, prefill_chunk]`` model pass writing a chunk of
-  one request's prompt into its pages (gather pages -> dense row ->
-  ``write_pos`` chunk write -> scatter the chunk back), sampling the
-  first token on the final chunk. With speculation enabled the SAME
-  program also prefills the draft model's pages — still one program.
+  one request's prompt into its pages and attending over them where
+  they lie, as the tick does: a ``PagedView`` of the slot's one table
+  row around the apply, the pool as its cache, the chunk's positions
+  scattered into the donated leaves and the kernel's query-tiled body
+  walking the row's pages up to the chunk's end. Samples the first
+  token on the final chunk. With speculation enabled the SAME program
+  also prefills the draft model's pages — still one program.
 * ``decode``: one ``[S, 1]`` tick over ALL slots through the same
   ``generation.decode_step_body`` the offline ``generate`` scan uses —
   attending IN PLACE over the page pool (``ops/paged_attention``: the
@@ -19,9 +22,9 @@ by tests:
   positions, and attention streams the pages — no transient
   ``[S, max_len]`` dense view). Free / mid-prefill rows never write —
   the per-page write drops their rows.
-* **length buckets** bound what the remaining dense spans (chunked
-  prefill's per-slot row, the speculative draft's short context) and
-  the paged streams actually touch: widths round up to the live
+* **length buckets** bound what the remaining dense span (the
+  speculative draft's short context) and the paged streams' tables
+  actually touch: widths round up to the live
   maximum's power-of-two page bucket instead of always ``max_len``,
   with the bucket width a STATIC jit argument — at most one program
   per occupied bucket (<= log2(max_pages) + 1 decode programs, each
@@ -81,10 +84,12 @@ from pytorch_distributed_tpu.serve.disagg import (
 from pytorch_distributed_tpu.ops.paged_attention import (
     PagedView,
     block_pages,
+    is_chunk,
     paged_view,
+    query_tiles,
     refuse_kernel_for,
     resolve_paged_attention_impl,
-    row_walk,
+    tile_walk,
 )
 from pytorch_distributed_tpu.ops.moe import collect_route_stats
 from pytorch_distributed_tpu.runtime import faults
@@ -94,6 +99,7 @@ from pytorch_distributed_tpu.serve.kv_slots import (
     extract_frames,
     frame_signature,
     gather_pages,
+    kv_frame_width,
     scatter_kv,
     splice_frames,
     token_nbytes,
@@ -463,34 +469,37 @@ class ServeEngine:
     def _prefill_chunk_body(self, model, params, pool, cache, pt, ids,
                             slot, start, n_pages):
         """One model's chunk prefill over its page pool (``pool`` owns
-        ``cache``): gather the slot's pages — only the leading
-        ``n_pages`` bucket the chunk can reach, not the full
-        ``max_len`` span — to a dense row, run the ``[1, C]`` chunk
-        write, and scatter exactly the chunk's positions back (padded
+        ``cache``), attending where the pool lies, as the tick does: a
+        ``PagedView`` of the slot's one table row — only the leading
+        ``n_pages`` bucket the chunk can reach — around the ``[1, C]``
+        apply, the POOL as its cache. ``decode_cache`` scatters the
+        chunk's ``C`` positions into the donated leaves (padded
         final-chunk positions included — they stay inside the slot's
         reserved private span and are overwritten or masked, as
-        before). The gather reads what the chunk attends to and the
-        scatter writes ``C`` positions into the donated pool; nothing
-        else of the pool moves. Returns (chunk logits, updated pool)."""
+        before), attention walks the row's pages up to ``start + C``
+        (``ops.paged_attention``: the query-tiled body of the kernel;
+        under ``"gather"`` the bucket's slab and the dense math; a
+        latent leaf's chunk gathers the bucket's frames and decodes
+        them, ``models/deepseek_v3.py``), and the leaves ride the layer
+        loop as its carry; nothing else of the pool moves. Returns
+        (chunk logits, updated pool)."""
         C = self.config.prefill_chunk
         row_pt = jax.lax.dynamic_slice_in_dim(pt, slot, 1, axis=0)
         row_pt = jax.lax.slice_in_dim(row_pt, 0, n_pages, axis=1)
-        row = gather_pages(cache, row_pt, pool.tails)
-        positions = (start + jnp.arange(C))[None, :]
-        logits, state = model.apply(
-            {"params": params, "cache": row},
-            ids,
-            decode=True,
-            cache_len=n_pages * self.pool.page_size,
-            mutable=["cache", "intermediates"],
-            positions=positions,
-            write_pos=jnp.asarray(start, jnp.int32)[None],
-        )
-        cache = scatter_kv(
-            cache, state["cache"], row_pt, positions,
-            jnp.ones((1, C), bool),
-        )
-        return logits, cache, collect_route_stats(
+        with paged_view(PagedView(
+            page_tables=row_pt, keep=jnp.ones((1,), bool),
+            page_size=pool.page_size,
+        )):
+            logits, state = model.apply(
+                {"params": params, "cache": cache},
+                ids,
+                decode=True,
+                cache_len=self.config.max_len,
+                mutable=["cache", "intermediates"],
+                positions=(start + jnp.arange(C))[None, :],
+                write_pos=jnp.asarray(start, jnp.int32)[None],
+            )
+        return logits, state["cache"], collect_route_stats(
             state.get("intermediates", {})
         )
 
@@ -1305,6 +1314,10 @@ class ServeEngine:
                 else tracing.span(
                     "serve.prefill_chunk", request=h.request.request_id,
                     n_pages=n_pages, start=plan.start, final=plan.final,
+                    # the one row's walk: pages up to start + C
+                    **self._walk_pages(
+                        [plan.start], cfg.prefill_chunk, n_pages, n_pages
+                    ),
                 )
             )
             with span:
@@ -1392,26 +1405,54 @@ class ServeEngine:
             span.set(**_route_args(tok, 1))
         self._route_pending.clear()
 
-    def _tick_pages(self, decoding, n_pages) -> dict:
-        """A tick span's page counts. ``live_pages``: for each decoding
-        row, the pages its length and the tick's write span reach.
-        ``fetched_pages``: the pages the tick's attention goes over for
-        them — the kernel's blocks of the live pages, whole (it copies
-        a block's live pages only; its products span the block), by the
-        kernel's own arithmetic; the ``gather`` impl gathers every
-        slot's bucket."""
-        W = 1 if self.spec is None else self.spec.num_draft_tokens + 1
+    def _query_tile(self, w) -> Optional[int]:
+        """How a call's attention cuts its ``w`` queries a row
+        (``ops.paged_attention``): one walk for a tick's and a
+        verify's; the kernel's tiles for a chunk's over a K/V pool,
+        sized by the heads the model shows; None where the call gathers
+        its bucket — the ``gather`` impl, and a latent pool's chunk
+        (``MLAttention`` decodes the row's frames)."""
+        width = kv_frame_width(self.pool.cache)
+        if self._resolved_impl != "kernel" or (
+            width is None and is_chunk(w)
+        ):
+            return None
+        if not is_chunk(w):
+            return w
+        hq = self.model.config.num_heads
+        hkv = getattr(self.model.config, "num_kv_heads", hq)
+        return query_tiles(w, hq // hkv, hkv, width // hkv)[1]
+
+    def _walk_pages(self, lengths, w, n_pages, gathered) -> dict:
+        """A dispatch span's page counts, for rows of ``lengths`` cached
+        tokens and ``w`` queries each. ``live_pages``: the pages the
+        rows' lengths and write spans reach. ``fetched_pages``: the
+        pages the attention goes over for them — the kernel's blocks of
+        the live pages, whole (it copies a block's live pages only; its
+        products span the block), once a tile of a chunk's queries (a
+        tile fetches the row's prefix anew), by the kernel's own
+        arithmetic; the ``gather`` impl gathers the bucket of every row
+        it is handed, ``gathered`` pages, and so does a latent pool's
+        chunk."""
         ps = self.pool.page_size
-        lengths = np.asarray(
-            [self.pool.lengths[slot] for slot, _ in decoding], np.int64
-        )
         k = block_pages(ps, self._token_bytes, n_pages)
-        pages, blocks = row_walk(lengths, W, ps, n_pages, k)
-        fetched = (
-            int(blocks.sum()) * k if self._resolved_impl == "kernel"
-            else self.config.num_slots * n_pages
+        tq = self._query_tile(w)
+        pages, blocks = tile_walk(
+            np.asarray(lengths, np.int64), w, tq or w, ps, n_pages, k
         )
-        return {"live_pages": int(pages.sum()), "fetched_pages": fetched}
+        return {
+            "live_pages": int(pages[:, -1].sum()),
+            "fetched_pages": gathered if tq is None else int(blocks.sum()) * k,
+        }
+
+    def _tick_pages(self, decoding, n_pages) -> dict:
+        """A tick span's page counts: every decoding row's walk; the
+        ``gather`` impl gathers every slot's bucket."""
+        return self._walk_pages(
+            [self.pool.lengths[slot] for slot, _ in decoding],
+            1 if self.spec is None else self.spec.num_draft_tokens + 1,
+            n_pages, self.config.num_slots * n_pages,
+        )
 
     def _run_decode(self) -> int:
         """One decode tick over the decoding rows; returns how many

@@ -46,13 +46,13 @@ tokens, same absolute positions, same deterministic program — so the
 gathered dense view is bitwise what the unshared engine computed, and
 the solo-``generate`` parity suite holds with sharing on.
 
-Where a dense view is still made: the decode tick and the speculative
-verify attend IN PLACE over the pool (``ops/paged_attention``) —
-new-token K/V lands via per-page scatters and attention reads the pages.
-The gather helpers below (``gather_pages`` / ``scatter_kv``) serve the
-two dense spans that remain, chunked prefill's one row and the
-speculative draft's short context, both bucket-sliced to the live
-maximum's power-of-two page width, never ``max_len``. Resident KV is
+Where a dense view is still made: the decode tick, the speculative
+verify and a prompt chunk attend IN PLACE over the pool
+(``ops/paged_attention``) — new K/V lands via per-page scatters and
+attention reads the pages. The gather helpers below (``gather_pages`` /
+``scatter_kv``) serve the one dense span that remains, the speculative
+draft's short context, bucket-sliced to the live maximum's
+power-of-two page width, never ``max_len``. Resident KV is
 ``pages_in_use × page_size`` (``serving_kv_bytes_ratio`` >= 2x pinned by
 test_bench_contract).
 """
@@ -226,8 +226,9 @@ def scatter_kv(cache, dense, page_tables, positions, keep):
     admission, and ``PagedKVPool.check_consistency`` + the shared-page
     checksum test pin it.
 
-    Callers are the engine's jitted programs only (prefill chunk, dense
-    decode tick, the speculative draft). ONE scatter per leaf along the
+    The caller is the engine's jitted speculative tick only (its draft;
+    a prompt chunk and every tick write through
+    ``ops.paged_attention.paged_write``). ONE scatter per leaf along the
     leaf's own page and row axes, the leaf where it lies: the compiled
     program writes ``B * W`` positions into the donated pool and reads
     or moves nothing else of it. (Moving the page axis to the front
@@ -307,6 +308,16 @@ def token_nbytes(cache) -> int:
         leaf.shape[-1] * leaf.dtype.itemsize
         for _, _, leaf in _frame_leaves(cache)
     )
+
+
+def kv_frame_width(cache) -> Optional[int]:
+    """Elements of ONE token in a K leaf of a K/V pool (``Hkv * D``);
+    None for a latent pool (``cached_latent``: one frame a token, no
+    heads of its own), whose prompt chunks gather their bucket."""
+    leaves = {name: leaf for name, _, leaf in _frame_leaves(cache)}
+    if "cached_latent" in leaves:
+        return None
+    return leaves["cached_key"].shape[-1]
 
 
 def frame_f32_nbytes(cache) -> int:

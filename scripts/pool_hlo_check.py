@@ -11,7 +11,17 @@ be a parameter, a tuple or its element, a bitcast, the layer loop
 ``transpose``, ``reshape``, ``slice``, ``dynamic-slice``,
 ``dynamic-update-slice`` or an allocated buffer of that size is a pass
 over the pool that a tick or a chunk would pay for, and is listed. The
-pool parameters must be aliased to the pool results.
+pool parameters must be aliased to the pool results. Of the chunk
+program (``_prefill_fn``) it asks three things more, since a chunk
+attends over the pool where it lies (PR 30): no ``gather`` of the row's
+bucket (whole frames, at least the bucket's positions times the narrowest
+frame), no score matrix in HBM (an f32 result of four or more dimensions
+with the chunk's queries and the bucket's positions among them), and ONE
+scatter a pool leaf (the chunk's positions, written where the leaf lies).
+The programs are compiled at the cell's WIDEST bucket. A LATENT pool's
+chunk is asked the third alone: it writes where the leaf lies, and
+decodes a gathered bucket of latents by design, as before PR 30
+(``models/deepseek_v3.py``).
 
     python scripts/pool_hlo_check.py                  # on the chip
     python scripts/pool_hlo_check.py --describe v5e:2x2   # anywhere
@@ -28,6 +38,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import re
 import sys
@@ -79,6 +90,45 @@ def pool_passes(hlo_text: str, frames: int, floor: int):
     return out
 
 
+def chunk_faults(hlo_text: str, frames: int, floor: int, chunk: dict):
+    """What a compiled chunk program may not hold (module docstring):
+    ``chunk`` gives its ``queries``, the bucket's ``positions``, the
+    leaves' ``frames`` (``[page_size, width]`` each), the
+    ``bucket_elements`` a gather of whole frames of the narrowest
+    leaf's bucket would have, the pool's ``leaves``, and whether it
+    attends ``in_place`` (a latent pool's does not)."""
+    out, scatters = [], 0
+    C, T = chunk["queries"], chunk["positions"]
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        shapes = [
+            [int(d) for d in dims.split(",") if d]
+            for dims in _SHAPE.findall(m["type"])
+        ]
+        sizes = [math.prod(dims) for dims in shapes]
+        if m["op"] == "scatter" and pool_sized(m["type"], frames, floor):
+            scatters += 1
+        if not chunk["in_place"]:
+            continue
+        if m["op"] == "gather" and any(
+            dims[-2:] in chunk["frames"] and n >= chunk["bucket_elements"]
+            for dims, n in zip(shapes, sizes)
+        ):
+            out.append((m["name"], "a gather of the bucket", m["type"]))
+        if m["type"].lstrip("(").startswith("f32") and any(
+            len(dims) >= 4 and C in dims and T in dims for dims in shapes
+        ):
+            out.append((m["name"], "a score matrix", m["type"].strip()))
+    if scatters != chunk["leaves"]:
+        out.append((
+            "scatter", f"{scatters} pool-sized scatters for "
+            f"{chunk['leaves']} pool leaves", "",
+        ))
+    return out
+
+
 def aliased_outputs(hlo_text: str):
     """``{output index: parameter number}`` from the module header."""
     head = hlo_text.split("\n", 1)[0]
@@ -93,9 +143,11 @@ def entry_parameters(hlo_text: str):
     return re.findall(r"([\w.]+): ", m.group(1)) if m else []
 
 
-def check_program(name, compiled, frames, floor, n_leaves):
+def check_program(name, compiled, frames, floor, n_leaves, chunk=None):
     text = compiled.as_text()
     passes = pool_passes(text, frames, floor)
+    if chunk is not None:
+        passes += chunk_faults(text, frames, floor, chunk)
     params = entry_parameters(text)
     alias = aliased_outputs(text)
     pool_params = [
@@ -122,7 +174,8 @@ def check_program(name, compiled, frames, floor, n_leaves):
 
 def compile_cell(cell_name, sharding):
     """Compile the cell's two programs over shapes alone, at the widest
-    bucket: yields (program name, compiled, frames, floor, leaves)."""
+    bucket: yields (program name, compiled, frames, floor, leaves, what
+    :func:`chunk_faults` needs of the chunk program or None)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -132,6 +185,7 @@ def compile_cell(cell_name, sharding):
     from pytorch_distributed_tpu.serve import (
         EngineConfig, ServeEngine, page_axis,
     )
+    from pytorch_distributed_tpu.serve.kv_slots import kv_frame_width
 
     cell = Cell(cell_name)
     cfg, fam, es = cell.config, cell.family(), cell.spec["engine"]
@@ -164,7 +218,7 @@ def compile_cell(cell_name, sharding):
             page_size=es["page_size"], num_pages=mp,
             prefix_cache=es["prefix_cache"],
         ))
-        planes = []  # elements of one layer's plane, per pool leaf
+        planes, widths = [], []  # a layer's plane, a frame: per leaf
 
         def leaf(path, x):
             ax = page_axis(path, x)
@@ -172,6 +226,7 @@ def compile_cell(cell_name, sharding):
                 return sds(x.shape, x.dtype)
             shape = x.shape[:ax] + (frames,) + x.shape[ax + 1:]
             planes.append(int(np.prod(shape[ax:])))
+            widths.append(shape[-1])
             return sds(shape, x.dtype)
 
         cache = jax.tree_util.tree_map_with_path(leaf, engine.pool.cache)
@@ -193,14 +248,22 @@ def compile_cell(cell_name, sharding):
             engine._decode_fn, donate_argnums=(1, 3, 4, 5),
             static_argnums=(10,),
         ).lower(params, cache, i32(S, mp), *rows, sds((S,), jnp.bool_), mp)
-        yield "_decode_fn", decode.compile(), frames, floor, len(planes)
+        yield (
+            "_decode_fn", decode.compile(), frames, floor, len(planes), None
+        )
         prefill = jax.jit(
             engine._prefill_fn, donate_argnums=(1,), static_argnums=(14,),
         ).lower(
             params, cache, i32(S, mp), i32(1, C), i32(), i32(), i32(),
             sds((), jnp.bool_), *rows, mp,
         )
-        yield "_prefill_fn", prefill.compile(), frames, floor, len(planes)
+        yield "_prefill_fn", prefill.compile(), frames, floor, len(planes), {
+            "queries": C, "positions": es["max_len"],
+            "frames": [[es["page_size"], w] for w in widths],
+            "bucket_elements": es["max_len"] * min(widths),
+            "leaves": len(planes),
+            "in_place": kv_frame_width(engine.pool.cache) is not None,
+        }
 
 
 def main(argv=None) -> int:
@@ -246,8 +309,8 @@ def main(argv=None) -> int:
 
     ok = True
     for cell in args.cell or CELLS:
-        for name, compiled, frames, floor, n in compile_cell(cell, sharding):
-            ok &= check_program(f"{cell}/{name}", compiled, frames, floor, n)
+        for name, *program in compile_cell(cell, sharding):
+            ok &= check_program(f"{cell}/{name}", *program)
     return 0 if ok else 1
 
 

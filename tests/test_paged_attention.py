@@ -486,6 +486,160 @@ def test_row_walk_counts_pages_and_blocks(w):
     assert pages.tolist() == by_hand[0] and blocks.tolist() == by_hand[1]
 
 
+# -- many queries a row: the query-tiled body (a prompt chunk) --------------
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles of 32 query rows over blocks of 2 pages of 8, and every
+    kernel call over a K and a V pool through the tiled body: a chunk
+    of a few dozen queries then spans several tiles and several
+    blocks."""
+    monkeypatch.setattr(_PAGED, "_BLOCK_MAX_TOKENS", 16)
+    monkeypatch.setattr(_PAGED, "_TILE_ROWS", 32)
+    monkeypatch.setattr(_PAGED, "_CHUNK_QUERIES", 1)
+
+
+# (query heads, kv heads, head size, value lanes of a latent frame) and
+# the W that is exactly one tile of 32 rows there. A latent frame's many
+# queries keep the tick's body (no model sends it a chunk: MLAttention
+# decodes the row's frames), so its cases hold that body to the same
+TILED_GEOMETRIES = {
+    "gqa4x1x128": (4, 1, 128, None, 8),
+    "mha4x64": (4, 4, 64, None, 16),    # two heads a 128-lane group
+    "latent8x640": (8, 1, 640, 512, 4),
+}
+
+
+def _chunk_case(rng, geometry, W):
+    """Three rows of one chunk each: an empty row, one mid-page, and
+    one right behind three whole pages it SHARES with the second (a
+    prefix hit). The table is twice as wide as any row reaches; what
+    no row owns is the null page, and the null page, like every frame
+    no row owns, holds NaN in ``dirty``."""
+    Hq, Hkv, D, r, _ = TILED_GEOMETRIES[geometry]
+    ps, n = 8, 16
+    lengths = np.asarray([0, 29, 24])
+    own = -(-(lengths + W) // ps)
+    assert own.max() * 2 <= n
+    tables = np.zeros((3, n), np.int32)
+    at = 1
+    for b in range(3):
+        tables[b, :own[b]] = np.arange(at, at + own[b])
+        at += own[b]
+    tables[2, :3] = tables[1, :3]
+    P1 = at + 3
+    q = jnp.asarray(rng.standard_normal((3, W, Hq, D)), jnp.float32)
+    pools = [
+        jnp.asarray(rng.standard_normal((P1, ps, Hkv * D)), jnp.float32)
+        for _ in range(1 if r else 2)
+    ]
+    live = np.zeros(P1, bool)
+    live[tables[tables > 0]] = True
+    dead = jnp.asarray(~live)[:, None, None]
+    dirty = [jnp.where(dead, jnp.nan, p) for p in pools]
+    clean = [jnp.where(dead, 0.0, p) for p in pools]
+    return q, clean, dirty, jnp.asarray(tables), jnp.asarray(
+        lengths, jnp.int32)
+
+
+@pytest.mark.parametrize("L", [None, 3], ids=["leaf", "stacked"])
+@pytest.mark.parametrize("window", [None, 3])
+@pytest.mark.parametrize("tiles", ["under", "at", "over"])
+@pytest.mark.parametrize("geometry", list(TILED_GEOMETRIES))
+def test_tiled_body_is_the_chunks_attention(
+    small_tiles, geometry, tiles, window, L
+):
+    """The tiled body (a latent frame: the tick's, many queries a row)
+    against the exact ``gather`` impl and the float reference, per
+    geometry: W under, at and over one query tile (over: two tiles and
+    a part of a third), with and without a window that binds inside
+    the chunk, a leaf alone and a plane of a stacked one. It runs over
+    pools whose dead frames hold NaN."""
+    Hq, Hkv, D, r, at = TILED_GEOMETRIES[geometry]
+    W = {"under": at - 1 if at > 4 else 3, "at": at, "over": 2 * at + 3}[
+        tiles]
+    rng = np.random.default_rng(30)
+    q, clean, dirty, tables, lengths = _chunk_case(rng, geometry, W)
+    hpg, tq, nt = _PAGED.query_tiles(W, Hq // Hkv, Hkv, D)
+    assert hpg * tq * (Hq // Hkv) == 32 or tiles == "under"
+    assert nt == {"under": 1, "at": 1, "over": 3}[tiles]
+    kw = dict(page_tables=tables, lengths=lengths, window=window,
+              scale=D ** -0.5)
+
+    def run(pools, impl):
+        leaves, layer = zip(*[_leaf(p, L, fold=1) for p in pools])
+        if impl == "reference":
+            return paged_attention_reference(
+                q, leaves[0], None if r else leaves[1], layer=layer[0],
+                value_dim=r, **kw)
+        v = _PAGED.PagedPrefix(leaves[0], r) if r else leaves[1]
+        return paged_attention(
+            q, leaves[0], v, layer=layer[0], impl=impl, **kw)
+
+    out = np.asarray(run(dirty, "kernel"))
+    assert out.shape == (3, W, Hq, r or D) and np.isfinite(out).all()
+    for impl in ("gather", "reference"):
+        ref = np.asarray(run(clean, impl))
+        assert np.max(np.abs(out - ref)) <= 3e-6, impl
+
+
+@pytest.mark.parametrize("W,G,Hkv,D,body", [
+    (1, 4, 8, 128, "paged_attention"),      # Mistral's tick
+    (5, 4, 8, 128, "paged_attention"),      # and its verify, k = 4
+    (1, 1, 16, 64, "paged_attention"),      # GPT-2's
+    (5, 1, 16, 64, "paged_attention"),
+    (15, 1, 16, 64, "paged_attention"),     # the longest verify
+    (1, 64, 1, 640, "paged_attention"),     # the latent tick: 64 rows
+    (5, 64, 1, 640, "paged_attention"),     # and its verify: 320
+    (64, 64, 1, 640, "paged_attention"),    # one pool: the one body
+    (512, 4, 8, 128, "paged_prefill"),      # the chunks: Mistral's,
+    (128, 1, 16, 64, "paged_prefill"),      # GPT-2's in the cell,
+    (64, 1, 16, 64, "paged_prefill"),       # in chip_smoke.py,
+    (16, 1, 16, 64, "paged_prefill"),       # and the shortest there is
+])
+def test_the_body_is_read_off_the_calls_shapes(W, G, Hkv, D, body):
+    """A tick and a speculative verify keep the one block-diagonal
+    operand, whatever rows a kv head brings; a call of 16 queries a row
+    or more over a K and a V pool takes the tiled body, under a name of
+    its own (the ticks' roofline readers sum every op named
+    ``paged_attention``)."""
+    sds = jax.ShapeDtypeStruct
+    pool = sds((9, 16, Hkv * D), jnp.float32)
+    text = str(jax.make_jaxpr(lambda q, k, t, l: paged_attention(
+        q, k, _PAGED.PagedPrefix(k, 512) if Hkv * D == 640 else k,
+        page_tables=t, lengths=l, impl="kernel",
+    ))(sds((2, W, G * Hkv, D), jnp.float32), pool,
+       sds((2, 4), jnp.int32), sds((2,), jnp.int32)))
+    assert f"name={body}\n" in text or f"name={body} " in text, text[-600:]
+    assert ("paged_attention" in body) != ("name=paged_prefill" in text)
+
+
+@pytest.mark.parametrize("w,g,hkv,d,want", [
+    (512, 4, 8, 128, (1, 256, 2)),     # Mistral: 1024 rows a head a tile
+    (128, 1, 16, 64, (2, 128, 1)),     # GPT-2: two heads a lane group
+    (64, 1, 16, 64, (2, 64, 1)),       # a chunk shorter than a tile
+    (127, 1, 16, 64, (2, 128, 1)),     # whole sublane tiles of positions
+    (100, 3, 2, 16, (2, 112, 1)),      # whole sublane tiles of rows
+])
+def test_query_tiles_follow_the_heads_and_the_chunk(w, g, hkv, d, want):
+    assert _PAGED.query_tiles(w, g, hkv, d) == want
+
+
+def test_tile_walk_counts_a_rows_prefix_once_a_tile():
+    """By hand at pages of 16 in blocks of 4: 512 queries behind 2048
+    cached tokens in tiles of 256 reach 144 and 160 pages, 36 and 40
+    blocks; one tile of them all is ``row_walk``."""
+    lengths = np.asarray([2048, 0])
+    pages, blocks = _PAGED.tile_walk(lengths, 512, 256, 16, 256, 4)
+    assert pages.tolist() == [[144, 160], [16, 32]]
+    assert blocks.tolist() == [[36, 40], [4, 8]]
+    one = _PAGED.tile_walk(lengths, 512, 512, 16, 256, 4)
+    row = _PAGED.row_walk(lengths, 512, 16, 256, 4)
+    assert one[0][:, 0].tolist() == row[0].tolist() == [160, 32]
+    assert one[1][:, 0].tolist() == row[1].tolist()
+
+
 # -- engine wiring ----------------------------------------------------------
 
 

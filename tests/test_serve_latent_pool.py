@@ -145,6 +145,64 @@ def test_chunked_prefill_then_decode_is_the_references_forward(toy):
             assert frames[:, 1:6].any() and frames[:, 11:16].any()
 
 
+def test_a_latent_chunk_decodes_its_gathered_rows(toy):
+    """A chunk under the view DECODES the row's bucket of latents (one
+    gather of it a leaf, then the dense arithmetic), whatever the
+    table's width: the same chunk behind the same prefix at a bucket of
+    8 pages and of 16 gives the same logits, and neither program holds
+    an attention kernel. A speculative verify's few queries a row run
+    absorbed through the kernel, as the tick does: ``ops`` tells the
+    two apart (``is_chunk``), not the model."""
+    small, cfg, _ = toy
+    with precision.use_policy(FULL):
+        engine = ServeEngine(small.model, small.params, EngineConfig(
+            num_slots=SLOTS, max_len=2 * MAX_LEN, prefill_chunk=CHUNK,
+            page_size=PAGE, num_pages=PAGES,
+        ))
+        ids = np.random.default_rng(3).integers(1, cfg["vocab_size"], 48)
+        pt = np.zeros((SLOTS, 2 * MAX_LEN // PAGE), np.int32)
+        pt[1, :6] = np.arange(1, 7)
+        pt = jnp.asarray(pt)
+        logits, texts = {}, {}
+        for n_pages in (8, 16):
+            cache = engine.pool.cache
+            for start in range(0, 48, CHUNK):
+                args = (engine.model, engine.params, engine.pool, cache, pt,
+                        jnp.asarray(ids[None, start:start + CHUNK]), 1,
+                        start)
+                out, cache, _ = engine._prefill_chunk_body(*args, n_pages)
+            logits[n_pages] = np.asarray(out)
+            texts[n_pages] = str(jax.make_jaxpr(
+                lambda c, i: engine._prefill_chunk_body(
+                    args[0], args[1], args[2], c, pt, i, 1, 32, n_pages
+                )[0]
+            )(cache, args[5]))
+
+        def verify(cache, toks, lengths):
+            with paged_view(PagedView(
+                page_tables=pt, keep=jnp.ones(SLOTS, bool), page_size=PAGE,
+            )):
+                return engine.model.apply(
+                    {"params": engine.params, "cache": cache}, toks,
+                    decode=True, cache_len=2 * MAX_LEN, mutable=["cache"],
+                    positions=lengths[:, None] + jnp.arange(5),
+                    write_pos=lengths,
+                )[0]
+
+        texts["verify"] = str(jax.make_jaxpr(verify)(
+            cache, jnp.ones((SLOTS, 5), jnp.int32),
+            jnp.zeros(SLOTS, jnp.int32),
+        ))
+    assert np.abs(logits[8] - logits[16]).max() < 2e-5
+    assert np.abs(logits[8]).max() > 1e-2
+    # the expert layer's kernel is there either way
+    for n_pages in (8, 16):
+        assert "name=paged_" not in texts[n_pages]
+        assert "gather[" in texts[n_pages]
+    assert texts["verify"].count("name=paged_attention") == 2  # both leaves
+    assert "name=paged_prefill" not in texts["verify"]
+
+
 def test_prefix_sharing_and_migration_frames_on_latent_pages(toy):
     engine, cfg, _ = toy
     rng = np.random.default_rng(4)

@@ -218,6 +218,50 @@ def test_prefix_sharing_is_copy_free_and_exact(body):
     engine.pool.check_consistency()
 
 
+@pytest.mark.parametrize("impl", ["gather", "tiled"])
+def test_chunk_under_the_view_matches_solo_generate(body, impl, monkeypatch):
+    """A prompt chunk runs as the tick does, under a ``PagedView`` of
+    its slot's row with the pool as its cache: a prompt of three chunks
+    whose last is padded, a second behind a prefix hit on the first's
+    whole pages (its one chunk starts mid-prompt, at the shared span's
+    end), and a prompt of exactly one chunk each emit solo
+    ``generate``'s stream — to the bit under ``"gather"`` (the bucket's
+    slab, the dense math), and token for token through the kernel's
+    query-tiled body (interpreted; tiles of 16 rows over blocks of 2
+    pages, so a chunk spans tiles and blocks)."""
+    import sys
+
+    paged = sys.modules["pytorch_distributed_tpu.ops.paged_attention"]
+    monkeypatch.setattr(paged, "_IMPL", "kernel" if impl == "tiled" else impl)
+    if impl == "tiled":
+        monkeypatch.setattr(paged, "_CHUNK_QUERIES", 1)
+        monkeypatch.setattr(paged, "_TILE_ROWS", 16)
+        monkeypatch.setattr(paged, "_BLOCK_MAX_TOKENS", 8)
+    model, params = body[0]
+    rng = np.random.default_rng(30)
+    ids = lambda n: rng.integers(1, 97, size=n).astype(np.int32)  # noqa: E731
+    sys_p = ids(16)
+    reqs = [
+        Request(np.concatenate([sys_p, ids(3)]), max_new_tokens=5),
+        Request(np.concatenate([sys_p, ids(6)]), max_new_tokens=4,
+                temperature=0.7, top_k=11, seed=3),
+        Request(ids(8), max_new_tokens=3),
+    ]
+    engine = ServeEngine(model, params, EngineConfig(
+        num_slots=2, max_len=48, prefill_chunk=8, page_size=4,
+    ))
+    handles = []
+    for r in reqs:  # one at a time: the second finds the first's pages
+        handles.append(engine.submit(r))
+        engine.run_until_drained()
+    assert engine.pool.prefix_hits == 1 and engine.pool.shared_tokens == 16
+    for h, r in zip(handles, reqs):
+        assert h.status is RequestStatus.COMPLETED
+        assert h.tokens == _solo(model, params, r)
+    _assert_bucketed_compiles(engine)
+    engine.pool.check_consistency()
+
+
 def test_page_exhaustion_blocks_head_of_line(gpt2):
     """With pages for only one request in flight, the second queues
     (strict FIFO) until the first retires — and both stay solo-exact."""

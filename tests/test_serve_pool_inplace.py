@@ -8,9 +8,13 @@ PLANE of a pool leaf are
 * the ``scatter`` that writes the new positions (the result is the leaf),
 * the layer loop, with the leaves among its CARRY — never its scanned
   inputs or stacked outputs, which an XLA ``while`` cannot alias,
-* the read: the ``pallas_call`` of the paged-attention kernel, or the
-  ``gather`` of the exact impl / of one row's bucket, whose RESULT is
-  bounded by the bucket, not the pool.
+* the read: the ``pallas_call`` of the paged-attention kernel — a
+  tick's and, since PR 30, a prompt chunk's: under ``"kernel"`` no
+  equation of ``_prefill_fn`` / ``_prefill_spec_fn`` gathers from a
+  pool leaf, so no array of the bucket's ``n_pages * page_size``
+  positions exists there — or the ``gather`` of the exact impl / of
+  the speculative draft's bucket, whose RESULT is bounded by the
+  bucket, not the pool.
 
 No ``transpose``, ``dynamic_slice``, ``reshape``, ``copy`` or
 ``dynamic_update_slice`` of a plane: each of those is a pass over the
@@ -137,6 +141,21 @@ def _faults(jaxpr, plane, out):
     return out
 
 
+def _pool_gathers(jaxpr, plane, out):
+    """Every ``gather`` that reads a plane of the pool, in this jaxpr
+    and all below it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for inner in _sub_jaxprs(eqn):
+            _pool_gathers(inner, plane, out)
+        if eqn.primitive.name == "gather" and any(
+            _size(v) >= plane for v in eqn.invars
+        ):
+            out.append(str(eqn.outvars[0].aval))
+    return out
+
+
 def _rows(engine):
     return (engine._toks, engine._lengths, engine._keys, engine._temps,
             engine._top_ks, engine._top_ps)
@@ -184,8 +203,34 @@ def test_no_program_moves_a_plane_of_the_pool(
         faults = _faults(jaxpr.jaxpr, plane, [])
         assert not faults, f"{name} moves the pool:\n" + "\n".join(faults)
         # the walk did see the pool: it is written, and read in place
-        # (a chunk reads its row's bucket by gather under every impl)
         text = str(jaxpr)
-        kernel = impl == "kernel" and "prefill" not in name
         assert "scatter" in text
-        assert ("pallas_call" if kernel else "gather") in text
+        assert ("pallas_call" if impl == "kernel" else "gather") in text
+        # under the kernel a chunk runs as the tick does: nothing of it
+        # gathers the row's bucket out of the pool (the speculative
+        # tick's draft still does, and the exact impl always)
+        gathers = _pool_gathers(jaxpr.jaxpr, plane, [])
+        if impl == "kernel" and "prefill" in name:
+            assert not gathers, f"{name} gathers the pool: {gathers}"
+        else:
+            assert gathers or impl == "kernel"
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+@pytest.mark.parametrize("body", sorted(BODIES))
+def test_the_chunk_program_aliases_every_pool_leaf(body, spec, monkeypatch):
+    """Donated in, aliased out: lowered with the pool donated, as the
+    engine jits it off the CPU, every leaf of the pool argument of
+    ``_prefill_fn`` (both pools of ``_prefill_spec_fn``) names the
+    result it aliases — the chunk's write is a scatter into the leaf,
+    which rides the layer loop as its carry and comes back."""
+    monkeypatch.setattr(_PAGED, "_IMPL", "kernel")
+    engine = _engine(body, 3, spec)
+    name = "_prefill_spec_fn" if spec else "_prefill_fn"
+    fn, args = _programs(engine)[name]
+    donated = (2, 3) if spec else (1,)
+    text = jax.jit(
+        fn, donate_argnums=donated, static_argnums=(len(args) - 1,)
+    ).lower(*args).as_text()
+    leaves = sum(len(jax.tree_util.tree_leaves(args[i])) for i in donated)
+    assert text.count("tf.aliasing_output") == leaves

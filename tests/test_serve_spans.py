@@ -205,6 +205,18 @@ def test_tick_carries_its_bucket_and_the_pages_its_rows_reach(drive):
             assert a["k"] == SPEC_K
 
 
+def test_chunk_carries_the_pages_its_row_reaches(drive):
+    """A chunk at ``start`` writes and attends positions up to ``start +
+    CHUNK``: ``live_pages`` is the pages under them, by hand; off a TPU
+    the attention is the gather impl, which gathers the row's bucket."""
+    chunks = [e["args"] for e in drive.named("serve.prefill_chunk")]
+    assert len(chunks) == sum(-(-p // CHUNK) for p, _ in MIX)
+    for a in chunks:
+        assert a["live_pages"] == (a["start"] + CHUNK) // PAGE
+        assert a["live_pages"] <= a["fetched_pages"] == a["n_pages"]
+    assert any(a["live_pages"] < a["n_pages"] for a in chunks)
+
+
 def test_fetched_pages_is_the_kernels_own_block_arithmetic(
     monkeypatch, drive
 ):
@@ -234,9 +246,58 @@ def test_fetched_pages_is_the_kernels_own_block_arithmetic(
         )
         whole_blocks += a["fetched_pages"] > a["live_pages"]
     assert whole_blocks  # some row ended inside a block
+    # a chunk's span says the same of its one row: the pages up to
+    # ``start + CHUNK``, and the kernel's blocks of them, whole (8
+    # queries are one walk: the tick's body)
+    chunks = [e["args"] for e in served.named("serve.prefill_chunk")]
+    assert len(chunks) == sum(-(-p // CHUNK) for p, _ in MIX)
+    for a in chunks:
+        k = _PAGED.block_pages(PAGE, 32 * 4, a["n_pages"])
+        pages, blocks = _PAGED.row_walk(
+            np.asarray([a["start"]]), CHUNK, PAGE, a["n_pages"], k
+        )
+        assert a["live_pages"] == pages.sum() == (a["start"] + CHUNK) // PAGE
+        assert a["fetched_pages"] == blocks.sum() * k
+        assert a["live_pages"] <= a["fetched_pages"] <= a["n_pages"]
+    # the 19-token prompt's third chunk reaches 6 pages of a bucket of 8
+    assert any(a["fetched_pages"] < a["n_pages"] for a in chunks)
     assert [h.tokens for h in served.handles] == [
         h.tokens for h in drive.handles
     ]
+
+
+def test_a_tiled_chunk_fetches_its_prefix_once_a_tile(monkeypatch):
+    """A chunk of 32 queries goes through the kernel's query-tiled body
+    (interpreted; tiles of 16 positions over blocks of 2 pages of 4),
+    and each tile walks the row from its first page: ``fetched_pages``
+    is the sum over the tiles, by ``tile_walk``, and passes the bucket
+    where a chunk has a prefix."""
+    C = 32
+    request = Request(np.arange(1, 41, dtype=np.int32), max_new_tokens=3)
+    exact = _engine("plain", prefill_chunk=C)
+    want = exact.submit(request)
+    exact.run_until_drained()
+    monkeypatch.setattr(_PAGED, "_IMPL", "kernel")
+    monkeypatch.setattr(_PAGED, "_BLOCK_MAX_TOKENS", 2 * PAGE)
+    monkeypatch.setattr(_PAGED, "_TILE_ROWS", 32)
+    assert _PAGED.query_tiles(C, 1, 2, 16) == (2, 16, 2)
+    eng = _engine("plain", prefill_chunk=C)
+    with tracing.enabled() as t:
+        got = eng.submit(request)
+        eng.run_until_drained()
+    chunks = [e["args"] for e in t._events
+              if e["ph"] == "X" and e["name"] == "serve.prefill_chunk"]
+    assert [a["start"] for a in chunks] == [0, 32]
+    for a in chunks:
+        pages, blocks = _PAGED.tile_walk(
+            np.asarray([a["start"]]), C, 16, PAGE, a["n_pages"], 2
+        )
+        assert a["live_pages"] == pages[0, -1] == (a["start"] + C) // PAGE
+        assert a["fetched_pages"] == blocks.sum() * 2
+    # by hand: the tiles reach 4 and 8 pages, then 12 and 16
+    assert [a["fetched_pages"] for a in chunks] == [12, 28]
+    assert chunks[1]["fetched_pages"] > chunks[1]["n_pages"] == 16
+    assert got.tokens == want.tokens
 
 
 def test_plain_tick_pages_by_hand():

@@ -120,6 +120,40 @@ def test_paged_kernel_lowers_on_latent_pages(W, L):
     )
 
 
+@pytest.mark.parametrize("L", [None, 3], ids=["leaf", "stacked"])
+@pytest.mark.parametrize("window", [None, 4096])
+@pytest.mark.parametrize("W,Hq,Hkv,D", [
+    (512, 32, 8, 128),      # Mistral's chunk: 1024 rows a head a tile
+    (128, 16, 16, 64),      # GPT-2's: two heads a 128-lane group
+    (96, 12, 4, 128),       # three queries a kv head: no power of two
+    (64, 16, 16, 64),       # GPT-2 at chip_smoke.py's chunk,
+    (96, 16, 16, 64),       # and between it and the cell's:
+    (127, 16, 16, 64),      # no whole tile of positions
+    (16, 16, 16, 64),       # the shortest chunk there is
+])
+def test_tiled_body_lowers(W, Hq, Hkv, D, window, L):
+    """A prompt chunk's call — one row, many queries — takes the
+    query-tiled body (``paged_prefill``) at the K/V serving
+    configurations' shapes and at every chunk a GPT-2 engine may be
+    given from 16 positions up: a 256-page table of 16-token pages,
+    bf16."""
+    ps, n = 16, 256
+    pool = (n + 1, ps, Hkv * D)
+    stacked = L is not None
+    leaf = _sds((L,) + pool if stacked else pool, "bfloat16")
+
+    def chunk(q, k, v, t, l, lay):
+        return paged_attention(
+            q, k, v, page_tables=t, lengths=l, window=window,
+            layer=lay if stacked else None, impl="kernel", scale=0.1,
+        )
+
+    args = (_sds((1, W, Hq, D), "bfloat16"), leaf, leaf,
+            _sds((1, n), "int32"), _sds((1,), "int32"), _sds((), "int32"))
+    assert "name=paged_prefill" in str(jax.make_jaxpr(chunk)(*args))
+    _assert_lowers_for_tpu(chunk, *args)
+
+
 @pytest.mark.parametrize("T,dtype", [(128, "bfloat16"), (512, "bfloat16"),
                                      (24, "float32")])
 def test_expert_gmm_lowers(T, dtype):
@@ -259,7 +293,8 @@ def test_compiled_serving_programs_leave_the_pool_in_place(
     a result the size of a pool leaf, a layer's plane or a good part of
     one unless it is the pool passing by, the layer loop or the scatter,
     and the pool parameters alias the pool results. What
-    ``scripts/pool_hlo_check.py`` prints on the chip, held here."""
+    ``scripts/pool_hlo_check.py`` prints on the chip, held here, with
+    what it asks of the chunk program alone."""
     import os
 
     scripts = os.path.join(
@@ -272,9 +307,16 @@ def test_compiled_serving_programs_leave_the_pool_in_place(
     monkeypatch.setattr(_PAGED, "_IMPL", "kernel")
     programs = list(pool_hlo_check.compile_cell(cell, one_v5e))
     assert [name for name, *_ in programs] == ["_decode_fn", "_prefill_fn"]
-    for name, compiled, frames, floor, n_leaves in programs:
+    for name, compiled, frames, floor, n_leaves, chunk in programs:
         text = compiled.as_text()
         assert pool_hlo_check.pool_passes(text, frames, floor) == [], name
+        if chunk is not None:
+            # the chunk attends where the pool lies (PR 30): no gather
+            # of the bucket, no score matrix in HBM, one scatter a leaf
+            assert pool_hlo_check.chunk_faults(
+                text, frames, floor, chunk
+            ) == [], name
+            assert "tpu_custom_call" in text
         params = pool_hlo_check.entry_parameters(text)
         pool = {i for i, p in enumerate(params) if "cached_" in p}
         aliased = set(pool_hlo_check.aliased_outputs(text).values())
