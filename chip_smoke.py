@@ -9,7 +9,9 @@ from a seed):
 1. kernels  — the Pallas paged-attention and flash-attention kernels,
    compiled by Mosaic (never interpreted), against their in-repo
    references at the smoke model's geometry and at the 32/8 x 128 GQA
-   geometry the first benchmark cells bring;
+   geometry the first benchmark cells bring; and a bf16 head at
+   Mistral's shape, fused with its cast to f32, still handing on bf16's
+   values (``Policy.to_output``: what a tick's greedy token rests on);
 2. train    — ``recipes/gpt2_zero1.main`` itself: ZeRO-1 over every
    local chip, sequence 1024, remat, global batch 8 per chip;
 3. serve    — a ``ServeEngine`` driven by the calls
@@ -66,6 +68,7 @@ class Sizes:
     kernel_batch: int
     kernel_ctx: int
     geometries: tuple    # (Hq, Hkv, D, dtype name, window)
+    head: tuple          # (rows, hidden, vocab) of a bf16 head's product
 
 
 FULL = Sizes(
@@ -77,6 +80,7 @@ FULL = Sizes(
         (16, 16, 64, "float32", None),     # the smoke model's
         (32, 8, 128, "bfloat16", 256),     # GQA + window (Mistral widths)
     ),
+    head=(32, 4096, 32000),                # mistral-serve-sat's tick
 )
 REHEARSAL = Sizes(
     gpt2="tiny", seq_len=32, batch_per_chip=2, train_steps=3,
@@ -87,6 +91,7 @@ REHEARSAL = Sizes(
         (4, 4, 16, "float32", None),
         (8, 2, 32, "bfloat16", 24),
     ),
+    head=(4, 64, 512),
 )
 
 # Kernel-vs-reference tolerances, as max |kernel - ref| / max |ref|, the
@@ -244,6 +249,51 @@ def check_kernels(sizes: Sizes, on_chip: bool) -> None:
                       + "/".join(f"{e:.2e}" for e in errs)
                       + f" (tol {tol:.0e})", flush=True)
                 assert max(errs) <= tol
+
+
+def check_head_rounding(sizes: Sizes) -> None:
+    """A head whose product is bf16 hands on bf16's values, fused or not.
+
+    ``Policy.to_output`` (the cast to f32, then ``reduce_precision``) is
+    what Llama's and DeepSeek-V3's heads return their logits through:
+    compiled into ONE program with the product, every f32 logit has to
+    be a bf16 number and their ``argmax`` the one a program reads that
+    wrote the bf16 logits to memory first. With a bare ``astype`` the
+    compiler hands on the product's f32 accumulator (0.005% of the
+    logits were bf16 numbers at this shape on a v5e, PERF.md §6 PR 33)
+    and a tick's greedy token depends on how its head was fused."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pytorch_distributed_tpu.runtime.precision import Policy
+
+    rows, hidden, vocab = sizes.head
+    kx, kw = jax.random.split(jax.random.key(0))
+    x = jax.random.normal(kx, (rows, hidden), jnp.bfloat16)
+    w = (jax.random.normal(kw, (hidden, vocab)) * 0.02).astype(jnp.bfloat16)
+
+    @jax.jit
+    def fused(x, w):
+        logits = Policy().to_output(x @ w)
+        return logits, jnp.argmax(logits, axis=-1)
+
+    logits, token = fused(x, w)
+    stored = jax.jit(lambda x, w: x @ w)(x, w)   # bf16, through memory
+    share = float(np.mean(
+        np.asarray(logits)
+        == np.asarray(logits.astype(jnp.bfloat16).astype(jnp.float32))
+    ))
+    same = int(np.sum(
+        np.asarray(token) == np.asarray(jnp.argmax(stored, axis=-1))
+    ))
+    print(f"  head   {rows}x{hidden}x{vocab} bfloat16: {share:.4%} of the "
+          f"fused f32 logits are bf16 numbers; argmax equal to the stored "
+          f"product's in {same}/{rows} rows", flush=True)
+    assert share == 1.0 and same == rows
+    assert np.array_equal(
+        np.asarray(logits), np.asarray(stored.astype(jnp.float32))
+    )
 
 
 def train_leg(sizes: Sizes, extra_args=(), *, batch_size=None) -> dict:
@@ -461,6 +511,7 @@ def main(argv=None) -> int:
     t_start = time.perf_counter()
     for name, leg in (
         ("kernels", lambda: check_kernels(sizes, on_chip)),
+        ("head", lambda: check_head_rounding(sizes)),
         ("train", lambda: train_leg(sizes)),
         ("serve", lambda: serve_leg(sizes, on_chip)),
     ):

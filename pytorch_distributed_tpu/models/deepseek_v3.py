@@ -378,7 +378,7 @@ class DeepseekV3ForCausalLM(nn.Module):
             cfg.vocab_size, use_bias=False, dtype=policy.compute_dtype,
             param_dtype=policy.param_dtype, name="lm_head",
         )(x)
-        return logits.astype(policy.output_dtype)
+        return policy.to_output(logits)
 
 
 def deepseek_v3_partition_rules(ep_axis: str = "ep", tp_axis: str = "tp"):

@@ -345,7 +345,7 @@ class LlamaForCausalLM(nn.Module):
                 cfg.vocab_size, use_bias=False, dtype=policy.compute_dtype,
                 param_dtype=policy.param_dtype, name="lm_head",
             )(x)
-        return logits.astype(policy.output_dtype)
+        return policy.to_output(logits)
 
 
 def llama_partition_rules(num_kv_heads: Optional[int] = None):

@@ -46,6 +46,21 @@ class Policy:
     def cast_to_output(self, tree):
         return _cast_floating(tree, self.output_dtype)
 
+    def to_output(self, x):
+        """``x`` in ``output_dtype`` holding ``x.dtype``'s values still.
+
+        A bare ``astype`` to a wider dtype leaves the compiler free to
+        skip the narrow rounding when it fuses the cast with the product
+        that made ``x`` (XLA's excess precision hands on the product's
+        f32 accumulator; seen on a v5e at Mistral's head, PERF.md §6
+        PR 33), and a greedy token at a bf16 tie then follows a fusion
+        choice. ``reduce_precision`` at ``x.dtype``'s own bits pins the
+        rounding; between equal dtypes it is the identity."""
+        stated = jnp.finfo(x.dtype)
+        return jax.lax.reduce_precision(
+            x.astype(self.output_dtype), stated.nexp, stated.nmant
+        )
+
 
 def _cast_floating(tree, dtype):
     def cast(x):

@@ -375,6 +375,10 @@ class ServeEngine:
         self._decoding_dirty = True
         self._decoding_cached = []
         self._active_cached = None
+        # decoding rows whose request samples (temperature > 0): what
+        # the tick's sampler is about to observe on the device, kept
+        # here so the tick spans can say it without a fetch
+        self._sampling_rows = 0
         self._steps = 0
         self._decode_ticks = 0
         # armed only: (span, tokens + routing counters) of dispatched
@@ -518,9 +522,11 @@ class ServeEngine:
         last = jax.lax.dynamic_index_in_dim(
             logits, last_idx, axis=1, keepdims=False
         )  # [1, V] — the chunk's last REAL prompt column
+        # only the final chunk's token is kept: no other buys a draw
         tok = sample_logits_rows(
             last, pair[1][None], temps[slot][None],
             top_ks[slot][None], top_ps[slot][None],
+            live=jnp.reshape(final, (1,)),
         )[0]
         keys = jnp.where(final, keys.at[slot].set(pair[0]), keys)
         toks = jnp.where(final, toks.at[slot].set(tok), toks)
@@ -638,7 +644,11 @@ class ServeEngine:
                 write_pos=lengths, with_intermediates=True,
             )
         pair = jax.vmap(jax.random.split)(keys)  # [S, 2, 2]
-        nxt = sample_logits_rows(last, pair[:, 1], temps, top_ks, top_ps)
+        # the sampler's cost follows the LIVE rows' parameters: a freed
+        # slot's stale temperature must not buy the batch a sort
+        nxt = sample_logits_rows(
+            last, pair[:, 1], temps, top_ks, top_ps, live=active
+        )
         # advance ONLY the decoding rows in place: the continuing token
         # becomes next tick's input, the rng chain splits once, the
         # length grows one — inactive rows (free / mid-prefill) keep
@@ -687,8 +697,9 @@ class ServeEngine:
         # the sampled machinery (per-row filtered distributions — a
         # vocab sort per position — plus rejection sampling) is real
         # compute the all-greedy steady state shouldn't pay: one
-        # runtime branch skips it when no live row samples
-        any_sampled = jnp.any(~greedy_row)
+        # runtime branch skips it when no live row samples (a freed
+        # slot keeps its last request's temperature: masked by `active`)
+        any_sampled = jnp.any(active & ~greedy_row)
 
         dense_d = gather_pages(dcache, dpt, self.draft_pool.tails)
 
@@ -1463,6 +1474,9 @@ class ServeEngine:
             for slot, _ in self._decoding_cached:
                 active[slot] = True
             self._active_cached = jnp.asarray(active)
+            self._sampling_rows = sum(
+                h.request.temperature > 0 for _, h in self._decoding_cached
+            )
             self._decoding_dirty = False
         decoding = self._decoding_cached
         if not decoding:
@@ -1480,6 +1494,7 @@ class ServeEngine:
             tracing._NULL_SPAN if tracing._tracer is None
             else tracing.span(
                 "serve.decode_tick", active=len(decoding), n_pages=n_pages,
+                sampling_rows=self._sampling_rows,
                 **self._tick_pages(decoding, n_pages),
             )
         )
@@ -1559,6 +1574,7 @@ class ServeEngine:
             else tracing.span(
                 "serve.spec_tick", active=len(decoding),
                 k=self.spec.num_draft_tokens, n_pages=n_pages,
+                sampling_rows=self._sampling_rows,
                 **self._tick_pages(decoding, n_pages),
             )
         )

@@ -9,8 +9,17 @@ arrive as ``[B]`` arrays and every row follows exactly the math of
 so a request's token stream is BIT-IDENTICAL to a solo ``generate``
 call with the same seed and params (pinned by tests/test_serve.py).
 
-Exactness notes (why the always-on filter path is a no-op for "off"
-rows, bit for bit):
+How much of this runs is decided BY THE PROGRAM, from the per-row
+parameters it is handed (``sample_logits_rows``; one ``lax.cond``, no
+static argument, one compiled program): a batch in which no LIVE row
+samples takes ``argmax`` and nothing else — no divide, no vocabulary
+sort, no softmax, no ``categorical``; a live row that samples buys the
+whole filter path below for the batch, filtered or not (an "off"
+filter masks nothing). Which branch ran never changes a token, by the
+notes below.
+
+Exactness notes (why the filter path is a no-op for "off" rows, bit
+for bit):
 
 * ``top_k`` off is encoded as ``k = V``: the k-th sorted logit is the
   row minimum, and ``logits < min`` masks nothing.
@@ -21,10 +30,14 @@ rows, bit for bit):
   drop a tail token a None-filtered ``generate`` would keep.)
 * Filters only MASK (set ``-inf``); kept logits are never rewritten,
   so a no-op mask leaves the row bitwise equal to the unfiltered path.
+* Rows are independent: nothing in the filter or the draw crosses
+  rows, so a row's token does not depend on which branch the OTHER
+  rows put the batch on.
 * Greedy rows (``temperature == 0``) take ``argmax`` of the RAW logits
   exactly like ``sample_logits``'s early return; their lane through
-  the sampling path divides by a substituted 1.0 (never 0) and the
-  result is discarded by the final select.
+  the sampling path (when another row asks for it) divides by a
+  substituted 1.0 (never 0) and the result is discarded by the final
+  select.
 """
 
 from __future__ import annotations
@@ -73,6 +86,7 @@ def sample_logits_rows(
     temps: jnp.ndarray,
     top_ks: jnp.ndarray,
     top_ps: jnp.ndarray,
+    live: jnp.ndarray | None = None,  # [B] bool; None = every row
 ) -> jnp.ndarray:
     """[B, V] logits -> [B] token ids, each row by its own params/key.
 
@@ -80,10 +94,23 @@ def sample_logits_rows(
     sampling rows draw ``categorical`` from their filtered/scaled
     distribution with their own key — the exact per-row transcript of
     ``generation.sample_logits``.
+
+    ``live`` marks the rows whose token anyone reads. It only prices
+    the call (module docstring): a row that is not live — a freed slot
+    still carrying its last request's temperature — never sends the
+    batch down the sampling branch, and its own lane holds whatever
+    the branch the live rows chose computes for it.
     """
-    filtered = filter_logits_rows(logits, temps, top_ks, top_ps)
-    sampled = jax.vmap(
-        lambda key, row: jax.random.categorical(key, row, axis=-1)
-    )(subkeys, filtered).astype(jnp.int32)
+    sampling = temps > 0
+    if live is not None:
+        sampling = sampling & live
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jnp.where(temps <= 0, greedy, sampled)
+
+    def sampled():
+        filtered = filter_logits_rows(logits, temps, top_ks, top_ps)
+        return jax.vmap(
+            lambda key, row: jax.random.categorical(key, row, axis=-1)
+        )(subkeys, filtered).astype(jnp.int32)
+
+    nxt = jax.lax.cond(jnp.any(sampling), sampled, lambda: greedy)
+    return jnp.where(temps <= 0, greedy, nxt)

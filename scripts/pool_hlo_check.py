@@ -23,6 +23,12 @@ chunk is asked the third alone: it writes where the leaf lies, and
 decodes a gathered bucket of latents by design, as before PR 30
 (``models/deepseek_v3.py``).
 
+Of both programs it asks one thing of their end, the sampler (PR 33):
+every ``sort`` lies in a computation that is reached only through a
+branch of a ``conditional`` — the compiler kept the sampler's branch a
+branch and did not flatten it into a select that runs both sides — so a
+call whose live rows are all greedy sorts no vocabulary.
+
     python scripts/pool_hlo_check.py                  # on the chip
     python scripts/pool_hlo_check.py --describe v5e:2x2   # anywhere
 
@@ -129,6 +135,47 @@ def chunk_faults(hlo_text: str, frames: int, floor: int, chunk: dict):
     return out
 
 
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(?P<name>[\w.\-]+) \(.*\{\s*$")
+_CALLED = re.compile(r"(?:to_apply|calls|body|condition)=%([\w.\-]+)")
+
+
+def unbranched_sorts(hlo_text: str, vocab: int):
+    """(the vocabulary sorts every call of the program runs, all its
+    vocabulary sorts): a sort of rows ``vocab`` wide (an expert layer
+    sorts its tokens, and may) is unbranched when its computation is
+    reached from ENTRY without passing through a branch of a
+    ``conditional``."""
+    calls, sorts, entry, here = {}, {}, None, None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            here = m["name"]
+            calls[here], sorts[here] = set(), []
+            if line.startswith("ENTRY"):
+                entry = here
+            continue
+        m = _INSTR.match(line)
+        if not m or here is None:
+            continue
+        if m["op"] == "sort" and any(
+            dims.split(",")[-1] == str(vocab)
+            for dims in _SHAPE.findall(m["type"])
+        ):
+            sorts[here].append(m["name"])
+        # a conditional's branches are the edges NOT followed
+        calls[here].update(_CALLED.findall(line))
+    seen, todo = set(), [entry]
+    while todo:
+        c = todo.pop()
+        if c not in seen and c in calls:
+            seen.add(c)
+            todo.extend(calls[c])
+    return (
+        [s for c in sorted(seen) for s in sorts[c]],
+        [s for c in sorts for s in sorts[c]],
+    )
+
+
 def aliased_outputs(hlo_text: str):
     """``{output index: parameter number}`` from the module header."""
     head = hlo_text.split("\n", 1)[0]
@@ -143,9 +190,10 @@ def entry_parameters(hlo_text: str):
     return re.findall(r"([\w.]+): ", m.group(1)) if m else []
 
 
-def check_program(name, compiled, frames, floor, n_leaves, chunk=None):
+def check_program(name, compiled, frames, floor, n_leaves, chunk, vocab):
     text = compiled.as_text()
     passes = pool_passes(text, frames, floor)
+    bare, sorts = unbranched_sorts(text, vocab)
     if chunk is not None:
         passes += chunk_faults(text, frames, floor, chunk)
     params = entry_parameters(text)
@@ -156,7 +204,10 @@ def check_program(name, compiled, frames, floor, n_leaves, chunk=None):
     ]
     aliased = sorted(set(alias.values()) & set(pool_params))
     mem = compiled.memory_analysis()
-    ok = not passes and len(aliased) == len(pool_params) == n_leaves
+    ok = (
+        not passes and not bare and bool(sorts)
+        and len(aliased) == len(pool_params) == n_leaves
+    )
     print(json.dumps({
         "program": name,
         "ok": ok,
@@ -165,6 +216,8 @@ def check_program(name, compiled, frames, floor, n_leaves, chunk=None):
         "pool_sized_passes": [
             {"instruction": i, "op": o, "type": t} for i, o, t in passes
         ],
+        "vocabulary_sorts": len(sorts),
+        "vocabulary_sorts_outside_a_conditional": bare,
         "temp_bytes": mem.temp_size_in_bytes,
         "argument_bytes": mem.argument_size_in_bytes,
         "kernel_calls": text.count('custom_call_target="tpu_custom_call"'),
@@ -175,7 +228,8 @@ def check_program(name, compiled, frames, floor, n_leaves, chunk=None):
 def compile_cell(cell_name, sharding):
     """Compile the cell's two programs over shapes alone, at the widest
     bucket: yields (program name, compiled, frames, floor, leaves, what
-    :func:`chunk_faults` needs of the chunk program or None)."""
+    :func:`chunk_faults` needs of the chunk program or None, the
+    vocabulary's width)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -248,8 +302,10 @@ def compile_cell(cell_name, sharding):
             engine._decode_fn, donate_argnums=(1, 3, 4, 5),
             static_argnums=(10,),
         ).lower(params, cache, i32(S, mp), *rows, sds((S,), jnp.bool_), mp)
+        vocab = cfg["vocab_size"]
         yield (
-            "_decode_fn", decode.compile(), frames, floor, len(planes), None
+            "_decode_fn", decode.compile(), frames, floor, len(planes), None,
+            vocab,
         )
         prefill = jax.jit(
             engine._prefill_fn, donate_argnums=(1,), static_argnums=(14,),
@@ -263,7 +319,7 @@ def compile_cell(cell_name, sharding):
             "bucket_elements": es["max_len"] * min(widths),
             "leaves": len(planes),
             "in_place": kv_frame_width(engine.pool.cache) is not None,
-        }
+        }, vocab
 
 
 def main(argv=None) -> int:

@@ -34,6 +34,6 @@ def test_without_a_tpu_it_fails_before_building_anything():
 def test_cpu_rehearsal_runs_every_phase_and_prints_no_result():
     proc = _run("--rehearse-cpu", timeout=900)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    for phase in ("kernels", "train", "serve"):
+    for phase in ("kernels", "head", "train", "serve"):
         assert f"[{phase}] passed" in proc.stdout
     assert '"ok"' not in proc.stdout

@@ -357,6 +357,39 @@ class TestPrecision:
         assert out["w"].dtype == jnp.bfloat16
         assert out["i"].dtype == jnp.int32
 
+    @pytest.mark.parametrize(
+        "dtype", [jnp.bfloat16, jnp.float16, jnp.float32]
+    )
+    def test_to_output_pins_the_narrow_rounding(self, dtype):
+        """``Policy.to_output`` widens without leaving the compiler the
+        choice of skipping the narrow rounding: one ``reduce_precision``
+        at the INPUT dtype's bits after the cast, the values unchanged,
+        and a product's f32 accumulator comes out as the narrow product
+        would — whatever was fused."""
+        p = ptd.Policy()
+        x = jnp.asarray(np.random.default_rng(0).normal(size=(4, 33)) * 7, dtype)
+        eqns = jax.make_jaxpr(p.to_output)(x).jaxpr.eqns
+        info = jnp.finfo(dtype)
+        assert [
+            (e.params["exponent_bits"], e.params["mantissa_bits"])
+            for e in eqns if e.primitive.name == "reduce_precision"
+        ] == [(info.nexp, info.nmant)]
+        assert eqns[-1].primitive.name == "reduce_precision"
+        out = p.to_output(x)
+        assert out.dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(out), np.asarray(x.astype(jnp.float32))
+        )
+        # the rounding itself, applied to values that were NOT rounded
+        # (what a fused head hands on): equal to the narrow dtype's own
+        acc = jnp.asarray(
+            np.random.default_rng(1).normal(size=(4, 33)) * 7, jnp.float32
+        )
+        np.testing.assert_array_equal(
+            np.asarray(jax.lax.reduce_precision(acc, info.nexp, info.nmant)),
+            np.asarray(acc.astype(dtype).astype(jnp.float32)),
+        )
+
     def test_gradscaler_bf16_noop(self):
         scaler = ptd.GradScaler()
         assert scaler.init_state() is None

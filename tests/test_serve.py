@@ -422,36 +422,128 @@ def test_kv_slot_pool_lifecycle(gpt2):
     pool.check_consistency()
 
 
-def test_sample_logits_rows_matches_static_sampler():
+_GREEDY = dict(temperature=0.0, top_k=None, top_p=None)
+_PLAIN = dict(temperature=1.0, top_k=None, top_p=None)
+_TOP_K = dict(temperature=0.7, top_k=7, top_p=None)
+_TOP_P = dict(temperature=1.3, top_k=None, top_p=0.6)
+_BOTH = dict(temperature=0.9, top_k=25, top_p=0.9)
+
+# batch compositions, on either side of the sampler's one branch
+# (serve/sampling.py: argmax only, or the filter path for the batch);
+# a row is (params, live)
+_SAMPLER_BATCHES = {
+    "every_combination": [
+        (_GREEDY, True), (_PLAIN, True), (_TOP_K, True), (_TOP_P, True),
+        (_BOTH, True),
+    ],
+    "all_greedy": [(_GREEDY, True)] * 4,
+    "all_sampling_no_filter": [
+        (_PLAIN, True), (dict(_PLAIN, temperature=0.6), True),
+        (dict(_PLAIN, temperature=1.7), True),
+    ],
+    "top_k_only": [(_TOP_K, True), (dict(_TOP_K, top_k=1), True),
+                   (dict(_TOP_K, top_k=500), True)],
+    "top_p_only": [(_TOP_P, True), (dict(_TOP_P, top_p=0.95), True)],
+    "top_k_and_top_p": [(_BOTH, True), (dict(_BOTH, top_k=3), True)],
+    "mixed_greedy_and_sampling": [
+        (_GREEDY, True), (_PLAIN, True), (_GREEDY, True),
+        (dict(_PLAIN, temperature=0.8), True),
+    ],
+    "mixed_greedy_and_filtered": [
+        (_GREEDY, True), (_BOTH, True), (_PLAIN, True), (_GREEDY, True),
+    ],
+    "stale_filtered_row_beside_live_greedy": [
+        (_GREEDY, True), (_BOTH, False), (_GREEDY, True), (_PLAIN, False),
+    ],
+    "stale_filtered_row_beside_live_sampling": [
+        (_PLAIN, True), (_TOP_K, False), (_GREEDY, True),
+    ],
+}
+
+
+@pytest.mark.parametrize("batch", sorted(_SAMPLER_BATCHES))
+def test_sample_logits_rows_matches_static_sampler(batch):
     """Row-wise sampler == generation.sample_logits per row, for every
     (greedy/temp/top-k/top-p/off) combination — the transcript that
-    makes engine-vs-generate parity possible."""
+    makes engine-vs-generate parity possible — whichever branch the
+    batch's live rows put the jitted program on. A row that is not live
+    prices nothing and its token is nobody's: beside live greedy rows
+    it comes back as the argmax the batch's one branch computed."""
     from pytorch_distributed_tpu.generation import sample_logits
 
+    rows = _SAMPLER_BATCHES[batch]
+    n = len(rows)
     rng = np.random.default_rng(0)
     V = 101
-    logits = jnp.asarray(rng.normal(size=(5, V)).astype(np.float32) * 3)
-    rows = [
-        dict(temperature=0.0, top_k=None, top_p=None),
-        dict(temperature=1.0, top_k=None, top_p=None),
-        dict(temperature=0.7, top_k=7, top_p=None),
-        dict(temperature=1.3, top_k=None, top_p=0.6),
-        dict(temperature=0.9, top_k=25, top_p=0.9),
-    ]
-    keys = jnp.stack([jax.random.PRNGKey(i) for i in range(5)])
+    logits = jnp.asarray(rng.normal(size=(n, V)).astype(np.float32) * 3)
+    keys = jnp.stack([jax.random.PRNGKey(i) for i in range(n)])
     want = [
-        int(sample_logits(
-            logits[i][None], keys[i], **rows[i]
-        )[0])
-        for i in range(5)
+        int(sample_logits(logits[i][None], keys[i], **rows[i][0])[0])
+        for i in range(n)
     ]
-    got = sample_logits_rows(
+    live = [alive for _, alive in rows]
+    got = jax.jit(sample_logits_rows)(
         logits, keys,
-        jnp.asarray([r["temperature"] for r in rows], jnp.float32),
-        jnp.asarray([r["top_k"] or 0 for r in rows], jnp.int32),
+        jnp.asarray([r["temperature"] for r, _ in rows], jnp.float32),
+        jnp.asarray([r["top_k"] or 0 for r, _ in rows], jnp.int32),
         jnp.asarray(
-            [np.inf if r["top_p"] is None else r["top_p"] for r in rows],
+            [np.inf if r["top_p"] is None else r["top_p"] for r, _ in rows],
             jnp.float32,
         ),
+        None if all(live) else jnp.asarray(live),
     )
-    assert [int(x) for x in got] == want
+    got = [int(x) for x in got]
+    assert [g for g, a in zip(got, live) if a] == [
+        w for w, a in zip(want, live) if a
+    ]
+    if not any(a and r["temperature"] > 0 for r, a in rows):
+        # no live row samples: argmax for every lane, stale ones too —
+        # and for the stale sampling rows that is NOT what their own
+        # parameters would have drawn, so the sampling branch did not run
+        greedy = [int(x) for x in jnp.argmax(logits, axis=-1)]
+        assert got == greedy
+        stale = [i for i, (r, a) in enumerate(rows)
+                 if not a and r["temperature"] > 0]
+        assert not stale or any(want[i] != greedy[i] for i in stale)
+
+
+def _head_roundings(model, ids):
+    """(exponent, mantissa) bits of every ``reduce_precision`` in the
+    model's forward under the default (bf16 compute, f32 out) policy."""
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids)
+    )["params"]
+    jaxpr = jax.make_jaxpr(
+        lambda p: model.apply({"params": p}, ids)
+    )(params).jaxpr
+    assert jaxpr.outvars[0].aval.dtype == jnp.float32
+    return [
+        (e.params["exponent_bits"], e.params["mantissa_bits"])
+        for e in jaxpr.eqns if e.primitive.name == "reduce_precision"
+    ]
+
+
+@pytest.mark.parametrize("family", ["llama", "mistral", "deepseek_v3", "gpt2"])
+def test_served_heads_state_the_precision_of_their_logits(family):
+    """A head whose product is in the compute dtype hands its logits on
+    through ``Policy.to_output``: the bf16 rounding is an operation of
+    the program, not a cast a fusion may skip (XLA's excess precision),
+    so the greedy token of a tick and of solo ``generate`` is one token
+    however each was compiled. GPT-2's head states f32 logits
+    (``preferred_element_type``) and rounds nothing."""
+    from pytorch_distributed_tpu.models import (
+        DeepseekV3Config, DeepseekV3ForCausalLM, LlamaConfig,
+        LlamaForCausalLM, MistralConfig, MistralForCausalLM,
+    )
+
+    model = {
+        "llama": lambda: LlamaForCausalLM(LlamaConfig.tiny()),
+        "mistral": lambda: MistralForCausalLM(MistralConfig.tiny()),
+        "deepseek_v3": lambda: DeepseekV3ForCausalLM(
+            DeepseekV3Config.tiny()
+        ),
+        "gpt2": lambda: GPT2LMHead(GPT2Config.tiny()),
+    }[family]()
+    bf16 = jnp.finfo(jnp.bfloat16)
+    want = [] if family == "gpt2" else [(bf16.nexp, bf16.nmant)]
+    assert _head_roundings(model, jnp.ones((1, 8), jnp.int32)) == want

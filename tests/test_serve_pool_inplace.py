@@ -21,6 +21,11 @@ No ``transpose``, ``dynamic_slice``, ``reshape``, ``copy`` or
 pool in the compiled program, and a tick then costs what the pool
 weighs (PERF.md). ``scripts/pool_hlo_check.py`` asks the same of
 the optimised HLO at the benchmark cells' shapes, on the chip.
+
+The same walk pins the sampler at the programs' end (PR 33): every
+``sort`` of a serving program sits inside a ``cond`` branch whose
+sibling holds none, so a tick whose live rows are all greedy sorts no
+vocabulary (``serve/sampling.py``).
 """
 
 import sys
@@ -234,3 +239,41 @@ def test_the_chunk_program_aliases_every_pool_leaf(body, spec, monkeypatch):
     ).lower(*args).as_text()
     leaves = sum(len(jax.tree_util.tree_leaves(args[i])) for i in donated)
     assert text.count("tf.aliasing_output") == leaves
+
+
+def _sorts(jaxpr, under_cond, out):
+    """(primitive, inside a cond branch?) of every sorting equation in
+    this jaxpr and all below it; a ``cond`` counts only if one of its
+    branches holds no sort at all, i.e. it can skip the work."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("sort", "top_k", "approx_top_k"):
+            out.append((name, under_cond))
+        if name == "cond":
+            branches = [b.jaxpr for b in eqn.params["branches"]]
+            skips = any(not _sorts(b, True, []) for b in branches)
+            for b in branches:
+                _sorts(b, under_cond or skips, out)
+            continue
+        for inner in _sub_jaxprs(eqn):
+            _sorts(inner, under_cond, out)
+    return out
+
+
+@pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
+@pytest.mark.parametrize("body", sorted(BODIES))
+def test_every_sort_of_a_serving_program_is_behind_a_branch(
+    body, spec, monkeypatch
+):
+    """The sampler sorts a row's vocabulary only for a live row that
+    samples through a filter: in each program the sorts are there (the
+    filtered path was traced), each inside a ``cond`` that has a branch
+    without one, and none on the path every call takes."""
+    monkeypatch.setattr(_PAGED, "_IMPL", "kernel")
+    engine = _engine(body, 1, spec)
+    for name, (fn, args) in _programs(engine).items():
+        jaxpr = jax.make_jaxpr(fn, static_argnums=(len(args) - 1,))(*args)
+        sorts = _sorts(jaxpr.jaxpr, False, [])
+        assert sorts, f"{name}: the filtered sampler was not traced"
+        bare = [prim for prim, under in sorts if not under]
+        assert not bare, f"{name} sorts on every call: {bare}"

@@ -314,6 +314,51 @@ def test_plain_tick_pages_by_hand():
     assert [a["n_pages"] for a in ticks] == [2, 2, 4, 4]
 
 
+def test_an_all_greedy_run_has_no_sampling_rows(drive):
+    tick = "serve.decode_tick" if drive.mode == "plain" else "serve.spec_tick"
+    ticks = drive.named(tick)
+    assert ticks and all(e["args"]["sampling_rows"] == 0 for e in ticks)
+
+
+@pytest.mark.parametrize("mode", ["plain", "spec"])
+def test_tick_counts_the_decoding_rows_that_sample(mode):
+    """Five requests in five slots, two of them with a temperature and
+    done first: ``sampling_rows`` is the host's count of decoding rows
+    with ``temperature > 0`` (never a fetch), and falls back to 0 while
+    the freed slots still hold their last request's temperature on the
+    device, where both ticks' samplers mask it by ``active`` the same
+    way (``sample_logits_rows(live=)``, ``_spec_fn``'s ``any_sampled``)."""
+    eng = _engine(mode, num_slots=5)
+    # (temperature, max_new_tokens): the sampling ones finish early
+    mix = ((0.0, 16), (0.8, 8), (0.0, 16), (1.1, 6), (0.0, 16))
+    with tracing.enabled() as t:
+        handles = [
+            eng.submit(Request(
+                np.arange(6, dtype=np.int32) + 10 * i + 1,
+                max_new_tokens=n, temperature=temp,
+                top_p=0.9 if temp else None, seed=i,
+            ))
+            for i, (temp, n) in enumerate(mix)
+        ]
+        eng.run_until_drained()
+    name = "serve.decode_tick" if mode == "plain" else "serve.spec_tick"
+    ticks = [e["args"] for e in t._events if e["name"] == name]
+    counts = [a["sampling_rows"] for a in ticks]
+    assert all(0 <= c <= a["active"] for c, a in zip(counts, ticks))
+    assert max(counts) == 2 and counts[-1] == 0
+    # once down, it stays down: nothing new was admitted
+    first_zero = counts.index(0, counts.index(2))
+    assert set(counts[first_zero:]) == {0}
+    if mode == "plain":
+        # by hand: a request's first token comes from its prefill, every
+        # other from one tick, so the two sampling requests ride
+        # (8 - 1) + (6 - 1) ticks between them
+        assert sum(counts) == 12
+    # the stale rows are really there: the freed slots kept their temps
+    stale = np.asarray(eng._temps)
+    assert (stale > 0).sum() == 2 and all(h.done for h in handles)
+
+
 def test_sweep_span_only_when_a_sweep_has_work():
     eng = _engine("plain")
     with tracing.enabled() as t:

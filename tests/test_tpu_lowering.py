@@ -12,6 +12,7 @@ sufficient: what Mosaic then makes of VMEM and tiling is only known on
 the chip — ``chip_smoke.py`` compiles and runs the same shapes there.
 """
 
+import re
 import sys
 
 import jax
@@ -294,7 +295,10 @@ def test_compiled_serving_programs_leave_the_pool_in_place(
     one unless it is the pool passing by, the layer loop or the scatter,
     and the pool parameters alias the pool results. What
     ``scripts/pool_hlo_check.py`` prints on the chip, held here, with
-    what it asks of the chunk program alone."""
+    what it asks of the chunk program alone — and of the sampler at
+    both programs' end (PR 33): the vocabulary sort is there, and only
+    behind a ``conditional`` the compiler kept, so an all-greedy call
+    runs none."""
     import os
 
     scripts = os.path.join(
@@ -307,9 +311,20 @@ def test_compiled_serving_programs_leave_the_pool_in_place(
     monkeypatch.setattr(_PAGED, "_IMPL", "kernel")
     programs = list(pool_hlo_check.compile_cell(cell, one_v5e))
     assert [name for name, *_ in programs] == ["_decode_fn", "_prefill_fn"]
-    for name, compiled, frames, floor, n_leaves, chunk in programs:
+    for name, compiled, frames, floor, n_leaves, chunk, vocab in programs:
         text = compiled.as_text()
         assert pool_hlo_check.pool_passes(text, frames, floor) == [], name
+        bare, sorts = pool_hlo_check.unbranched_sorts(text, vocab)
+        assert sorts and not bare and " conditional(" in text, (name, bare)
+        if "lm_head/dot_general" in text:
+            # a head in bf16 (Mistral, GigaChat; GPT-2's tied head states
+            # f32): the compiler kept the rounding of its logits as an
+            # operation (`Policy.to_output`) and did not hand the f32
+            # accumulator on, so the tick's greedy token is the model's
+            assert re.search(
+                r"reduce-precision\(.*exponent_bits=8, mantissa_bits=7",
+                text,
+            ), name
         if chunk is not None:
             # the chunk attends where the pool lies (PR 30): no gather
             # of the bucket, no score matrix in HBM, one scatter a leaf
