@@ -26,6 +26,7 @@ import threading
 from typing import Any, Optional
 
 import jax
+import jax.extend.core as jcore
 import jax.numpy as jnp
 
 
@@ -69,6 +70,105 @@ def _cast_floating(tree, dtype):
         return x
 
     return jax.tree_util.tree_map(cast, tree)
+
+
+def rest_in_compute_dtype(forward, params, dtype):
+    """``params`` with every leaf the model only ever reads in ``dtype``
+    stored in ``dtype``, and ``{"leaves": n, "bytes": [before, after]}``
+    of what was cast.
+
+    A leaf is cast when it is a float wider than ``dtype`` and every use
+    of it in ``forward(params)`` is a ``convert_element_type`` to
+    ``dtype`` (a ``Dense`` kernel under the default :class:`Policy`):
+    cast once here, it is the very operand the program made of it on
+    every call — the same bits, without the per-call read of the wide
+    copy. A leaf read wide anywhere (an embedding lookup, a norm scale)
+    is left as it was. ``forward`` is traced once over abstract shapes,
+    and not at all when no leaf is wider than ``dtype``; when nothing is
+    cast, ``params`` itself comes back. Leaves may be arrays or
+    ``jax.ShapeDtypeStruct``\\ s (a compile over shapes alone)."""
+    dtype = jnp.dtype(dtype)
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    wide = [
+        i for i, x in enumerate(leaves)
+        if jnp.issubdtype(x.dtype, jnp.floating)
+        and jnp.dtype(x.dtype).itemsize > dtype.itemsize
+    ]
+    if wide:
+        closed = jax.make_jaxpr(
+            lambda *ls: forward(jax.tree_util.tree_unflatten(treedef, ls))
+        )(*(jax.ShapeDtypeStruct(x.shape, x.dtype) for x in leaves))
+        narrowed = _only_narrowed(closed.jaxpr, dtype, {})
+        wide = [i for i in wide if narrowed[i]]
+    picked = [leaves[i] for i in wide]
+    cast = {"leaves": len(picked), "bytes": [
+        sum(x.size * jnp.dtype(x.dtype).itemsize for x in picked),
+        sum(x.size * dtype.itemsize for x in picked),
+    ]}
+    if not picked:
+        return params, cast
+    if all(isinstance(x, jax.ShapeDtypeStruct) for x in picked):
+        narrow = [
+            jax.ShapeDtypeStruct(x.shape, dtype, sharding=x.sharding)
+            for x in picked
+        ]
+    else:
+        narrow = _astype(picked, dtype)
+    for i, x in zip(wide, narrow):
+        leaves[i] = x
+    return jax.tree_util.tree_unflatten(treedef, leaves), cast
+
+
+_astype = jax.jit(
+    lambda xs, dtype: [x.astype(dtype) for x in xs], static_argnums=1
+)
+
+# operations whose sub-jaxpr takes the operation's operands in order
+_CALLS = ("scan", "jit", "remat2", "closed_call", "custom_jvp_call",
+          "custom_vjp_call")
+
+
+def _only_narrowed(jaxpr, dtype, seen):
+    """Per input of ``jaxpr``: is every use a convert to ``dtype``,
+    followed by position into the sub-jaxprs of :data:`_CALLS` and of a
+    ``cond``'s branches? Any other use, a return among them, says no."""
+    ok = {v: True for v in jaxpr.invars}
+    for v in jaxpr.outvars:
+        if v in ok:
+            ok[v] = False
+    for eqn in jaxpr.eqns:
+        for pos, v in enumerate(eqn.invars):
+            if isinstance(v, jcore.Literal) or not ok.get(v):
+                continue
+            if (eqn.primitive.name == "convert_element_type"
+                    and eqn.params["new_dtype"] == dtype):
+                continue
+            subs = _subjaxprs(eqn)
+            ok[v] = bool(subs) and all(
+                pos >= skip and _sub_narrowed(sub, dtype, seen)[pos - skip]
+                for sub, skip in subs
+            )
+    return [ok[v] for v in jaxpr.invars]
+
+
+def _subjaxprs(eqn):
+    """[(sub-jaxpr, how many leading operands it does not take)]."""
+    name = eqn.primitive.name
+    if name == "cond":
+        return [(b.jaxpr, 1) for b in eqn.params["branches"]]
+    if name not in _CALLS:
+        return []
+    subs = [
+        getattr(p, "jaxpr", p) for p in eqn.params.values()
+        if isinstance(p, (jcore.ClosedJaxpr, jcore.Jaxpr))
+    ]
+    return [(s, 0) for s in subs[:1] if len(s.invars) == len(eqn.invars)]
+
+
+def _sub_narrowed(jaxpr, dtype, seen):
+    if id(jaxpr) not in seen:
+        seen[id(jaxpr)] = _only_narrowed(jaxpr, dtype, seen)
+    return seen[id(jaxpr)]
 
 
 _FULL = Policy(compute_dtype=jnp.float32)

@@ -94,6 +94,10 @@ from pytorch_distributed_tpu.ops.paged_attention import (
 from pytorch_distributed_tpu.ops.moe import collect_route_stats
 from pytorch_distributed_tpu.runtime import faults
 from pytorch_distributed_tpu.runtime import tracing
+from pytorch_distributed_tpu.runtime.precision import (
+    current_policy,
+    rest_in_compute_dtype,
+)
 from pytorch_distributed_tpu.serve.kv_slots import (
     PagedKVPool,
     extract_frames,
@@ -131,6 +135,21 @@ def _with_route_stats(tokens, routed):
     if routed is None:
         return tokens
     return jnp.concatenate([tokens.reshape(-1), routed.reshape(-1)])
+
+
+def _rest_in_compute_dtype(model, params, page_size: int):
+    """``precision.rest_in_compute_dtype`` over the model's own decode
+    apply, traced as the pool's shapes are (``serve/kv_slots.py``)."""
+
+    def forward(p):
+        return model.apply(
+            {"params": p}, jnp.zeros((1, 1), jnp.int32), decode=True,
+            cache_len=page_size, mutable=["cache"],
+        )
+
+    return rest_in_compute_dtype(
+        forward, params, current_policy().compute_dtype
+    )
 
 
 def _route_args(down, n_tokens: int):
@@ -252,7 +271,6 @@ class ServeEngine:
         clock=time.monotonic,
     ):
         self.model = model
-        self.params = params
         self.config = config
         self.spec = spec
         self.role = config.role
@@ -292,6 +310,11 @@ class ServeEngine:
             page_size=config.page_size, num_pages=config.num_pages,
             prefix_cache=config.prefix_cache,
         )
+        # a leaf every program would narrow on every call rests narrow
+        # instead: the same operands, cast once (runtime/precision.py)
+        self.params, cast = _rest_in_compute_dtype(
+            model, params, self.pool.page_size
+        )
         self.draft_pool = None
         self._spec_tail = 0
         if spec is not None:
@@ -301,6 +324,15 @@ class ServeEngine:
                     f"max_len {config.max_len} exceeds the DRAFT "
                     f"model's maximum sequence length {dlimit}"
                 )
+            dparams, dcast = _rest_in_compute_dtype(
+                spec.draft_model, spec.draft_params, self.pool.page_size
+            )
+            self.spec = spec = dataclasses.replace(spec, draft_params=dparams)
+            cast = {
+                "leaves": cast["leaves"] + dcast["leaves"],
+                "bytes": [a + b for a, b in zip(cast["bytes"],
+                                                 dcast["bytes"])],
+            }
             # the draft shares the target's page geometry so one chunk
             # stream and one joint prefix skip drive both caches
             self.draft_pool = PagedKVPool(
@@ -313,6 +345,15 @@ class ServeEngine:
             # verify writes up to k rejected-draft entries past the
             # emitted horizon — reserved at admission, checked at submit
             self._spec_tail = spec.num_draft_tokens
+        #: the leaves cast at construction, target and draft together,
+        #: and their bytes before and after
+        self.param_cast = cast
+        logger.info(
+            "serve engine: %d slots, %d pages of %d, params cast to the "
+            "compute dtype: %d leaves, %d -> %d bytes",
+            config.num_slots, self.pool.num_pages, self.pool.page_size,
+            cast["leaves"], *cast["bytes"],
+        )
         self.scheduler = Scheduler(config.num_slots, config.prefill_chunk)
         # -- r18 fleet state ------------------------------------------------
         # one geometry string commits the pool's frame layout; every

@@ -29,6 +29,15 @@ branch of a ``conditional`` — the compiler kept the sampler's branch a
 branch and did not flatten it into a select that runs both sides — so a
 call whose live rows are all greedy sorts no vocabulary.
 
+And one of their weights: no ``convert`` (or fusion) makes a bf16 copy
+of an f32 parameter stack, a leaf of the scanned layers. The engine
+casts once, at construction, every leaf the model only reads in the
+compute dtype (``runtime.precision.rest_in_compute_dtype``), and the
+programs get it narrow; a bf16 copy of a stack is that cast paid again
+on every call (a GPT-2 tick's 0.6 GB of ``temp_bytes`` before it). The
+f32 weights a program still casts whole, such as a tied head's
+embedding, are listed under ``weight_casts`` and do not fail it.
+
     python scripts/pool_hlo_check.py                  # on the chip
     python scripts/pool_hlo_check.py --describe v5e:2x2   # anywhere
 
@@ -176,6 +185,28 @@ def unbranched_sorts(hlo_text: str, vocab: int):
     )
 
 
+def weight_casts(hlo_text: str, weights: dict):
+    """(instruction, type, leaves) of every ``convert`` or fusion, in
+    every computation, whose result is a bf16 array the shape of an f32
+    weight the program receives: ``weights`` maps a weight's dims
+    (``"24,1024,4096"``) to the paths of the leaves of that shape."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if not m or m["op"] not in ("convert", "fusion"):
+            continue
+        for dims in re.findall(r"bf16\[([0-9,]*)\]", m["type"]):
+            if dims in weights:
+                out.append((m["name"], m["type"].strip(), weights[dims]))
+    return out
+
+
+def stacked(leaves) -> bool:
+    """Is any of these leaf paths in a scanned layer stack (the block
+    ``models/scan.py`` scans is named ``block``)?"""
+    return any("['block']" in p for p in leaves)
+
+
 def aliased_outputs(hlo_text: str):
     """``{output index: parameter number}`` from the module header."""
     head = hlo_text.split("\n", 1)[0]
@@ -190,12 +221,14 @@ def entry_parameters(hlo_text: str):
     return re.findall(r"([\w.]+): ", m.group(1)) if m else []
 
 
-def check_program(name, compiled, frames, floor, n_leaves, chunk, vocab):
+def check_program(name, compiled, frames, floor, n_leaves, chunk, vocab,
+                  weights):
     text = compiled.as_text()
     passes = pool_passes(text, frames, floor)
     bare, sorts = unbranched_sorts(text, vocab)
     if chunk is not None:
         passes += chunk_faults(text, frames, floor, chunk)
+    casts = weight_casts(text, weights)
     params = entry_parameters(text)
     alias = aliased_outputs(text)
     pool_params = [
@@ -207,6 +240,7 @@ def check_program(name, compiled, frames, floor, n_leaves, chunk, vocab):
     ok = (
         not passes and not bare and bool(sorts)
         and len(aliased) == len(pool_params) == n_leaves
+        and not any(stacked(leaves) for _, _, leaves in casts)
     )
     print(json.dumps({
         "program": name,
@@ -218,6 +252,10 @@ def check_program(name, compiled, frames, floor, n_leaves, chunk, vocab):
         ],
         "vocabulary_sorts": len(sorts),
         "vocabulary_sorts_outside_a_conditional": bare,
+        "weight_casts": [
+            {"instruction": i, "type": t, "leaves": leaves}
+            for i, t, leaves in casts
+        ],
         "temp_bytes": mem.temp_size_in_bytes,
         "argument_bytes": mem.argument_size_in_bytes,
         "kernel_calls": text.count('custom_call_target="tpu_custom_call"'),
@@ -229,7 +267,8 @@ def compile_cell(cell_name, sharding):
     """Compile the cell's two programs over shapes alone, at the widest
     bucket: yields (program name, compiled, frames, floor, leaves, what
     :func:`chunk_faults` needs of the chunk program or None, the
-    vocabulary's width)."""
+    vocabulary's width, the f32 weights the program gets as
+    :func:`weight_casts` takes them)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -287,8 +326,17 @@ def compile_cell(cell_name, sharding):
         # a quarter of the smallest plane: the compiler has been seen
         # to split a leaf in halves ahead of a gather
         floor = min(planes) // 4
+        # the programs get the engine's leaves, some cast at construction
+        params = engine.params
+        weights = {}  # the f32 weights the programs get, by their dims
+        for path, x in jax.tree_util.tree_leaves_with_path(params):
+            if x.dtype == jnp.float32 and len(x.shape) >= 2:
+                weights.setdefault(",".join(map(str, x.shape)), []).append(
+                    jax.tree_util.keystr(path)
+                )
         print(f"# {cell_name}: {len(planes)} pool leaves, {frames} frames "
-              f"of {es['page_size']} positions, bucket {mp} pages")
+              f"of {es['page_size']} positions, bucket {mp} pages; params "
+              f"cast at construction: {engine.param_cast}")
 
         def i32(*shape):
             return sds(shape, jnp.int32)
@@ -305,7 +353,7 @@ def compile_cell(cell_name, sharding):
         vocab = cfg["vocab_size"]
         yield (
             "_decode_fn", decode.compile(), frames, floor, len(planes), None,
-            vocab,
+            vocab, weights,
         )
         prefill = jax.jit(
             engine._prefill_fn, donate_argnums=(1,), static_argnums=(14,),
@@ -319,7 +367,7 @@ def compile_cell(cell_name, sharding):
             "bucket_elements": es["max_len"] * min(widths),
             "leaves": len(planes),
             "in_place": kv_frame_width(engine.pool.cache) is not None,
-        }, vocab
+        }, vocab, weights
 
 
 def main(argv=None) -> int:

@@ -298,7 +298,10 @@ def test_compiled_serving_programs_leave_the_pool_in_place(
     what it asks of the chunk program alone — and of the sampler at
     both programs' end (PR 33): the vocabulary sort is there, and only
     behind a ``conditional`` the compiler kept, so an all-greedy call
-    runs none."""
+    runs none. And no program casts a parameter stack: the engine's
+    leaves are the ones the model reads narrow, cast at construction,
+    so no f32 -> bf16 copy of a scanned layer's weight is left; what is
+    cast whole still is a tied head's embedding (GPT-2's ``wte``)."""
     import os
 
     scripts = os.path.join(
@@ -311,9 +314,14 @@ def test_compiled_serving_programs_leave_the_pool_in_place(
     monkeypatch.setattr(_PAGED, "_IMPL", "kernel")
     programs = list(pool_hlo_check.compile_cell(cell, one_v5e))
     assert [name for name, *_ in programs] == ["_decode_fn", "_prefill_fn"]
-    for name, compiled, frames, floor, n_leaves, chunk, vocab in programs:
+    for (name, compiled, frames, floor, n_leaves, chunk, vocab,
+         weights) in programs:
         text = compiled.as_text()
         assert pool_hlo_check.pool_passes(text, frames, floor) == [], name
+        casts = pool_hlo_check.weight_casts(text, weights)
+        assert {leaf for _, _, ls in casts for leaf in ls} <= {
+            "['wte']['embedding']"
+        }, (name, casts)
         bare, sorts = pool_hlo_check.unbranched_sorts(text, vocab)
         assert sorts and not bare and " conditional(" in text, (name, bare)
         if "lm_head/dot_general" in text:
